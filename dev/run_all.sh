@@ -1,6 +1,9 @@
 #!/usr/bin/env bash
-# Full verification: native build, tests (with batch validation), examples,
-# micro-benchmarks, headline bench (role of the reference's dev/run-tests.py).
+# Full verification ON THE CPU: native build, tests (with batch validation),
+# examples, the bench --smoke functional gates, micro-benchmarks (role of the
+# reference's dev/run-tests.py). Nothing here touches a device: the proof
+# that the query path starts on the chip is `python chip_smoke.py`, run on
+# the machine with the chip, and a measuring `python bench.py` needs one too.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -84,5 +87,8 @@ python dev/perfcheck.py
 echo "== micro-benchmarks =="
 python benchmarks/run_benchmarks.py --rows "${BENCH_ROWS:-2000000}"
 
-echo "== headline bench =="
-python bench.py
+echo "== bench functional gate (forced CPU, counts only) =="
+python bench.py --smoke
+
+echo "== chip smoke at a tiny size on the CPU (python chip_smoke.py on the chip) =="
+python chip_smoke.py --cpu

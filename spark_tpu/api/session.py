@@ -145,11 +145,11 @@ class TpuSession:
         _lockwatch.configure(self.conf)
         from ..exec import persist_cache as _persist
 
-        # persistent compile/result caches (spark.tpu.cache.*) — off by
-        # default (cache dir empty); with a dir configured this points
-        # jax's XLA persistent compilation cache at <dir>/xla and
-        # installs the disk-hit/miss event counters. Conf ships to
-        # workers, whose begin_stage_obs makes the same call.
+        # persistent XLA compile cache: placed by
+        # JAX_COMPILATION_CACHE_DIR, else <spark.tpu.cache.dir>/xla,
+        # else the fixed in-checkout .cache/xla; installs the
+        # disk-hit/miss event counters. Conf ships to workers, whose
+        # begin_stage_obs makes the same call.
         _persist.configure(self.conf)
         from ..obs import export as _export
 

@@ -535,20 +535,23 @@ OBS_PROFILE_WALL_TOLERANCE = _register(ConfigEntry(
 CACHE_DIR = _register(ConfigEntry(
     "spark.tpu.cache.dir", "",
     "Root directory for the persistent caches: the XLA compile cache "
-    "(<dir>/xla — jitted programs compiled once hit disk on every later "
+    "(<dir>/xla unless JAX_COMPILATION_CACHE_DIR places it elsewhere — "
+    "jitted programs compiled once hit disk on every later "
     "process's first dispatch), the warm-start manifest (<dir>/"
     "manifest.jsonl — per-fingerprint tier decisions and join/mesh "
     "capacity outcomes, so a restarted server skips capacity-retry "
     "recompiles), and the result cache (<dir>/result — full "
     "plan-fingerprint + data-version keyed Arrow IPC payloads; a hit "
-    "answers with ZERO kernel launches). Empty (default) = every "
-    "persistent cache off; tier-1 exact-count tests and the plan "
-    "analyzer's default launch model assume this default.", str))
+    "answers with ZERO kernel launches). Empty (default) = manifest and "
+    "result cache off, and the XLA compile cache at the fixed "
+    "in-checkout .cache/xla (tier-1 exact-count tests pin it off with "
+    "jax_enable_compilation_cache).", str))
 
 CACHE_COMPILE = _register(ConfigEntry(
     "spark.tpu.cache.compile.enabled", True,
-    "With spark.tpu.cache.dir set, point jax's XLA persistent "
-    "compilation cache at <dir>/xla so every jitted program's backend "
+    "Keep jax's XLA persistent compilation cache on (at "
+    "JAX_COMPILATION_CACHE_DIR, else <spark.tpu.cache.dir>/xla, else "
+    "the in-checkout .cache/xla) so every jitted program's backend "
     "compile is written to disk once and served from disk in later "
     "processes (the normal jax.jit dispatch path stays intact — no AOT "
     "lowered.compile(), whose compile is unshared with dispatch on this "

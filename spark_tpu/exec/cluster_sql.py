@@ -329,10 +329,10 @@ def _run_stage_store(plan_bytes: bytes, conf_overrides: dict,
 
     import jax
 
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
+    # cluster workers are CPU workers on purpose (a chip belongs to one
+    # process — the driver); worker_env already says so in the
+    # environment, this holds for a worker started any other way
+    jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)
 
     from ..config import SQLConf

@@ -30,8 +30,8 @@ from ..types import dict_encoded
 DEVICE_BUDGET = _register(ConfigEntry(
     "spark.tpu.memory.deviceBudgetBytes", 0,
     "Device-memory budget (bytes) a single blocking operator may "
-    "materialize as one tile. 0 = auto: live device bytes_limit × 0.5, "
-    "else 4 GiB. (Role of spark.memory.fraction over the unified region, "
+    "materialize as one tile. 0 = auto: live device bytes_limit × 0.5 "
+    "(a CPU backend, which reports none: 4 GiB). (Role of spark.memory.fraction over the unified region, "
     "core/memory/UnifiedMemoryManager.scala.)", int))
 
 SPILL_BYTES = _register(ConfigEntry(
@@ -61,16 +61,21 @@ def schema_row_bytes(schema) -> int:
 
 
 def _auto_budget() -> int:
-    try:
-        import jax
+    """Half the device's memory limit. A CPU backend reports none and
+    gets 4 GB (tests); an accelerator that reports none is an error —
+    tiling against a guessed HBM size hides the device."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit // 2
-    except Exception:
-        pass
-    return 4 << 30
+    dev = jax.local_devices()[0]
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit > 0:
+        return limit // 2
+    if dev.platform == "cpu":
+        return 4 << 30
+    raise RuntimeError(
+        f"{dev.platform} device {dev.device_kind!r} reports no "
+        "memory_stats()['bytes_limit']: set "
+        "spark.tpu.memory.deviceBudgetBytes explicitly")
 
 
 class MemoryManager:

@@ -6,14 +6,19 @@ per-stage compile keys): the suite and the serving path are both
 compile-bound, PR 10's whole-query tier made cold compiles the dominant
 per-query cost, and a restarted server used to pay every one of them
 again while a repeated dashboard query re-launched kernels to recompute
-an identical answer. Three layers, all rooted at `spark.tpu.cache.dir`
-(empty by default — every persistent cache OFF; the tier-1 exact-count
-tests and the plan analyzer's default launch model assume that default):
+an identical answer. Three layers. The manifest and the result cache
+are rooted at `spark.tpu.cache.dir` (empty by default — both OFF). The
+compile cache is on whenever jax allows it (the test harness pins it off
+with jax's own `jax_enable_compilation_cache`, because the tier-1
+exact-count tests assume no persistent cache unless a test asks):
 
   * **Persistent compile cache** (`spark.tpu.cache.compile.enabled`) —
-    jax's XLA persistent compilation cache pointed at `<dir>/xla`, with
-    the entry-size/compile-time floors dropped so every engine kernel
-    qualifies. The normal `jax.jit` dispatch path stays intact — this
+    jax's XLA persistent compilation cache, placed by
+    `JAX_COMPILATION_CACHE_DIR` when that is set (no code overrides it),
+    else at `<spark.tpu.cache.dir>/xla`, else at the fixed in-checkout
+    `.cache/xla`; the entry-size/compile-time floors are dropped so every
+    engine kernel qualifies. The normal `jax.jit` dispatch path stays
+    intact — this
     deliberately does NOT route through AOT `lowered.compile()`, whose
     backend compile is not shared with the dispatch path on this jax
     version (the PR 12 kernelMemory finding). A jax monitoring listener
@@ -58,7 +63,7 @@ import time
 
 from ..utils import lockwatch
 
-__all__ = ["configure", "cache_root", "compile_cache_active",
+__all__ = ["configure", "cache_root", "xla_cache_dir",
            "result_cache_active", "disk_counters", "reset_disk_counters",
            "ResultCache",
            "result_cache_for", "result_key", "result_probe",
@@ -78,13 +83,6 @@ def cache_root(conf) -> str:
     from ..config import CACHE_DIR
 
     return str(conf.get(CACHE_DIR) or "")  # tpulint: ignore[host-sync]
-
-
-def compile_cache_active(conf) -> bool:
-    from ..config import CACHE_COMPILE
-
-    enabled = conf.get(CACHE_COMPILE)  # conf value: host data
-    return bool(cache_root(conf)) and bool(enabled)  # tpulint: ignore[host-sync]
 
 
 def result_cache_active(conf) -> bool:
@@ -150,45 +148,81 @@ def reset_disk_counters() -> None:
         DISK_MISSES = 0
 
 
+# where the XLA cache lives when nothing outside places it: one fixed path
+# inside the checkout (the path is part of the cache key's locality — a
+# directory that moves between runs never hits)
+_DEFAULT_XLA_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache", "xla")
+
+
+def xla_cache_dir(conf) -> str | None:
+    """Directory of this process's XLA persistent compilation cache, or
+    None when it is off (spark.tpu.cache.compile.enabled=false, or jax's
+    own jax_enable_compilation_cache switch — how the test harness pins
+    it off). JAX_COMPILATION_CACHE_DIR places it from outside and always
+    wins; then `<spark.tpu.cache.dir>/xla`; then the fixed in-checkout
+    path."""
+    import jax
+
+    from ..config import CACHE_COMPILE
+
+    if not conf.get(CACHE_COMPILE) \
+            or not jax.config.jax_enable_compilation_cache:
+        return None
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    root = cache_root(conf)
+    return os.path.join(root, "xla") if root else _DEFAULT_XLA_DIR
+
+
 def configure(conf) -> None:
     """Idempotent per-session/per-worker switch (the persist analog of
-    obs.resources.configure): with a cache dir configured and the
-    compile cache enabled, point jax's persistent compilation cache at
-    `<dir>/xla` and install the hit/miss event listener. Never raises
-    into session construction."""
+    obs.resources.configure): turn jax's persistent compilation cache on
+    at xla_cache_dir(conf), drop the entry-size/compile-time floors so
+    the engine's many small programs qualify, and install the hit/miss
+    event listener. With JAX_COMPILATION_CACHE_DIR set the directory is
+    jax's own reading of the environment — no code sets another. A
+    directory that cannot be created is an error, not a silent cold
+    start on every run."""
     global _configured_dir, _listener_installed
-    if not compile_cache_active(conf):
+    target = xla_cache_dir(conf)
+    if target is None:
         return
-    target = os.path.join(cache_root(conf), "xla")
-    try:
-        import jax
+    import jax
 
-        from ..config import CACHE_COMPILE_MAX_BYTES
+    from ..config import CACHE_COMPILE_MAX_BYTES
 
-        if _configured_dir != target:
+    if _configured_dir != target:
+        try:
             os.makedirs(target, exist_ok=True)
+        except OSError as e:
+            raise RuntimeError(
+                f"XLA compile cache directory {target!r} cannot be "
+                f"created ({e}); point JAX_COMPILATION_CACHE_DIR at a "
+                "writable directory or set "
+                "spark.tpu.cache.compile.enabled=false") from e
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             jax.config.update("jax_compilation_cache_dir", target)
-            # every engine kernel qualifies: the suite is compile-bound
-            # precisely because of many small programs
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            max_bytes = int(conf.get(  # tpulint: ignore[host-sync]
-                CACHE_COMPILE_MAX_BYTES))
-            if max_bytes > 0:
-                jax.config.update("jax_compilation_cache_max_size",
-                                  max_bytes)
-            _configured_dir = target
-        if not _listener_installed:
-            import jax._src.monitoring as _mon
+            if _configured_dir is not None:
+                # jax opens its cache once per process: a session that
+                # moves the directory has to close the old one
+                from jax.experimental.compilation_cache import (
+                    compilation_cache as _cc,
+                )
 
-            _mon.register_event_listener(_on_monitor_event)
-            _listener_installed = True
-    except Exception:
-        # the persistent cache is an optimization: a read-only FS or a
-        # jax without the knobs must never fail session construction
-        pass
+                _cc.reset_cache()
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        max_bytes = int(conf.get(  # tpulint: ignore[host-sync]
+            CACHE_COMPILE_MAX_BYTES))
+        if max_bytes > 0:
+            jax.config.update("jax_compilation_cache_max_size", max_bytes)
+        _configured_dir = target
+    if not _listener_installed:
+        jax.monitoring.register_event_listener(_on_monitor_event)
+        _listener_installed = True
 
 
 # ---------------------------------------------------------------------------

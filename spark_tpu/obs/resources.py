@@ -108,37 +108,40 @@ def kernel_memory_enabled() -> bool:
 # peak-bandwidth reference (achieved-vs-peak GB/s in EXPLAIN ANALYZE)
 # ---------------------------------------------------------------------------
 
-# published HBM bandwidth per chip generation (GB/s); the conf override
-# spark.tpu.memory.peakGbps wins when set (>0)
-_PEAK_GBPS_BY_KIND = (
-    ("v6", 1640.0), ("v5p", 2765.0), ("v5e", 819.0), ("v5", 819.0),
-    ("v4", 1228.0), ("v3", 900.0), ("v2", 700.0),
-)
+# published HBM bandwidth in GB/s, keyed by the exact `device_kind` the
+# chip reports; the conf override spark.tpu.memory.peakGbps wins when
+# set (>0). A device that is not in the table is an error, not a default.
+_PEAK_GBPS_BY_KIND = {
+    # Google Cloud documentation, "TPU v5e": 16 GB HBM2e at 819 GB/s per
+    # chip; kind string as reported by the chip (PR 21 chip run)
+    "TPU v5 lite": 819.0,
+}
 
 
 def device_peak_gbps(conf=None) -> float | None:
-    """Peak HBM GB/s of the local accelerator, or None when unknown
-    (CPU backends have no meaningful HBM roofline). Reads only the jax
-    device *descriptor* — never device memory."""
+    """Peak HBM GB/s of the local accelerator. None on a CPU backend
+    (no meaningful HBM roofline); an accelerator whose device_kind is
+    not in the table raises. Reads only the jax device *descriptor* —
+    never device memory."""
     if conf is not None:
-        try:
-            from ..config import MEMORY_PEAK_GBPS
+        from ..config import MEMORY_PEAK_GBPS
 
-            v = float(conf.get(MEMORY_PEAK_GBPS))
-            if v > 0:
-                return v
-        except Exception:
-            pass
-    try:
-        import jax
+        v = float(conf.get(MEMORY_PEAK_GBPS))  # tpulint: ignore[host-sync]
+        if v > 0:
+            return v
+    import jax
 
-        kind = jax.local_devices()[0].device_kind.lower()
-    except Exception:
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
         return None
-    for tag, gbps in _PEAK_GBPS_BY_KIND:
-        if tag in kind:
-            return gbps
-    return None
+    try:
+        return _PEAK_GBPS_BY_KIND[dev.device_kind]
+    except KeyError:
+        raise RuntimeError(
+            f"no published peak HBM bandwidth for device_kind "
+            f"{dev.device_kind!r}: add it to obs/resources."
+            "_PEAK_GBPS_BY_KIND with its source, or set "
+            "spark.tpu.memory.peakGbps") from None
 
 
 # ---------------------------------------------------------------------------

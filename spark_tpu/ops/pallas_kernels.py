@@ -3,36 +3,30 @@
 XLA fuses elementwise work well, but data-dependent scatter (histogram,
 dense-key group-by) lowers to serialized HBM scatters on TPU. These
 kernels recast scatter as ONE-HOT MATMUL on the MXU: each grid step loads
-a row block into VMEM, builds `onehot[block, buckets]`, and accumulates
-`values @ onehot` into a VMEM scratch that lives across the sequential
+a row block into VMEM, builds `onehot[buckets, block]`, and accumulates
+`values @ onehot.T` into a VMEM scratch that lives across the sequential
 grid — one HBM write at the end. (Reference analog: the vectorized hash
 map of AggregateBenchmark / the shuffle partition histogram in
 sqlx/shuffle/ShuffleExchangeExec; rebuilt here for the MXU instead of
 per-core hash tables.)
 
-On CPU (tests; no TPU chip available) the kernels run in interpret mode —
-same program, Python semantics. Counts and blockwise partial sums stay
-exact in float32 (≤ 2^24 per block); int64-exact sums keep using the
-XLA scatter path (see ops/grouping.py).
+`interpret` is the caller's to say (tests on the CPU pass True; nothing is
+inferred from the backend). Counts and blockwise partial sums stay exact
+in float32 (≤ 2^24 per bucket); int64-exact sums keep using the XLA
+scatter path (see ops/grouping.py).
 """
 
 from __future__ import annotations
 
 import functools
 
-import numpy as np
-
-
-def _pl():
-    import jax
-    from jax.experimental import pallas as pl
-
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except Exception:  # pragma: no cover
-        pltpu = None
-    interpret = jax.default_backend() != "tpu"
-    return jax, pl, pltpu, interpret
+_LANES = 128
+_SUBLANES = 8
+# the one-hot tile is buckets × block × 4 bytes of VMEM: the row block
+# shrinks as buckets grow, and past _MAX_BUCKETS even the narrowest block
+# does not fit beside the double-buffered inputs
+_ONEHOT_BYTES = 2 << 20
+_MAX_BUCKETS = _ONEHOT_BYTES // (4 * _LANES)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -40,70 +34,14 @@ def _round_up(x: int, m: int) -> int:
 
 
 @functools.lru_cache(maxsize=64)
-def _histogram_fn(rows: int, buckets: int, block: int):
-    jax, pl, pltpu, interpret = _pl()
+def _bucket_sum_fn(rows: int, buckets: int, block: int, interpret: bool):
+    """jit(keys[1, rows] int32, vals[1, rows] f32) -> f32[buckets]:
+    vals summed by key."""
+    import jax
     import jax.numpy as jnp
-
-    grid = rows // block
-
-    def kernel(pid_ref, mask_ref, out_ref, acc_ref):
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _init():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-
-        pids = pid_ref[:]                       # [1, block] int32
-        m = mask_ref[:]                         # [1, block] f32 0/1
-        iota = jax.lax.broadcasted_iota(jnp.int32, (block, buckets), 1)
-        onehot = (pids.reshape(block, 1) == iota).astype(jnp.float32)
-        acc_ref[:] += m @ onehot                # [1, buckets] on the MXU
-
-        @pl.when(i == grid - 1)
-        def _flush():
-            out_ref[:] = acc_ref[:]
-
-    def build(pids2, mask2):
-        return pl.pallas_call(
-            kernel,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((1, block), lambda i: (0, i)),
-                pl.BlockSpec((1, block), lambda i: (0, i)),
-            ],
-            out_specs=pl.BlockSpec((1, buckets), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, buckets), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((1, buckets), jnp.float32)]
-            if pltpu is not None else [],
-            interpret=interpret,
-        )(pids2, mask2)
-
-    return jax.jit(build)
-
-
-def partition_histogram(pids, mask, num_partitions: int, block: int = 1024):
-    """Exact per-partition live-row counts: int32 pids[cap] + bool
-    mask[cap] → int32[num_partitions]. One MXU matmul per block."""
-    import jax.numpy as jnp
-
-    cap = int(pids.shape[0])
-    buckets = _round_up(max(num_partitions, 1), 128)
-    block = min(block, _round_up(cap, 8))
-    rows = _round_up(cap, block)
-    p2 = jnp.full((rows,), buckets - 1, jnp.int32).at[:cap].set(
-        jnp.clip(pids.astype(jnp.int32), 0, buckets - 1))
-    m2 = jnp.zeros((rows,), jnp.float32).at[:cap].set(
-        mask.astype(jnp.float32))
-    # rows where mask=0 contribute nothing regardless of pid
-    out = _histogram_fn(rows, buckets, block)(
-        p2.reshape(1, rows), m2.reshape(1, rows))
-    return out[0, :num_partitions].astype(jnp.int32)
-
-
-@functools.lru_cache(maxsize=64)
-def _group_sum_fn(rows: int, groups: int, block: int):
-    jax, pl, pltpu, interpret = _pl()
-    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     grid = rows // block
 
@@ -112,51 +50,85 @@ def _group_sum_fn(rows: int, groups: int, block: int):
 
         @pl.when(i == 0)
         def _init():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        keys = key_ref[:]                       # [1, block] int32
-        vals = val_ref[:]                       # [1, block] f32 (pre-masked)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (block, groups), 1)
-        onehot = (keys.reshape(block, 1) == iota).astype(jnp.float32)
-        acc_ref[:] += vals @ onehot             # [1, groups]
+        # one-hot laid out [buckets, block]: the keys broadcast along
+        # sublanes, so there is no in-kernel relayout
+        onehot = (lax.broadcasted_iota(jnp.int32, (buckets, block), 0)
+                  == key_ref[...]).astype(jnp.float32)
+        # vals @ onehot.T on the MXU, M padded to one sublane tile
+        acc_ref[...] += lax.dot_general(
+            jnp.broadcast_to(val_ref[...], (_SUBLANES, block)), onehot,
+            (((1,), (1,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
 
         @pl.when(i == grid - 1)
         def _flush():
-            out_ref[:] = acc_ref[:]
+            out_ref[...] = acc_ref[...]
 
     def build(keys2, vals2):
-        return pl.pallas_call(
-            kernel,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((1, block), lambda i: (0, i)),
-                pl.BlockSpec((1, block), lambda i: (0, i)),
-            ],
-            out_specs=pl.BlockSpec((1, groups), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, groups), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((1, groups), jnp.float32)]
-            if pltpu is not None else [],
-            interpret=interpret,
-        )(keys2, vals2)
+        # the engine runs with jax_enable_x64; Mosaic rejects the int64
+        # block indices a Python `0` would become under it
+        with jax.enable_x64(False):
+            out = pl.pallas_call(
+                kernel,
+                grid=(grid,),
+                in_specs=[pl.BlockSpec((1, block), lambda i: (0, i)),
+                          pl.BlockSpec((1, block), lambda i: (0, i))],
+                out_specs=pl.BlockSpec((_SUBLANES, buckets),
+                                       lambda i: (0, 0)),
+                out_shape=jax.ShapeDtypeStruct((_SUBLANES, buckets),
+                                               jnp.float32),
+                scratch_shapes=[pltpu.VMEM((_SUBLANES, buckets),
+                                           jnp.float32)],
+                interpret=interpret,
+            )(keys2, vals2)
+        return out[0]
 
     return jax.jit(build)
 
 
-def dense_group_sum_f32(keys, values, mask, num_groups: int,
-                        block: int = 1024):
+def _bucket_sum(keys, vals, num_buckets: int, block: int, interpret: bool):
+    """Pad to whole blocks (padding rows carry value 0) and run the
+    kernel; returns f32[num_buckets]."""
+    import jax.numpy as jnp
+
+    cap = int(keys.shape[0])
+    buckets = _round_up(max(num_buckets, 1), _LANES)
+    if buckets > _MAX_BUCKETS:
+        raise ValueError(
+            f"{num_buckets} buckets: the one-hot tile would not fit VMEM "
+            f"(limit {_MAX_BUCKETS}); use the XLA scatter path")
+    fit = (_ONEHOT_BYTES // (4 * buckets)) // _LANES * _LANES
+    block = max(_LANES, min(_round_up(block, _LANES), fit,
+                            _round_up(cap, _LANES)))
+    rows = _round_up(cap, block)
+    k2 = jnp.zeros((rows,), jnp.int32).at[:cap].set(
+        jnp.clip(keys.astype(jnp.int32), 0, buckets - 1))
+    v2 = jnp.zeros((rows,), jnp.float32).at[:cap].set(vals)
+    out = _bucket_sum_fn(rows, buckets, block, interpret)(
+        k2.reshape(1, rows), v2.reshape(1, rows))
+    return out[:num_buckets]
+
+
+def partition_histogram(pids, mask, num_partitions: int, *,
+                        interpret: bool, block: int = 1024):
+    """Exact per-partition live-row counts: int32 pids[cap] + bool
+    mask[cap] → int32[num_partitions]. One MXU matmul per block."""
+    import jax.numpy as jnp
+
+    counts = _bucket_sum(pids, mask.astype(jnp.float32), num_partitions,
+                         block, interpret)
+    return counts.astype(jnp.int32)
+
+
+def dense_group_sum_f32(keys, values, mask, num_groups: int, *,
+                        interpret: bool, block: int = 1024):
     """Grouped float sum over DENSE int keys in [0, num_groups):
     the MXU one-hot path of the dense-range aggregation fast path
     (float32 accumulation — int64-exact sums stay on the XLA scatter)."""
     import jax.numpy as jnp
 
-    cap = int(keys.shape[0])
-    groups = _round_up(max(num_groups, 1), 128)
-    block = min(block, _round_up(cap, 8))
-    rows = _round_up(cap, block)
-    k2 = jnp.full((rows,), groups - 1, jnp.int32).at[:cap].set(
-        jnp.clip(keys.astype(jnp.int32), 0, groups - 1))
-    v2 = jnp.zeros((rows,), jnp.float32).at[:cap].set(
-        jnp.where(mask, values.astype(jnp.float32), 0.0))
-    out = _group_sum_fn(rows, groups, block)(
-        k2.reshape(1, rows), v2.reshape(1, rows))
-    return out[0, :num_groups]
+    vals = jnp.where(mask, values.astype(jnp.float32), 0.0)
+    return _bucket_sum(keys, vals, num_groups, block, interpret)

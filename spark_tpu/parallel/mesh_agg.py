@@ -37,7 +37,7 @@ def make_distributed_groupby_sum(mesh, axis_name: str = "data",
     exchange quota defaults to shard_cap // P (retryable upward by caller)."""
     from jax.sharding import PartitionSpec as P
 
-    from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     n_part = mesh.shape[axis_name]
 

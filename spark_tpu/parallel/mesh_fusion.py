@@ -273,7 +273,7 @@ def build_plain_stage(mesh, axis: str, quota: int, num_out: int,
     import jax
 
     from ..ops.hashing import hash_columns, partition_ids
-    from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     layout = MeshSpecLayout(axis)
     rows = layout.rows()
@@ -344,7 +344,7 @@ def build_fused_stage(mesh, axis: str, shard_cap: int, quota: int,
 
     from ..physical.compile import trace_pipeline
     from ..ops.hashing import hash_columns, partition_ids
-    from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     layout = MeshSpecLayout(axis)
     rows = layout.rows()

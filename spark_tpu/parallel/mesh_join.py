@@ -24,7 +24,7 @@ def make_broadcast_join_sum(mesh, axis_name: str = "data"):
     scan→broadcast-join→project spine of a TPC-DS star query."""
     from jax.sharding import PartitionSpec as P
 
-    from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     def local_fn(pk, pv, pm, bk, bv, bm):
         # build side is replicated: dense direct-address table per shard

@@ -32,7 +32,7 @@ def make_mesh_groupby_pipeline(mesh, axis_name: str = "data"):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ._shard_map_compat import shard_map
+    from jax import shard_map
 
     from ..ops import grouping as G
     from .collectives import _bucket_local

@@ -183,7 +183,26 @@ class KernelCache:
         if not callable(f):
             return f
         kind = key[0] if isinstance(key, tuple) and key else "?"
-        state = {"first": True, "cost": None, "capturing": False}
+        # "ran": some invocation has returned — until then an XLA error
+        # out of this kernel is its (lazy, first-call) compile
+        state = {"first": True, "ran": False, "cost": None,
+                 "capturing": False}
+
+        def call(*args, **kwargs):
+            if state["ran"]:
+                return f(*args, **kwargs)
+            try:
+                out = f(*args, **kwargs)
+            except Exception as e:
+                if _faults.is_runtime_fault(e) \
+                        and not isinstance(e, _faults.InjectedFault):
+                    raise _faults.KernelCompileError(
+                        f"kernel '{kind}' failed before its first "
+                        f"completed launch — compile refusal: "
+                        f"{type(e).__name__}: {e}") from e
+                raise
+            state["ran"] = True
+            return out
 
         def launch(*args, **kwargs):
             if _faults.ENABLED:
@@ -258,7 +277,7 @@ class KernelCache:
 
                 d0 = _pc.DISK_HITS
                 t0 = _time.perf_counter()
-                out = f(*args, **kwargs)
+                out = call(*args, **kwargs)
                 dt = (_time.perf_counter() - t0) * 1000
                 disk_hit = _pc.DISK_HITS > d0
                 with self._lock:
@@ -269,7 +288,7 @@ class KernelCache:
                     _obs_disk_hit(kind)
                 _obs_compile(kind, dt)
                 return out
-            return f(*args, **kwargs)
+            return call(*args, **kwargs)
 
         launch._kernel = f
         return launch

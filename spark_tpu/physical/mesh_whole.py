@@ -603,14 +603,14 @@ def _build_mesh_program(b: _MeshProgramBuilder, root: _Lowered):
     """jit(shard_map(local program)). The local function reassembles the
     flat args list from the (donated, kept) buckets, emits the whole
     lowered tree per shard, and centrally reduces every verdict scalar
-    (pmax'd join `needed`s and guards, pmin/pmax'd spans; overflows are
+    (max'd join `needed`s and guards, min/max'd spans; overflows are
     already psum'd) so the host reads ONE value per check after the
     single dispatch. Outputs are replicated (the root is gathered), so
     check_vma=False with P() out_specs is sound by construction."""
     import jax
 
     from ..parallel import mesh_fusion as MF
-    from ..parallel._shard_map_compat import shard_map
+    from jax import shard_map
 
     slots = b.arg_slots()
     don_specs, keep_specs = b.spec_lists()
@@ -629,14 +629,25 @@ def _build_mesh_program(b: _MeshProgramBuilder, root: _Lowered):
         args = [None] * n_args
         for pos, (bk, j) in enumerate(slots):
             args[pos] = don[j] if bk == 0 else keep[j]
+        jnp = _jnp()
+
+        # not lax.pmax/pmin: these scalars are 64-bit, which the TPU
+        # emulates, and it lowers only SUM all-reduces for emulated types
+        # ("UNIMPLEMENTED: Supported lowering only of Sum all reduce",
+        # v5e, PR 21) — gather the P scalars and reduce locally
+        def allmax(x):
+            return jnp.max(lax.all_gather(x, axis))
+
+        def allmin(x):
+            return jnp.min(lax.all_gather(x, axis))
+
         needed = _Collect()
         datas, valids, mask = root.emit(args, needed)
-        needed_r = tuple(lax.pmax(x, axis) for x in needed)
+        needed_r = tuple(allmax(x) for x in needed)
         ovfs = tuple(needed.overflows)
-        spans = tuple((lax.pmin(lo, axis), lax.pmax(hi, axis),
-                       lax.pmax(dup, axis))
+        spans = tuple((allmin(lo), allmax(hi), allmax(dup))
                       for lo, hi, dup in needed.spans)
-        guards = tuple(lax.pmax(g, axis) for g in needed.guards)
+        guards = tuple(allmax(g) for g in needed.guards)
         return (datas, valids, mask, needed_r, ovfs, spans, guards)
 
     out_specs = ([rep] * len(valid_sig),

@@ -53,8 +53,8 @@ import zlib
 
 from . import lockwatch
 
-__all__ = ["ENABLED", "InjectedFault", "configure", "maybe_fail",
-           "fire_counts", "reset", "is_transient_marker",
+__all__ = ["ENABLED", "InjectedFault", "KernelCompileError", "configure",
+           "maybe_fail", "fire_counts", "reset", "is_transient_marker",
            "is_runtime_fault"]
 
 # fast-path flag: fault points check this module bool before anything
@@ -96,16 +96,29 @@ class InjectedFault(RuntimeError):
         self.point = point
 
 
+class KernelCompileError(RuntimeError):
+    """The backend refused to COMPILE a kernel (Mosaic/XLA rejection,
+    VMEM or HBM exhaustion at compile time, unimplemented op). jax raises
+    the same XlaRuntimeError for a compile refusal and an execution
+    fault, so KernelCache classifies at the call site: an error out of a
+    kernel that has never yet run is its compile (physical/compile.py).
+    Never a runtime fault — a program the chip refuses must surface, not
+    quietly re-execute on a smaller tier."""
+
+
 def is_runtime_fault(e: BaseException) -> bool:
     """Is this a RUNTIME failure of a compiled program (XLA runtime
     error, device resource exhaustion, injected dispatch/compile chaos)
-    rather than a logic error? Runtime faults are recoverable by
-    degrading to a smaller execution granularity — the whole-query tier
-    re-executes stage-at-a-time, a mesh gang retries then falls back to
-    the host shuffle. Logic errors must keep propagating: re-executing
-    a deterministic bug elsewhere hides it."""
+    rather than a logic error or a compile refusal? Runtime faults are
+    recoverable by degrading to a smaller execution granularity — the
+    whole-query tier re-executes stage-at-a-time, a mesh gang retries
+    then falls back to the host shuffle. Logic errors and compile
+    refusals must keep propagating: re-executing a deterministic bug
+    elsewhere hides it."""
     if isinstance(e, InjectedFault):
         return True
+    if isinstance(e, KernelCompileError):
+        return False
     name = type(e).__name__
     if name in ("XlaRuntimeError", "JaxRuntimeError", "InternalError",
                 "ResourceExhaustedError"):

@@ -4,8 +4,11 @@ Role of the reference's [NATIVE-ROLE] Java off-heap layer
 (common/unsafe/.../Platform.java, Murmur3_x86_32.java, RadixSort.java):
 host-side hot loops — string hashing at dictionary build, counting-sort
 partitioning, dictionary merge — implemented in C++ and loaded via ctypes
-(no pybind11 in the image). Auto-builds with g++ on first use; every entry
-point has a numpy fallback so callers catch ImportError/OSError.
+(no pybind11 in the image). Builds with g++ on first use, and again
+whenever the library is older than its source (the library is git-ignored,
+so a copied tree may carry a stale one); every entry point has a numpy
+fallback so callers catch ImportError/OSError. `status()` says which of
+the three happened.
 """
 
 from __future__ import annotations
@@ -20,23 +23,43 @@ import numpy as np
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "native")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "sparktpu_native.cpp")
 _SO_PATH = os.path.join(_NATIVE_DIR, "build", "libsparktpu_native.so")
 
 
 def _try_build() -> None:
-    src = os.path.join(_NATIVE_DIR, "sparktpu_native.cpp")
-    if not os.path.exists(src):
+    if not os.path.exists(_SRC_PATH):
         raise ImportError("native source missing")
     os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
-    subprocess.run(
-        ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-o", _SO_PATH, src],
-        check=True, capture_output=True, timeout=120)
+    # build beside the target and rename: a concurrent process (cluster
+    # workers start together) must never dlopen a half-written library
+    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-o", tmp,
+             _SRC_PATH],
+            check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO_PATH)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
+def _stale() -> bool:
+    try:
+        return os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return True  # no library yet (a missing source fails the build)
 
 
 @lru_cache(maxsize=1)
-def _load():
-    if not os.path.exists(_SO_PATH):
+def _load_with_origin():
+    """(library, "built" | "loaded"): built when this process compiled
+    it, loaded when an up-to-date one was already there."""
+    origin = "loaded"
+    if _stale():
         _try_build()
+        origin = "built"
     lib = ctypes.CDLL(_SO_PATH)
     lib.spark_tpu_hash_strings.restype = None
     lib.spark_tpu_hash_strings.argtypes = [
@@ -49,7 +72,11 @@ def _load():
     lib.spark_tpu_merge_dicts.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
         ctypes.c_void_p, ctypes.c_void_p]
-    return lib
+    return lib, origin
+
+
+def _load():
+    return _load_with_origin()[0]
 
 
 def available() -> bool:
@@ -58,6 +85,15 @@ def available() -> bool:
         return True
     except Exception:
         return False
+
+
+def status() -> str:
+    """`built` (compiled by this process), `loaded` (an up-to-date
+    library was already there) or `numpy-fallback (reason)`."""
+    try:
+        return _load_with_origin()[1]
+    except Exception as e:
+        return f"numpy-fallback ({type(e).__name__}: {str(e)[:120]})"
 
 
 def _pack(values: list[str]) -> tuple[bytes, np.ndarray]:

@@ -1,5 +1,6 @@
-"""Pallas MXU kernels vs numpy oracles (interpret mode on CPU; the same
-programs compile for TPU — see ops/pallas_kernels.py)."""
+"""Pallas MXU kernels vs numpy oracles, interpreted (this suite runs on
+the CPU; chip_smoke-time compilation on the chip is recorded in
+CHANGES.md, PR 21 — see ops/pallas_kernels.py)."""
 
 import numpy as np
 import pytest
@@ -17,7 +18,8 @@ def test_partition_histogram_exact():
         pids = rng.integers(0, parts, cap)
         mask = rng.random(cap) < 0.8
         got = np.asarray(partition_histogram(
-            jnp.asarray(pids, jnp.int32), jnp.asarray(mask), parts))
+            jnp.asarray(pids, jnp.int32), jnp.asarray(mask), parts,
+            interpret=True))
         exp = np.bincount(pids[mask], minlength=parts)
         assert (got == exp).all()
 
@@ -25,7 +27,7 @@ def test_partition_histogram_exact():
 def test_partition_histogram_all_dead_rows():
     pids = jnp.zeros(64, jnp.int32)
     mask = jnp.zeros(64, bool)
-    got = np.asarray(partition_histogram(pids, mask, 4))
+    got = np.asarray(partition_histogram(pids, mask, 4, interpret=True))
     assert (got == 0).all()
 
 
@@ -37,7 +39,7 @@ def test_dense_group_sum_matches_scatter():
     mask = rng.random(cap) < 0.9
     got = np.asarray(dense_group_sum_f32(
         jnp.asarray(keys, jnp.int32), jnp.asarray(vals),
-        jnp.asarray(mask), groups))
+        jnp.asarray(mask), groups, interpret=True))
     exp = np.zeros(groups, np.float64)
     np.add.at(exp, keys[mask], vals[mask])
     assert np.abs(got - exp).max() < 1e-3
@@ -48,5 +50,14 @@ def test_dense_group_sum_non_multiple_block():
     keys = jnp.asarray(np.arange(10) % 3, jnp.int32)
     vals = jnp.ones(10, jnp.float32)
     mask = jnp.ones(10, bool)
-    got = np.asarray(dense_group_sum_f32(keys, vals, mask, 3))
+    got = np.asarray(dense_group_sum_f32(keys, vals, mask, 3,
+                                         interpret=True))
     assert got.tolist() == [4.0, 3.0, 3.0]
+
+
+def test_bucket_count_is_bounded_by_vmem():
+    from spark_tpu.ops.pallas_kernels import _MAX_BUCKETS
+
+    with pytest.raises(ValueError, match="VMEM"):
+        partition_histogram(jnp.zeros(8, jnp.int32), jnp.ones(8, bool),
+                            _MAX_BUCKETS + 1, interpret=True)

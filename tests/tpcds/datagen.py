@@ -110,9 +110,15 @@ STORE_NAMES = ["ese", "ought", "able", "pri", "bar", "anti", "cally",
 
 
 class _Gen:
-    def __init__(self, scale: float, seed: int):
+    def __init__(self, scale: float, seed: int,
+                 keep: dict[str, set[str]] | None = None):
+        """`keep` maps a table name to the columns worth building: the
+        rest of that table's schema is left out (the Decimal conversion
+        of an unread fact column costs minutes at SF1 volume). Tables not
+        named in `keep` are built whole."""
         self.rng = np.random.default_rng(seed)
         self.scale = scale
+        self.keep = keep or {}
         self.tables: dict[str, pa.Table] = {}
 
     # ---- helpers ---------------------------------------------------------
@@ -142,9 +148,12 @@ class _Gen:
         """Order + type-coerce per schema; fill any unspecified column with
         a generic value of its declared type."""
         schema = _SCHEMA[name]
+        kept = self.keep.get(name)
         arrays, fields = [], []
         nrows = len(next(iter(cols.values())))
         for cname, ctype in schema:
+            if kept is not None and cname not in kept:
+                continue
             ctype_u = ctype.upper()
             m = re.match(r"DECIMAL\((\d+),(\d+)\)", ctype_u)
             if cname in cols:

@@ -21,6 +21,8 @@ import re
 import sqlite3
 from decimal import Decimal
 
+import pyarrow as pa
+
 
 class _StddevSamp:
     def __init__(self):
@@ -62,22 +64,21 @@ def load_sqlite(tables) -> sqlite3.Connection:
     for name, tab in tables.items():
         cols = tab.column_names
         conn.execute(f"CREATE TABLE {name} ({', '.join(cols)})")
-        pyrows = []
         pycols = []
         for c in cols:
-            vals = tab.column(c).to_pylist()
-            conv = []
-            for v in vals:
-                if isinstance(v, Decimal):
-                    v = float(v)
-                elif isinstance(v, (datetime.date, datetime.datetime)):
-                    v = v.isoformat()[:10]
-                conv.append(v)
-            pycols.append(conv)
-        pyrows = list(zip(*pycols))
+            col = tab.column(c)
+            if pa.types.is_decimal(col.type):
+                # in arrow, not cell by cell: a fact table's decimal
+                # columns are most of the load time at SF1 volume
+                col = col.cast(pa.float64())
+            vals = col.to_pylist()
+            if pa.types.is_date(col.type) or pa.types.is_timestamp(col.type):
+                vals = [None if v is None else v.isoformat()[:10]
+                        for v in vals]
+            pycols.append(vals)
         conn.executemany(
             f"INSERT INTO {name} VALUES ({','.join('?' * len(cols))})",
-            pyrows)
+            zip(*pycols))
     conn.commit()
     return conn
 
