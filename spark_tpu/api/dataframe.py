@@ -406,7 +406,12 @@ class DataFrame:
         per-operator metrics — rows, wall-ms, attributed kernel launches
         and compile-ms, including inside whole-stage fused operators —
         side by side with the static predictions, flagging drift
-        (obs/metrics.AnalyzedReport; the EXPLAIN ANALYZE analog)."""
+        (obs/metrics.AnalyzedReport; the EXPLAIN ANALYZE analog).
+        mode="device" EXECUTES the query too (one warm run + one run
+        under the jax profiler) and renders, for each whole-query program
+        the run launched, the device time of every operator and of the
+        kernel bodies inside it (sort, probe, expand, segment_reduce,
+        ...), discarded attempts apart (obs/device_profile.py)."""
         print(self.query_execution.explain_string(mode))
 
     def createOrReplaceTempView(self, name: str) -> None:

@@ -50,6 +50,23 @@ class QueryPlanningTracker:
                       key=lambda t: -t[1])[:n]
 
 
+def _planes_to_host(batches) -> dict:
+    """Copy every plane of the result to the host, in the order
+    `ColumnarBatch.to_arrow` reads them. A jax array keeps its host copy,
+    so the Arrow assembly that follows finds it there: the device→host
+    time and the assembly time come apart, and nothing is copied twice."""
+    import numpy as np
+
+    planes = nbytes = 0
+    for b in batches:
+        for a in [b.row_mask] + [x for c in b.columns
+                                 for x in (c.data, c.validity)]:
+            if a is not None:
+                nbytes += np.asarray(a).nbytes  # tpulint: ignore[host-sync]
+                planes += 1
+    return {"planes": planes, "bytes": nbytes}
+
+
 class QueryExecution:
     # flight-recorder close results (obs/history.py): populated by
     # execute() when spark.tpu.obs.profileDir is set; class defaults so
@@ -233,7 +250,7 @@ class QueryExecution:
             import uuid
 
             qid = uuid.uuid4().hex[:12]
-            eph_token = push_query(qid)
+            eph_token = push_query(qid, self._tracer)
         from .context import ScopedMetrics
 
         # ScopedMetrics: every counter this query adds lands on the
@@ -488,7 +505,7 @@ class QueryExecution:
         # or in cluster workers (tag ships with the task) — is stamped
         # with qid, so concurrent collects on one shared session produce
         # disjoint span sets
-        qtoken = push_query(qid)
+        qtoken = push_query(qid, tracer)
         t0 = time.perf_counter()
         if bus is not None:
             bus.post(QueryEvent("queryStarted", qid, time.time()))
@@ -560,26 +577,29 @@ class QueryExecution:
             # the executed run's profile attributes its own miss
             self._rc_miss_pending = True
         try:
-            from contextlib import nullcontext
+            from ..obs.tracing import span_here
 
             parts = self.execute()
-            with tracer.span("collect", cat="phase") if tracer is not None \
-                    else nullcontext():
+            with span_here("collect", cat="phase"):
                 batches = [b for p in parts for b in p]
                 schema = attrs_schema(self.physical.output)
                 if not batches:
                     from ..columnar.batch import ColumnarBatch
 
                     batches = [ColumnarBatch.empty(schema)]
-                tables = [b.to_arrow() for b in batches]
-                try:
-                    # identical schemas concat fine even with duplicate
-                    # output names (legal, as in the reference); permissive
-                    # unify (which rejects duplicates) only for promotions
-                    out = pa.concat_tables(tables)
-                except pa.lib.ArrowInvalid:
-                    out = pa.concat_tables(tables,
-                                           promote_options="permissive")
+                with span_here("collect.d2h", cat="phase") as d2h:
+                    d2h.set_args(_planes_to_host(batches))
+                with span_here("collect.arrow", cat="phase"):
+                    tables = [b.to_arrow() for b in batches]
+                    try:
+                        # identical schemas concat fine even with
+                        # duplicate output names (legal, as in the
+                        # reference); permissive unify (which rejects
+                        # duplicates) only for promotions
+                        out = pa.concat_tables(tables)
+                    except pa.lib.ArrowInvalid:
+                        out = pa.concat_tables(
+                            tables, promote_options="permissive")
             limit = int(self.session.conf.get(MAX_RESULT_ROWS))
             if out.num_rows > limit:
                 raise RuntimeError(
@@ -819,6 +839,10 @@ class QueryExecution:
             ])
         if mode == "analyze":
             return self.analyzed_report().render()
+        if mode == "device":
+            from ..obs import device_profile
+
+            return device_profile.explain(self)
         parts = [
             "== Analyzed Logical Plan ==", self.analyzed.tree_string(),
             "== Optimized Logical Plan ==", self.optimized.tree_string(),

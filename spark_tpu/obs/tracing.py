@@ -33,6 +33,17 @@ Three cross-cutting mechanisms ride every span:
     tracks with the worker's identity so worker spans render as their
     own named tracks.
 
+  * the profiler's clock — every span also enters a
+    `jax.profiler.TraceAnnotation("st:" + name)` carrying the query id and
+    the span's scalar args as they are at entry. With no profiler session
+    that is a flag test; with one, the engine's spans lie on `/host:CPU`
+    beside the device's timeline, so an idle gap of the device can be
+    read off against what the engine was doing.
+
+`recorded_spans(t_from, t_to)` reads the spans of every live tracer of
+the process on the `time.perf_counter()` clock — what a benchmark's
+readers and an in-process debug endpoint call.
+
 Export is the Chrome trace-event format ("traceEvents" complete events,
 microsecond timestamps), loadable in Perfetto (ui.perfetto.dev) or
 chrome://tracing.
@@ -45,10 +56,14 @@ import json
 import threading
 import time
 import uuid
+import weakref
 from typing import Optional
 
 __all__ = ["Tracer", "current_flow", "current_query", "pop_query",
-           "push_query", "to_chrome_trace"]
+           "push_query", "recorded_spans", "span_here", "to_chrome_trace"]
+
+# prefix of the engine's spans in a profiler trace (`/host:CPU`)
+ANNOTATION_PREFIX = "st:"
 
 
 # ---------------------------------------------------------------------------
@@ -67,17 +82,36 @@ _FLOW: "contextvars.ContextVar" = contextvars.ContextVar(
     "spark_tpu_flow_scope", default=None)
 
 
-def push_query(query_id: str):
-    """Enter a query scope; returns the reset token for pop_query."""
-    return _QUERY.set(query_id)
+# the tracer of the query in scope, for code that no ExecContext reaches
+# (the process-global KernelCache): set beside the query tag, read by
+# span_here
+_TRACER: "contextvars.ContextVar" = contextvars.ContextVar(
+    "spark_tpu_tracer_scope", default=None)
+
+
+def push_query(query_id: str, tracer: "Tracer | None" = None):
+    """Enter a query scope (and, when given, its tracer's); returns the
+    reset token for pop_query."""
+    return (_QUERY.set(query_id),
+            None if tracer is None else _TRACER.set(tracer))
 
 
 def pop_query(token) -> None:
-    _QUERY.reset(token)
+    qtoken, ttoken = token
+    if ttoken is not None:
+        _TRACER.reset(ttoken)
+    _QUERY.reset(qtoken)
 
 
 def current_query() -> str | None:
     return _QUERY.get()
+
+
+def span_here(name: str, cat: str = "exec", args: Optional[dict] = None):
+    """A span on the tracer of the query in scope; a no-op outside a
+    query or with tracing off."""
+    tracer = _TRACER.get()
+    return _NULL_SPAN if tracer is None else tracer.span(name, cat, args)
 
 
 def current_flow() -> str | None:
@@ -103,10 +137,27 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotation(name: str, qid, args):
+    """The span as the profiler sees it: `st:<name>` with the query id
+    and the scalar args. Entered here, exited by the span."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    kw = {} if qid is None else {"query": qid}
+    if args:
+        kw.update((k, v) for k, v in args.items()
+                  if isinstance(v, (str, int, float)))
+    ann = _TraceAnnotation(ANNOTATION_PREFIX + name, **kw)
+    ann.__enter__()
+    return ann
+
 
 class _Span:
     __slots__ = ("tracer", "name", "cat", "args", "t0", "flow", "_ftoken",
-                 "_qid")
+                 "_qid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args,
                  flow: bool = False):
@@ -118,6 +169,7 @@ class _Span:
         self.flow = flow
         self._ftoken = None
         self._qid = None
+        self._ann = None
 
     def set_args(self, args) -> None:
         """Attach/merge args before exit (per-span kernel attribution)."""
@@ -138,8 +190,9 @@ class _Span:
                 args["flow_parent"] = parent
             self.set_args(args)
             self._ftoken = _FLOW.set(fid)
-        self.t0 = time.perf_counter()
         self._qid = _QUERY.get()
+        self._ann = _annotation(self.name, self._qid, self.args)
+        self.t0 = time.perf_counter()
         # live telemetry reads in-flight spans: register open, drop on
         # close (two dict ops per span — still pure host bookkeeping)
         self.tracer._open_add(self)
@@ -147,6 +200,7 @@ class _Span:
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
+        self._ann.__exit__(None, None, None)
         if self._ftoken is not None:
             _FLOW.reset(self._ftoken)
         self.tracer._open_remove(self)
@@ -195,6 +249,8 @@ class Tracer:
         self.anchor = (time.time(), time.perf_counter())
         # spans currently inside __enter__/__exit__ (live telemetry view)
         self._open: dict[int, "_Span"] = {}
+        with _TRACERS_LOCK:
+            _TRACERS.add(self)
 
     @property
     def enabled(self) -> bool:
@@ -341,6 +397,24 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(process_name), f)
         return path
+
+
+# every live tracer of the process, for recorded_spans
+_TRACERS: "weakref.WeakSet" = weakref.WeakSet()
+_TRACERS_LOCK = threading.Lock()
+
+
+def recorded_spans(t_from: float = float("-inf"),
+                   t_to: float = float("inf")) -> list[dict]:
+    """The spans of every live tracer of this process that started in
+    [t_from, t_to) on the `time.perf_counter()` clock, oldest first, as
+    the dicts `Tracer.since` gives (`ts` in seconds, `dur_ms`, `args`,
+    `query`). Spans a ring has evicted are gone."""
+    with _TRACERS_LOCK:
+        tracers = list(_TRACERS)
+    spans = [s for t in tracers for s in t.spans() if t_from <= s[2] < t_to]
+    spans.sort(key=lambda s: s[2])
+    return [Tracer._span_dict(s) for s in spans]
 
 
 def _flow_events(complete: list) -> list:

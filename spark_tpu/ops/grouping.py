@@ -28,6 +28,7 @@ class GroupLayout(NamedTuple):
     num_groups: jnp.ndarray  # int32 scalar — number of live groups
 
 
+@jax.named_scope("group_sort")
 def group_rows(key_cols: Sequence[jnp.ndarray],
                key_valids: Sequence[jnp.ndarray | None],
                row_mask: jnp.ndarray) -> GroupLayout:
@@ -60,6 +61,7 @@ def group_rows(key_cols: Sequence[jnp.ndarray],
     return GroupLayout(perm, seg_ids, start_flag, active, num_groups)
 
 
+@jax.named_scope("group_runs")
 def group_rows_presorted(key: jnp.ndarray, row_mask: jnp.ndarray
                          ) -> GroupLayout:
     """GroupLayout for a single key column whose values are ALREADY
@@ -87,6 +89,7 @@ def group_rows_presorted(key: jnp.ndarray, row_mask: jnp.ndarray
     return GroupLayout(pos, seg_ids, start_flag, row_mask, num_groups)
 
 
+@jax.named_scope("group_keys")
 def scatter_group_keys(layout: GroupLayout, key_col: jnp.ndarray,
                        key_valid: jnp.ndarray | None):
     """Gather each group's key value into output slot seg_id.
@@ -213,6 +216,7 @@ def seg_first(layout: GroupLayout, values: jnp.ndarray, valid=None):
 # HashAggregateExec kernels and the whole-stage fused kernels
 # (physical/fusion.py) so both paths reduce with byte-identical op code.
 
+@jax.named_scope("segment_reduce")
 def apply_group_ops(layout: GroupLayout, ops: Sequence[str], val_datas,
                     val_valids):
     """Sorted-segment reduce of each (op, values, validity) triple over a
@@ -246,6 +250,7 @@ def apply_group_ops(layout: GroupLayout, ops: Sequence[str], val_datas,
     return bufs
 
 
+@jax.named_scope("dense_reduce")
 def apply_dense_ops(seg, out_cap: int, cap: int, ops: Sequence[str],
                     val_datas, val_valids, live_mask):
     """Direct scatter reduce keyed by precomputed segment ids (dense-range
@@ -300,6 +305,7 @@ def apply_dense_ops(seg, out_cap: int, cap: int, ops: Sequence[str],
     return bufs
 
 
+@jax.named_scope("global_reduce")
 def apply_global_ops(ops: Sequence[str], val_datas, val_valids, row_mask):
     """Whole-tile (ungrouped) reduce. Returns [(scalar, has | None)]."""
     outs = []
@@ -352,6 +358,7 @@ def _min_ident(dtype):
     return jnp.asarray(jnp.iinfo(dtype).min, dtype)
 
 
+@jax.named_scope("percentile")
 def group_percentile(key_cols, key_valids, values, value_valid, row_mask,
                      q: float):
     """Exact per-group percentile: one sort by (keys, value) makes each

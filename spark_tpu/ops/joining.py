@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -34,6 +35,11 @@ class BuildSide(NamedTuple):
     perm: jnp.ndarray         # int32[Bcap] original row index per sorted slot
 
 
+# Scopes: each separate loop of the join carries its own `jax.named_scope`
+# (`build_sort`, `probe`, `expand`), so that a profile of a program that
+# traced these bodies says which of them the device is in.
+
+@jax.named_scope("build_sort")
 def build_index(key_cols: Sequence[jnp.ndarray],
                 key_valids: Sequence[jnp.ndarray | None],
                 row_mask: jnp.ndarray) -> BuildSide:
@@ -73,16 +79,30 @@ def probe_join(build: BuildSide,
     pcap = probe_mask.shape[0]
     oc = out_capacity
 
-    ph = hash_columns(probe_key_cols, list(probe_key_valids))
-    usable = probe_mask
-    for v in probe_key_valids:
-        if v is not None:
-            usable = usable & v
-    ph = jnp.where(usable, ph, I64_MAX - 1)  # sentinel that matches nothing
+    with jax.named_scope("probe"):
+        ph = hash_columns(probe_key_cols, list(probe_key_valids))
+        usable = probe_mask
+        for v in probe_key_valids:
+            if v is not None:
+                usable = usable & v
+        ph = jnp.where(usable, ph, I64_MAX - 1)  # sentinel: matches nothing
 
-    lo = jnp.searchsorted(build.sorted_hash, ph, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(build.sorted_hash, ph, side="right").astype(jnp.int32)
-    counts = jnp.where(usable, hi - lo, 0)
+        lo = jnp.searchsorted(build.sorted_hash, ph,
+                              side="left").astype(jnp.int32)
+        hi = jnp.searchsorted(build.sorted_hash, ph,
+                              side="right").astype(jnp.int32)
+        counts = jnp.where(usable, hi - lo, 0)
+    return _expand(build, build_key_cols, build_key_valids, probe_key_cols,
+                   probe_key_valids, probe_mask, oc, join_type, pcap, lo,
+                   counts)
+
+
+@jax.named_scope("expand")
+def _expand(build, build_key_cols, build_key_valids, probe_key_cols,
+            probe_key_valids, probe_mask, oc, join_type, pcap, lo,
+            counts) -> JoinResult:
+    """probe_join's second loop: the match ranges flattened into the
+    static-capacity output, each pair verified on the true keys."""
 
     # --- verify hash ranges by comparing true keys, count real matches ----
     # For semi/anti we must not rely on hash ranges alone. Verified counts
@@ -153,6 +173,7 @@ def probe_join(build: BuildSide,
     raise ValueError(f"unsupported join type {join_type}")
 
 
+@jax.named_scope("cross")
 def cross_join(probe_mask: jnp.ndarray, build_mask: jnp.ndarray,
                out_capacity: int) -> JoinResult:
     """Cartesian product (reference: CartesianProductExec). Build side is

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -45,6 +46,7 @@ def _directional(key: jnp.ndarray, ascending: bool) -> jnp.ndarray:
     return ~key
 
 
+@jax.named_scope("sort")
 def sort_permutation(keys: Sequence[jnp.ndarray],
                      valids: Sequence[jnp.ndarray | None],
                      specs: Sequence[SortKeySpec],

@@ -13,6 +13,9 @@ kernels across plan instances.
 from __future__ import annotations
 
 import collections
+import contextlib
+import contextvars
+import hashlib
 import threading
 from typing import Any, Callable, Sequence
 
@@ -27,6 +30,7 @@ from ..obs.metrics import (
     record_kernel_launch as _obs_launch,
     record_kernel_miss as _obs_miss,
 )
+from ..obs.tracing import span_here as _span_here
 from ..expr.expressions import (
     Alias, AttributeReference, Expression, Literal, SortOrder,
 )
@@ -35,7 +39,84 @@ from ..utils import faults as _faults
 
 __all__ = ["canonical_key", "KernelCache", "ExprPipeline", "bind_inputs",
             "broadcast_to_cap", "trace_pipeline", "pipeline_host_pass",
-            "pipeline_signature", "pipeline_columns"]
+            "pipeline_signature", "pipeline_columns", "named_jit",
+            "capture_programs", "note_program", "module_name"]
+
+
+# ---------------------------------------------------------------------------
+# Stable program names
+# ---------------------------------------------------------------------------
+
+# Part of every named program's hash. jax's persistent-cache key leaves op
+# metadata out (jax_compilation_cache_include_metadata_in_key), so a
+# program whose `jax.named_scope` labels changed is a cache HIT on the old
+# executable with the old `op_name`s. Bump this when the scopes inside the
+# kernel bodies (ops/sorting, ops/joining, ops/grouping, the whole-query
+# emits) are renamed, so that the next run compiles afresh.
+SCOPES_VERSION = 1
+
+
+def named_jit(kind: str, key, fn, labels: Sequence = (), **jit_kwargs):
+    """`jax.jit(fn)` under the name `<kind>_<sha1 of (SCOPES_VERSION, key,
+    labels)>[:10]`, so that the XLA module is `jit_<kind>_<hash>` in a
+    profiler trace and in the compiled text. `key` is the program's
+    KernelCache key and `labels` its scope labels (the operator rows):
+    both are tuples of strings and numbers whose `repr` is the same in
+    every process — no `hash()`, no `id()` — because XLA's disk-cache key
+    includes the module name, and a name that moved would recompile
+    every program on every start."""
+    import jax
+
+    digest = hashlib.sha1(repr(
+        (SCOPES_VERSION, key, tuple(labels))).encode()).hexdigest()[:10]
+    fn.__name__ = fn.__qualname__ = f"{kind}_{digest}"
+    return jax.jit(fn, **jit_kwargs)  # tpulint: ignore[raw-jit]
+
+
+def module_name(kernel) -> str | None:
+    """The XLA module a KernelCache kernel runs as (`jit_<name>`), which
+    is how a profiler trace names it."""
+    name = getattr(getattr(kernel, "_kernel", kernel), "__name__", None)
+    return None if name is None else "jit_" + name
+
+
+# programs launched while a capture is open: explain("device") and the
+# tests ask which programs a query ran, with what to compile them again
+_CAPTURE: "contextvars.ContextVar" = contextvars.ContextVar(
+    "spark_tpu_program_capture", default=None)
+
+
+@contextlib.contextmanager
+def capture_programs():
+    """Collect a record of every named program launched in this context:
+    {program, members, scopes, kernel, args} with `args` reduced to
+    shapes, so that `kernel._kernel.lower(*args)` gives the program's
+    text again without holding a plane."""
+    got: list = []
+    token = _CAPTURE.set(got)
+    try:
+        yield got
+    finally:
+        _CAPTURE.reset(token)
+
+
+def note_program(kernel, args: tuple, members: list, scopes: list) -> None:
+    """One launch for an open capture; a contextvar read otherwise."""
+    got = _CAPTURE.get()
+    if got is None:
+        return
+    import jax
+
+    # a plane on one device goes in as the call gives it, with no
+    # sharding: the lowering is then the one the call compiled, and
+    # compiling it again is a cache hit and not a second compile
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=a.sharding if len(a.sharding.device_set) > 1
+            else None), args)
+    got.append({"program": module_name(kernel), "members": list(members),
+                "scopes": list(scopes), "kernel": kernel, "args": shapes})
 
 
 # ---------------------------------------------------------------------------
@@ -239,7 +320,10 @@ class KernelCache:
                 # BEFORE the dispatch so even the first launch
                 # attributes cost (host-side trace/lower only — no
                 # kernel launch, no device sync)
-                cost = _capture_kernel_cost(f, args, kwargs)
+                with _span_here("kernel.cost_capture", "compile",
+                                {"kind": str(kind),
+                                 "program": module_name(f)}):
+                    cost = _capture_kernel_cost(f, args, kwargs)
                 with self._lock:
                     state["cost"] = cost
                     state["capturing"] = False
@@ -277,9 +361,13 @@ class KernelCache:
 
                 d0 = _pc.DISK_HITS
                 t0 = _time.perf_counter()
-                out = call(*args, **kwargs)
+                with _span_here("kernel.first_launch", "compile",
+                                {"kind": str(kind),
+                                 "program": module_name(f)}) as sp:
+                    out = call(*args, **kwargs)
+                    disk_hit = _pc.DISK_HITS > d0
+                    sp.set_args({"disk_hit": disk_hit})
                 dt = (_time.perf_counter() - t0) * 1000
-                disk_hit = _pc.DISK_HITS > d0
                 with self._lock:
                     self.compile_ms += dt
                     if disk_hit:

@@ -39,7 +39,9 @@ from __future__ import annotations
 from ..columnar.batch import Column, ColumnarBatch, bucket_capacity
 from ..errors import ExecutionError
 from ..types import BooleanType, dict_encoded
-from .compile import GLOBAL_KERNEL_CACHE
+from .compile import (
+    GLOBAL_KERNEL_CACHE, module_name, named_jit, note_program,
+)
 from .operators import attrs_schema
 from .whole_query import (
     _MAX_PROGRAM_RETRIES, _Collect, _Lowered, _MCol, _ProgramBuilder,
@@ -181,7 +183,12 @@ class _MeshProgramBuilder(_ProgramBuilder):
         return out
 
     # -- dispatch ----------------------------------------------------------
-    def lower(self, node) -> _Lowered:
+    def _scoped(self, low: _Lowered, label: str) -> _Lowered:
+        out = super()._scoped(low, label)
+        self._set_form(out, self._form(low))
+        return out
+
+    def _lower_node(self, node) -> _Lowered:
         from . import operators as O
         from .exchange import BroadcastExchangeExec, ShuffleExchangeExec
         from .fusion import FusedAggregateExec, FusedLimitExec
@@ -246,7 +253,7 @@ class _MeshProgramBuilder(_ProgramBuilder):
             return self._lower_mesh_exchange(node)
         if isinstance(node, BroadcastExchangeExec):
             low = self._to_rep(self.lower(node.child))
-            self.members.append("BroadcastExchange -> replicated gather")
+            self._member(node, "BroadcastExchange -> replicated gather")
             return low
         if isinstance(node, O.CoalescePartitionsExec):
             return self.lower(node.child)
@@ -424,8 +431,8 @@ class _MeshProgramBuilder(_ProgramBuilder):
         # range/single/round-robin: the downstream consumer re-groups,
         # re-sorts or reduces globally anyway — gather to the replicated
         # flow (the single-device whole tier's in-program gather)
-        self.members.append(
-            f"Exchange[{type(p).__name__}] -> in-program gather")
+        self._member(node,
+                     f"Exchange[{type(p).__name__}] -> in-program gather")
         self.key.append(("xgather",))
         return self._to_rep(low)
 
@@ -445,8 +452,8 @@ class _MeshProgramBuilder(_ProgramBuilder):
         out_cap = P * quota
         self.key.append(("mxchg", xid, quota, kidx, bools,
                          tuple(x[1] for x in luts)))
-        self.members.append(
-            "Exchange[HashPartitioning] -> in-program all_to_all")
+        self._member(
+            node, "Exchange[HashPartitioning] -> in-program all_to_all")
         self.x_ids.append(xid)
 
         def emit(args, needed, _low=low):
@@ -487,8 +494,8 @@ class _MeshProgramBuilder(_ProgramBuilder):
         P, axis = self.P, self.axis
         self.key.append(("mxlocal", kidx, bools,
                          tuple(x[1] for x in luts)))
-        self.members.append(
-            "Exchange[HashPartitioning] -> in-program pid filter")
+        self._member(
+            node, "Exchange[HashPartitioning] -> in-program pid filter")
 
         def emit(args, needed, _low=low):
             from jax import lax
@@ -599,16 +606,14 @@ class _MeshProgramBuilder(_ProgramBuilder):
 # program compilation
 # ---------------------------------------------------------------------------
 
-def _build_mesh_program(b: _MeshProgramBuilder, root: _Lowered):
-    """jit(shard_map(local program)). The local function reassembles the
+def _build_mesh_program(b: _MeshProgramBuilder, root: _Lowered, key: tuple):
+    """jit(shard_map(local program)), named from its cache key. The local function reassembles the
     flat args list from the (donated, kept) buckets, emits the whole
     lowered tree per shard, and centrally reduces every verdict scalar
     (max'd join `needed`s and guards, min/max'd spans; overflows are
     already psum'd) so the host reads ONE value per check after the
     single dispatch. Outputs are replicated (the root is gathered), so
     check_vma=False with P() out_specs is sound by construction."""
-    import jax
-
     from ..parallel import mesh_fusion as MF
     from jax import shard_map
 
@@ -663,8 +668,8 @@ def _build_mesh_program(b: _MeshProgramBuilder, root: _Lowered):
         return f(don, keep)
 
     donate = MF.DONATE_DEFAULT and not b.use_base and len(don_specs) > 0
-    return jax.jit(sharded,  # tpulint: ignore[raw-jit]
-                   donate_argnums=(0,) if donate else ())
+    return named_jit("mesh_whole", key, sharded, labels=b.scopes,
+                     donate_argnums=(0,) if donate else ())
 
 
 # ---------------------------------------------------------------------------
@@ -688,8 +693,6 @@ class MeshWholeQueryExec(WholeQueryExec):
                 f"({self.decision.reason[:60]})")
 
     def _execute_whole(self, ctx) -> list:
-        from contextlib import nullcontext
-
         from ..config import DEVICE_MESH_AXIS
         from ..parallel.mesh_exchange import _get_mesh
         from ..parallel.mesh_fusion import (
@@ -699,14 +702,7 @@ class MeshWholeQueryExec(WholeQueryExec):
         P = int(self.decision.details.get("mesh_devices") or 0)
         axis = str(ctx.conf.get(DEVICE_MESH_AXIS))
         mesh = _get_mesh(P, axis)
-        tracer = getattr(ctx, "tracer", None)
-        span = tracer.span("whole_query.program", cat="operator",
-                           args={"tier": "mesh-whole",
-                                 "reason": self.decision.reason,
-                                 **{k: v for k, v in
-                                    self.decision.details.items()
-                                    if isinstance(v, (int, float, str))}}) \
-            if tracer is not None else nullcontext()
+        span, sub = self._program_span(ctx, "mesh-whole")
         seed_rec = getattr(ctx, "persist_seed", None) or {}
         join_caps: list[int] = [int(c) for c in
                                 (seed_rec.get("join_caps") or ())]
@@ -724,94 +720,110 @@ class MeshWholeQueryExec(WholeQueryExec):
         try:
             with span:
                 while rounds < _MAX_PROGRAM_RETRIES:
-                    b = _MeshProgramBuilder(
-                        ctx, join_caps, spans_seed=spans_seed,
-                        dense_off=dense_off, mesh=mesh, axis=axis,
-                        num_shards=P, quotas=quotas,
-                        mesh_seed=mesh_seed, leaf_cache=leaf_cache,
-                        use_base=use_base, gang=gang)
-                    gang = False
-                    root = b._to_rep(b.lower(self.plan))
-                    key = ("mesh_whole", axis, P,
-                           "base" if use_base else "don",
-                           tuple(b.key))
-                    kernel = GLOBAL_KERNEL_CACHE.get_or_build(
-                        key, lambda _b=b, _r=root:
-                        _build_mesh_program(_b, _r))
-                    staged = StagedBuffers(b.staged)
-                    don_args, keep_args = b.split_args()
-                    try:
-                        with expected_donation_residue():
-                            (datas, valids, mask, needed, ovfs, spans,
-                             guards) = kernel(don_args, keep_args)
-                    except Exception as e:
-                        staged.release_all()
-                        if not is_runtime_fault(e) or gang_left <= 0:
-                            raise
-                        # gang retry: ONE fresh attempt. Base planes are
-                        # undonated by contract — the rebuilt program
-                        # reuses them after _planes_alive proves it
-                        # (whole_query.mesh_gang_base_reused)
-                        gang_left -= 1
-                        gang = True
+                    with sub("whole_query.attempt",
+                             lambda: self._attempt_args(rounds,
+                                                        join_caps)) as att:
+                        with sub("whole_query.lower"):
+                            b = _MeshProgramBuilder(
+                                ctx, join_caps, spans_seed=spans_seed,
+                                dense_off=dense_off, mesh=mesh, axis=axis,
+                                num_shards=P, quotas=quotas,
+                                mesh_seed=mesh_seed, leaf_cache=leaf_cache,
+                                use_base=use_base, gang=gang)
+                            gang = False
+                            root = b._to_rep(b.lower(self.plan))
+                            key = ("mesh_whole", axis, P,
+                                   "base" if use_base else "don",
+                                   tuple(b.key))
+                        with sub("whole_query.launch") as launch:
+                            kernel = GLOBAL_KERNEL_CACHE.get_or_build(
+                                key, lambda _b=b, _r=root, _k=key:
+                                _build_mesh_program(_b, _r, _k))
+                            launch.set_args(
+                                {"program": module_name(kernel)})
+                            staged = StagedBuffers(b.staged)
+                            don_args, keep_args = b.split_args()
+                            note_program(kernel, (don_args, keep_args),
+                                         b.members, b.scopes)
+                            try:
+                                with expected_donation_residue():
+                                    (datas, valids, mask, needed, ovfs,
+                                     spans, guards) = kernel(don_args,
+                                                             keep_args)
+                            except Exception as e:
+                                staged.release_all()
+                                if not is_runtime_fault(e) \
+                                        or gang_left <= 0:
+                                    raise
+                                # gang retry: ONE fresh attempt. Base
+                                # planes are undonated by contract — the
+                                # rebuilt program reuses them after
+                                # _planes_alive proves it
+                                # (whole_query.mesh_gang_base_reused)
+                                gang_left -= 1
+                                gang = True
+                                use_base = True
+                                ctx.metrics.add(
+                                    "whole_query.mesh_gang_retries")
+                                att.set_args(
+                                    {"program": module_name(kernel),
+                                     "discarded": True})
+                                continue
+                            staged.release_consumed()
+                        # the round's ONE verdict: every capacity scalar
+                        # of the single dispatch, applied together
+                        with sub("whole_query.verdict"):
+                            bumped = False
+                            for i, nd in enumerate(needed):
+                                n_i = int(nd)  # tpulint: ignore[host-sync]
+                                if n_i > join_caps[i]:
+                                    join_caps[i] = bucket_capacity(n_i)
+                                    bumped = True
+                            for xid, o in zip(b.x_ids, ovfs):
+                                if int(o) > 0:  # tpulint: ignore[host-sync]
+                                    quotas[xid] = quotas[xid] * 2
+                                    ctx.metrics.add(
+                                        "mesh_whole.quota_retries")
+                                    bumped = True
+                            for jid, g in zip(b.guard_jids, guards):
+                                if int(g):  # tpulint: ignore[host-sync]
+                                    dense_off.add(jid)
+                                    ctx.metrics.add(
+                                        "whole_query.dense_guard_retries")
+                                    bumped = True
+                        att.set_args({"program": module_name(kernel),
+                                      "discarded": bumped})
+                    if bumped:
+                        rounds += 1
                         use_base = True
-                        ctx.metrics.add("whole_query.mesh_gang_retries")
                         continue
-                    staged.release_consumed()
-                    # the round's ONE verdict: every capacity scalar of
-                    # the single dispatch, applied together
-                    bumped = False
-                    for i, nd in enumerate(needed):
-                        n_i = int(nd)  # tpulint: ignore[host-sync]
-                        if n_i > join_caps[i]:
-                            join_caps[i] = bucket_capacity(n_i)
-                            bumped = True
-                    for xid, o in zip(b.x_ids, ovfs):
-                        if int(o) > 0:  # tpulint: ignore[host-sync]
-                            quotas[xid] = quotas[xid] * 2
-                            ctx.metrics.add(
-                                "mesh_whole.quota_retries")
-                            bumped = True
-                    for jid, g in zip(b.guard_jids, guards):
-                        if int(g):  # tpulint: ignore[host-sync]
-                            dense_off.add(jid)
-                            ctx.metrics.add(
-                                "whole_query.dense_guard_retries")
-                            bumped = True
-                    if not bumped:
-                        if rounds:
-                            ctx.metrics.add(
-                                "whole_query.capacity_retries", rounds)
-                        ctx.metrics.add("whole_query.dispatches",
-                                        rounds + 1)
-                        ctx.metrics.add("mesh_whole.dispatches",
-                                        rounds + 1)
-                        if join_caps:
-                            ctx.persist_join_caps = list(join_caps)
-                        if b.quota_keys:
-                            prior = getattr(ctx, "persist_mesh_quotas",
-                                            None) or {}
-                            ctx.persist_mesh_quotas = {
-                                **prior,
-                                **{mk: int(quotas[x])  # tpulint: ignore[host-sync]
-                                   for x, mk in b.quota_keys.items()}}
-                        if b.dense_joins:
-                            ctx.metrics.add("whole_query.dense_probe",
-                                            len(b.dense_joins))
-                        _record_spans(ctx, b, spans, len(join_caps))
-                        schema = attrs_schema(self.output)
-                        cols = [Column(f.dataType, d, v,
-                                       m.sdict
-                                       if dict_encoded(f.dataType)
-                                       else None)
-                                for f, d, v, m in
-                                zip(schema.fields, datas, valids,
-                                    root.metas)]
-                        batch = ColumnarBatch(schema, cols, mask,
-                                              num_rows=None)
-                        return [[batch]]
-                    rounds += 1
-                    use_base = True
+                    if rounds:
+                        ctx.metrics.add(
+                            "whole_query.capacity_retries", rounds)
+                    ctx.metrics.add("whole_query.dispatches", rounds + 1)
+                    ctx.metrics.add("mesh_whole.dispatches", rounds + 1)
+                    if join_caps:
+                        ctx.persist_join_caps = list(join_caps)
+                    if b.quota_keys:
+                        prior = getattr(ctx, "persist_mesh_quotas",
+                                        None) or {}
+                        ctx.persist_mesh_quotas = {
+                            **prior,
+                            **{mk: int(quotas[x])  # tpulint: ignore[host-sync]
+                               for x, mk in b.quota_keys.items()}}
+                    if b.dense_joins:
+                        ctx.metrics.add("whole_query.dense_probe",
+                                        len(b.dense_joins))
+                    _record_spans(ctx, b, spans, len(join_caps))
+                    schema = attrs_schema(self.output)
+                    cols = [Column(f.dataType, d, v,
+                                   m.sdict if dict_encoded(f.dataType)
+                                   else None)
+                            for f, d, v, m in
+                            zip(schema.fields, datas, valids, root.metas)]
+                    batch = ColumnarBatch(schema, cols, mask,
+                                          num_rows=None)
+                    return [[batch]]
                 raise ExecutionError(
                     "mesh whole-query program exceeded its retry budget "
                     f"({_MAX_PROGRAM_RETRIES}) — report this plan")

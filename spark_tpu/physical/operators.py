@@ -288,8 +288,19 @@ class LocalTableScanExec(PhysicalPlan):
             return [hit]
         tbl = self.table.select(list(names)) if self.table.num_columns \
             else self.table
-        batches = list(table_to_batches(tbl, ctx.conf.batch_capacity,
-                                        attrs_schema(self.attrs)))
+        # the table's planes go to the device here, once: host
+        # conversion, padding and the enqueue of each copy (the copies
+        # themselves are asynchronous; the first program waits for them)
+        from ..obs.tracing import span_here
+
+        with span_here("ingest.h2d", cat="operator") as sp:
+            batches = list(table_to_batches(tbl, ctx.conf.batch_capacity,
+                                            attrs_schema(self.attrs)))
+            sp.set_args({
+                "bytes": sum(b.device_nbytes() for b in batches),
+                "planes": sum(1 + sum(1 + (c.validity is not None)
+                                      for c in b.columns)
+                              for b in batches)})
         entry["batches"][key] = batches
         return [batches]
 

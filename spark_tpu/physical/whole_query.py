@@ -68,8 +68,8 @@ from ..expr.expressions import Alias, AttributeReference
 from ..types import BooleanType, StringType, dict_encoded
 from .aggregates import FUSABLE_OPS
 from .compile import (
-    GLOBAL_KERNEL_CACHE, bind_inputs, canonical_key, pipeline_host_pass,
-    trace_pipeline,
+    GLOBAL_KERNEL_CACHE, bind_inputs, canonical_key, module_name, named_jit,
+    note_program, pipeline_host_pass, trace_pipeline,
 )
 from .operators import PhysicalPlan, attrs_schema
 
@@ -592,6 +592,11 @@ class _ProgramBuilder:
         # across the retry loop: a bumped bucket re-enters here)
         self._join_seq = 0
         self.members: list[str] = []   # lowered ops, produce->consume order
+        # per members row, the `jax.named_scope` its own work traces
+        # under (`m<row>.<Kind>`), or None for a row that lowers to
+        # nothing of its own; _member_of finds a node's row again
+        self.scopes: list = []
+        self._member_of: dict[int, int] = {}
         # warm-start build-side key spans ([lo, hi, unique] per join id,
         # from the persistent manifest) and the joins whose seeded span
         # the data contradicted this run (guard-verdict retry state)
@@ -608,13 +613,40 @@ class _ProgramBuilder:
         self.args.append(arr)
         return len(self.args) - 1
 
-    def _member(self, node) -> None:
-        s = node.simple_string() if hasattr(node, "simple_string") \
-            else type(node).__name__
-        self.members.append(s[:100])
+    def _member(self, node, text: Optional[str] = None,
+                scoped: bool = True) -> None:
+        """One row of `members` for `node`, and the scope label that
+        lower() puts the node's work under."""
+        if text is None:
+            text = node.simple_string() if hasattr(node, "simple_string") \
+                else type(node).__name__
+        kind = type(node).__name__.removesuffix("Exec")
+        if scoped:
+            self._member_of[id(node)] = len(self.members)
+        self.scopes.append(f"m{len(self.members):02d}.{kind}"
+                           if scoped else None)
+        self.members.append(text[:100])
 
     # -- dispatch ----------------------------------------------------------
     def lower(self, node) -> _Lowered:
+        """Lower `node`, with everything its emit traces under the
+        node's scope label. Children emit inside their parent's scope,
+        so an instruction's operator is the INNERMOST `mNN.` component
+        of its op_name. Scopes exist at trace time only."""
+        low = self._lower_node(node)
+        row = self._member_of.get(id(node))
+        return low if row is None else self._scoped(low, self.scopes[row])
+
+    def _scoped(self, low: _Lowered, label: str) -> _Lowered:
+        import jax
+
+        def emit(args, needed, _emit=low.emit):
+            with jax.named_scope(label):
+                return _emit(args, needed)
+
+        return _Lowered(low.metas, low.cap, emit)
+
+    def _lower_node(self, node) -> _Lowered:
         from ..exec.scheduler import _StageOutput
         from . import operators as O
         from .exchange import BroadcastExchangeExec, ShuffleExchangeExec
@@ -665,13 +697,16 @@ class _ProgramBuilder:
                 filters, outputs = node.pipe_fusion
                 low = self._lower_pipe(filters, outputs, node.child.output,
                                        node.pipe_attrs, low)
-            self.members.append(
-                f"Exchange[{type(node.partitioning).__name__}] -> "
-                "in-program gather")
+            # scoped only for the fused map-side pipeline's sake
+            self._member(node,
+                         f"Exchange[{type(node.partitioning).__name__}] -> "
+                         "in-program gather",
+                         scoped=node.pipe_fusion is not None)
             self.key.append(("xgather",))
             return low
         if isinstance(node, BroadcastExchangeExec):
-            self.members.append("BroadcastExchange -> in-program identity")
+            self._member(node, "BroadcastExchange -> in-program identity",
+                         scoped=False)
             return self.lower(node.child)
         if isinstance(node, O.CoalescePartitionsExec):
             return self.lower(node.child)
@@ -984,6 +1019,8 @@ class _ProgramBuilder:
                         for i in kidx)
 
         def emit(args, needed, _low=low):
+            import jax
+
             from ..ops.sorting import sort_permutation
 
             d, v, m = _low.emit(args, needed)
@@ -998,9 +1035,11 @@ class _ProgramBuilder:
                 keys.append(kd)
                 kvalids.append(v[i])
             perm = sort_permutation(keys, kvalids, specs_t, m)
-            out_d = [jnp.take(x, perm) for x in d]
-            out_v = [None if x is None else jnp.take(x, perm) for x in v]
-            return out_d, out_v, jnp.take(m, perm)
+            with jax.named_scope("gather"):
+                out_d = [jnp.take(x, perm) for x in d]
+                out_v = [None if x is None else jnp.take(x, perm)
+                         for x in v]
+                return out_d, out_v, jnp.take(m, perm)
 
         return _Lowered(low.metas, low.cap, emit)
 
@@ -1118,6 +1157,8 @@ class _ProgramBuilder:
             return eqs, valids
 
         def emit(args, needed, _probe=probe, _build=build, _oc=out_cap):
+            import jax
+
             from ..ops import joining as J
 
             pd, pv, pm = _probe.emit(args, needed)
@@ -1132,30 +1173,32 @@ class _ProgramBuilder:
                 # observe the build-key span + uniqueness so the NEXT
                 # same-fingerprint run (via the warm-start manifest)
                 # compiles the dense direct-address variant directly
-                bk = beqs[0].astype(jnp.int64)
-                blive = bm if bvalids[0] is None else (bm & bvalids[0])
-                big = jnp.int64(1) << 62
-                lo_o = jnp.min(jnp.where(blive, bk, big))
-                hi_o = jnp.max(jnp.where(blive, bk, -big))
-                sk = jnp.sort(jnp.where(blive, bk, big))
-                dup = jnp.any((sk[1:] == sk[:-1]) & (sk[:-1] != big)) \
-                    if sk.shape[0] > 1 else jnp.asarray(False)
-                needed.spans.append((lo_o, hi_o, dup.astype(jnp.int32)))
-            if semi_anti:
+                with jax.named_scope("span_observe"):
+                    bk = beqs[0].astype(jnp.int64)
+                    blive = bm if bvalids[0] is None \
+                        else (bm & bvalids[0])
+                    big = jnp.int64(1) << 62
+                    lo_o = jnp.min(jnp.where(blive, bk, big))
+                    hi_o = jnp.max(jnp.where(blive, bk, -big))
+                    sk = jnp.sort(jnp.where(blive, bk, big))
+                    dup = jnp.any((sk[1:] == sk[:-1])
+                                  & (sk[:-1] != big)) \
+                        if sk.shape[0] > 1 else jnp.asarray(False)
+                    needed.spans.append(
+                        (lo_o, hi_o, dup.astype(jnp.int32)))
+            with jax.named_scope("gather"):
                 datas = [jnp.take(x, r.probe_idx) for x in pd]
-                valids = [None if x is None else jnp.take(x, r.probe_idx)
-                          for x in pv]
+                valids = [None if x is None
+                          else jnp.take(x, r.probe_idx) for x in pv]
+                if semi_anti:
+                    return datas, valids, r.out_mask
+                null_build = ~r.matched
+                for x, xv in zip(bd, bv):
+                    datas.append(jnp.take(x, r.build_idx))
+                    base = jnp.take(xv, r.build_idx) if xv is not None \
+                        else jnp.ones(_oc, dtype=bool)
+                    valids.append(base & ~null_build)
                 return datas, valids, r.out_mask
-            datas = [jnp.take(x, r.probe_idx) for x in pd]
-            valids = [None if x is None else jnp.take(x, r.probe_idx)
-                      for x in pv]
-            null_build = ~r.matched
-            for x, xv in zip(bd, bv):
-                datas.append(jnp.take(x, r.build_idx))
-                base = jnp.take(xv, r.build_idx) if xv is not None \
-                    else jnp.ones(_oc, dtype=bool)
-                valids.append(base & ~null_build)
-            return datas, valids, r.out_mask
 
         return _Lowered(metas, out_cap, emit)
 
@@ -1424,19 +1467,41 @@ class WholeQueryExec(PhysicalPlan):
             # double-count the stage tier's launches in kernel.* metrics
             return DAGScheduler(ctx)._run(self.plan)
 
-    def _execute_whole(self, ctx) -> list:
-        import jax
+    def _program_span(self, ctx, tier: str):
+        """The `whole_query.program` span and a maker of the spans under
+        it (`args` may be a callable, called only when a span is made);
+        with tracing off both are no-ops and no args are built."""
+        from ..obs.tracing import _NULL_SPAN
 
         tracer = getattr(ctx, "tracer", None)
-        from contextlib import nullcontext
+        if tracer is None:
+            return _NULL_SPAN, lambda name, args=None: _NULL_SPAN
+        span = tracer.span(
+            "whole_query.program", cat="operator",
+            args={"tier": tier, "reason": self.decision.reason,
+                  **{k: v for k, v in self.decision.details.items()
+                     if isinstance(v, (int, float, str))}})
+        return span, lambda name, args=None: tracer.span(
+            name, cat="operator", args=args() if callable(args) else args)
 
-        span = tracer.span("whole_query.program", cat="operator",
-                           args={"tier": "whole",
-                                 "reason": self.decision.reason,
-                                 **{k: v for k, v in
-                                    self.decision.details.items()
-                                    if isinstance(v, (int, float, str))}}) \
-            if tracer is not None else nullcontext()
+    def _attempt_args(self, attempt: int, join_caps: list) -> dict:
+        """What an attempt starts from: its capacities, and the three
+        byte counts side by side — the tier chooser's estimate, the
+        engine's ledger and the device's own. None of them syncs."""
+        import jax
+
+        from ..obs.resources import GLOBAL_LEDGER
+
+        stats = jax.devices()[0].memory_stats() or {}
+        return {"attempt": attempt,
+                "join_caps": ",".join(str(c) for c in join_caps),
+                "est_resident_bytes":
+                    self.decision.details.get("est_resident_bytes", -1),
+                "device_bytes_in_use": stats.get("bytes_in_use", -1),
+                "ledger_bytes": GLOBAL_LEDGER.bytes}
+
+    def _execute_whole(self, ctx) -> list:
+        span, sub = self._program_span(ctx, "whole")
         # warm-start seeding (exec/persist_cache.py): a prior same-
         # fingerprint run's FINAL join output capacities ride the
         # persistent manifest back onto this process's first attempt, so
@@ -1454,64 +1519,80 @@ class WholeQueryExec(PhysicalPlan):
         dense_off: set[int] = set()
         with span:
             for attempt in range(_MAX_PROGRAM_RETRIES):
-                b = _ProgramBuilder(ctx, join_caps,
-                                    spans_seed=spans_seed,
-                                    dense_off=dense_off)
-                root = b.lower(self.plan)
-                key = ("whole_query", tuple(b.key))
+                with sub("whole_query.attempt",
+                         lambda: self._attempt_args(attempt, join_caps)) \
+                        as att:
+                    with sub("whole_query.lower"):
+                        b = _ProgramBuilder(ctx, join_caps,
+                                            spans_seed=spans_seed,
+                                            dense_off=dense_off)
+                        root = b.lower(self.plan)
+                        key = ("whole_query", tuple(b.key))
 
-                def build(_root=root, _nargs=len(b.args)):
-                    def program(args):
-                        needed = _Collect()
-                        datas, valids, mask = _root.emit(args, needed)
-                        return (datas, valids, mask, tuple(needed),
-                                tuple(needed.spans),
-                                tuple(needed.guards))
+                    def build(_root=root, _key=key, _scopes=b.scopes):
+                        def program(args):
+                            needed = _Collect()
+                            datas, valids, mask = _root.emit(args, needed)
+                            return (datas, valids, mask, tuple(needed),
+                                    tuple(needed.spans),
+                                    tuple(needed.guards))
 
-                    return jax.jit(program)
+                        return named_jit("whole_query", _key, program,
+                                         labels=_scopes)
 
-                kernel = GLOBAL_KERNEL_CACHE.get_or_build(key, build)
-                datas, valids, mask, needed, spans, guards = \
-                    kernel(b.args)
-                # the program's ONE capacity verdict: join `needed`
-                # scalars sync after the single dispatch (the query's
-                # last device interaction before collect)
-                bumped = False
-                for i, nd in enumerate(needed):
-                    n_i = int(nd)  # tpulint: ignore[host-sync]
-                    if n_i > join_caps[i]:
-                        join_caps[i] = bucket_capacity(n_i)
-                        bumped = True
-                # dense-probe guards: the seeded span no longer covers
-                # the build rows (data drifted under the fingerprint) —
-                # drop the dense variant for that join and re-lower
-                for jid, g in zip(b.guard_jids, guards):
-                    if int(g):  # tpulint: ignore[host-sync]
-                        dense_off.add(jid)
-                        ctx.metrics.add("whole_query.dense_guard_retries")
-                        bumped = True
-                if not bumped:
-                    if attempt:
-                        ctx.metrics.add("whole_query.capacity_retries",
-                                        attempt)
-                    ctx.metrics.add("whole_query.dispatches", attempt + 1)
-                    if join_caps:
-                        # capacity outcomes for the warm-start manifest
-                        # (QueryExecution writes it at query close)
-                        ctx.persist_join_caps = list(join_caps)
-                    if b.dense_joins:
-                        ctx.metrics.add("whole_query.dense_probe",
-                                        len(b.dense_joins))
-                    _record_spans(ctx, b, spans, len(join_caps))
-                    schema = attrs_schema(self.output)
-                    cols = [Column(f.dataType, d, v,
-                                   m.sdict if dict_encoded(f.dataType)
-                                   else None)
-                            for f, d, v, m in zip(schema.fields, datas,
-                                                  valids, root.metas)]
-                    batch = ColumnarBatch(schema, cols, mask,
-                                          num_rows=None)
-                    return [[batch]]
+                    with sub("whole_query.launch") as launch:
+                        kernel = GLOBAL_KERNEL_CACHE.get_or_build(key,
+                                                                  build)
+                        launch.set_args({"program": module_name(kernel)})
+                        note_program(kernel, (b.args,), b.members,
+                                     b.scopes)
+                        datas, valids, mask, needed, spans, guards = \
+                            kernel(b.args)
+                    # the program's ONE capacity verdict: join `needed`
+                    # scalars sync after the single dispatch (the query's
+                    # last device interaction before collect), so this
+                    # span is the host's view of the program's device time
+                    with sub("whole_query.verdict"):
+                        bumped = False
+                        for i, nd in enumerate(needed):
+                            n_i = int(nd)  # tpulint: ignore[host-sync]
+                            if n_i > join_caps[i]:
+                                join_caps[i] = bucket_capacity(n_i)
+                                bumped = True
+                        # dense-probe guards: the seeded span no longer
+                        # covers the build rows (data drifted under the
+                        # fingerprint) — drop the dense variant for that
+                        # join and re-lower
+                        for jid, g in zip(b.guard_jids, guards):
+                            if int(g):  # tpulint: ignore[host-sync]
+                                dense_off.add(jid)
+                                ctx.metrics.add(
+                                    "whole_query.dense_guard_retries")
+                                bumped = True
+                    att.set_args({"program": module_name(kernel),
+                                  "discarded": bumped})
+                if bumped:
+                    continue
+                if attempt:
+                    ctx.metrics.add("whole_query.capacity_retries",
+                                    attempt)
+                ctx.metrics.add("whole_query.dispatches", attempt + 1)
+                if join_caps:
+                    # capacity outcomes for the warm-start manifest
+                    # (QueryExecution writes it at query close)
+                    ctx.persist_join_caps = list(join_caps)
+                if b.dense_joins:
+                    ctx.metrics.add("whole_query.dense_probe",
+                                    len(b.dense_joins))
+                _record_spans(ctx, b, spans, len(join_caps))
+                schema = attrs_schema(self.output)
+                cols = [Column(f.dataType, d, v,
+                               m.sdict if dict_encoded(f.dataType)
+                               else None)
+                        for f, d, v, m in zip(schema.fields, datas,
+                                              valids, root.metas)]
+                batch = ColumnarBatch(schema, cols, mask, num_rows=None)
+                return [[batch]]
             raise ExecutionError(
                 "whole-query program exceeded its capacity-retry budget "
                 f"({_MAX_PROGRAM_RETRIES}) — report this plan")

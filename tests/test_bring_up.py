@@ -109,7 +109,7 @@ def test_whole_tier_compile_refusal_propagates_instead_of_degrading(
     real_jit = jax.jit
 
     def refusing_jit(fn, *a, **kw):
-        if getattr(fn, "__name__", "") == "program":   # the whole program
+        if getattr(fn, "__name__", "").startswith("whole_query_"):
             def refuse(*_a, **_k):
                 raise _refusal()
             return refuse
