@@ -159,7 +159,7 @@ def render(runs: list, programs: dict, attempts: list, head: str) -> str:
             by_row.setdefault(label, {})[phase] = ns
         rec = programs.get(run["program"], {})
         labels = [s for s in rec.get("scopes", []) if s] + [UNATTRIBUTED]
-        members = {s: m.split("\n", 1)[0][:72] for s, m in
+        members = {s: m.split("\n", 1)[0] for s, m in
                    zip(rec.get("scopes", []), rec.get("members", []))}
         for label in labels:
             phases = by_row.get(label, {})

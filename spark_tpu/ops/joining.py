@@ -1,15 +1,24 @@
-"""Equi-join kernel: sorted build side + searchsorted probe + cumsum expansion.
+"""Equi-join kernel: sorted build side + ranked probe + cumsum expansion.
 
 Role of the reference's hash joins — BroadcastHashJoinExec / ShuffledHashJoinExec
 over HashedRelation (sqlx/joins/ShuffledHashJoinExec.scala:38, buildHashedRelation
 :103, sqlx/joins/HashedRelation.scala) and SortMergeJoinExec (:39). TPU-native
 design: pointer-chasing hash tables don't vectorize; instead the build side is
 sorted by a combined 64-bit key hash (`lax.sort`), each probe row finds its
-match range via two `searchsorted` binary searches, and the variable-fanout
-output is flattened into a STATIC-capacity batch with the classic
-cumsum/searchsorted expansion. Hash false-positives are eliminated by gathering
-and comparing the actual key columns (so 64-bit hashing is a grouping
-accelerator, not a correctness assumption).
+match range as two ranks in the sorted hashes (`rank_sorted`, left and
+right), and the variable-fanout output is flattened into a STATIC-capacity
+batch by a cumsum of the counts and the rank of each output slot in it. Hash
+false-positives are eliminated by gathering and comparing the actual key
+columns (so 64-bit hashing is a grouping accelerator, not a correctness
+assumption).
+
+`rank_sorted` is `jnp.searchsorted` with two bodies. The binary search is a
+loop of ceil(log2(len(a)+1)) dependent steps, each one scalar gather per
+query, and a TPU gathers scalars one at a time: 8 Mi queries into 131 072
+keys, both sides, were 7.05 s on a v5e. The merge ranks the queries by sorting
+them together with the keys, counting the keys before each with a cumsum and
+sorting the counts back: sorts and scans, which run at memory speed (the same
+ranks in 0.067 s). `rank_path` picks between them from the two static lengths.
 
 Output capacity overflow is reported via a scalar (`needed`) that the host
 checks to retry at the next capacity bucket (SURVEY.md §7 'Hard parts' (1)).
@@ -26,6 +35,72 @@ from jax import lax
 from .hashing import hash_columns
 
 I64_MAX = jnp.iinfo(jnp.int64).max
+
+# What each body of `rank_sorted` costs on a TPU v5e, in seconds (PERF.md §6,
+# PR 26). GATHER_S: one query's one step of the binary search, a scalar
+# gather (14.6 ns with 131 072 queries, where the choice is close; 23-32 ns
+# with 8 Mi). MERGE_S: one element of keys + queries through the merge, two
+# sorts and the scans (6.9 ns). MERGE_FIXED_S: what a merge must save a run
+# to be worth its sorts, which add 22-49 s to the program's compile where
+# the search adds 0.2-1.3 s: at 50 ms a program repays them in a thousand
+# runs, and a join of small inputs never pays them.
+GATHER_S = 15e-9
+MERGE_S = 7e-9
+MERGE_FIXED_S = 0.05
+
+
+def rank_path(n_sorted: int, n_queries: int) -> str:
+    """`"merge"` or `"search"`: the cheaper body of `rank_sorted` for
+    `n_queries` queries into `n_sorted` keys, both known at trace time."""
+    steps = int(n_sorted).bit_length()        # = ceil(log2(n_sorted + 1))
+    search = n_queries * steps * GATHER_S
+    merge = MERGE_FIXED_S + (n_sorted + n_queries) * MERGE_S
+    return "merge" if merge < search else "search"
+
+
+def rank_sorted(a: jnp.ndarray, v: jnp.ndarray, side: str,
+                path: str | None = None):
+    """`jnp.searchsorted(a, v, side=side)` as int32: for each query in `v`
+    the number of keys in the sorted `a` that are < it (`"left"`) or <= it
+    (`"right"`); `side="both"` gives the pair (left, right) for the price
+    of one. `path` forces a body, for the tests; callers leave it to
+    `rank_path`."""
+    n, m = a.shape[0], v.shape[0]
+    path = path or rank_path(n, m)
+    sides = ("left", "right") if side == "both" else (side,)
+    with jax.named_scope(f"rank_{path}"):
+        if path == "search":
+            out = [jnp.searchsorted(a, v, side=s).astype(jnp.int32)
+                   for s in sides]
+        else:
+            out = _merge_ranks(a, v, sides)
+    return tuple(out) if side == "both" else out[0]
+
+
+def _merge_ranks(a, v, sides) -> list:
+    """Ranks by sorts and scans, with no gather. A stable sort of keys then
+    queries leaves each query behind the keys equal to it, so the count of
+    keys up to its slot is its right rank; its left rank is the count of
+    keys before its run of equal values, carried along the run from the
+    run's first slot by a cummax (counts never fall). A second sort, on
+    where each element came from, puts the ranks back in query order."""
+    n = a.shape[0]
+    origin = lax.iota(jnp.int32, n + v.shape[0])
+    keys, origin = lax.sort((jnp.concatenate([a, v]), origin), num_keys=1,
+                            is_stable=True)
+    is_key = origin < n
+    upto = jnp.cumsum(is_key, dtype=jnp.int32)
+    ranks = []
+    for side in sides:
+        if side == "right":
+            ranks.append(upto)
+        else:
+            run_start = jnp.concatenate([jnp.ones(1, dtype=bool),
+                                         keys[1:] != keys[:-1]])
+            ranks.append(lax.cummax(
+                jnp.where(run_start, upto - is_key, 0), axis=0))
+    back = lax.sort((origin, *ranks), num_keys=1)
+    return [r[n:] for r in back[1:]]
 
 
 class BuildSide(NamedTuple):
@@ -87,10 +162,7 @@ def probe_join(build: BuildSide,
                 usable = usable & v
         ph = jnp.where(usable, ph, I64_MAX - 1)  # sentinel: matches nothing
 
-        lo = jnp.searchsorted(build.sorted_hash, ph,
-                              side="left").astype(jnp.int32)
-        hi = jnp.searchsorted(build.sorted_hash, ph,
-                              side="right").astype(jnp.int32)
+        lo, hi = rank_sorted(build.sorted_hash, ph, "both")
         counts = jnp.where(usable, hi - lo, 0)
     return _expand(build, build_key_cols, build_key_valids, probe_key_cols,
                    probe_key_valids, probe_mask, oc, join_type, pcap, lo,
@@ -119,8 +191,7 @@ def _expand(build, build_key_cols, build_key_valids, probe_key_cols,
     total = offsets[pcap - 1] if pcap > 0 else jnp.int64(0)
 
     j = lax.iota(jnp.int64, oc)
-    src = jnp.searchsorted(offsets, j, side="right").astype(jnp.int32)
-    src = jnp.minimum(src, pcap - 1)
+    src = jnp.minimum(rank_sorted(offsets, j, "right"), pcap - 1)
     base = offsets[src] - ecounts[src]
     within = (j - base).astype(jnp.int32)
     in_range = j < total
@@ -187,8 +258,7 @@ def cross_join(probe_mask: jnp.ndarray, build_mask: jnp.ndarray,
     offsets = jnp.cumsum(counts)
     total = offsets[pcap - 1]
     j = lax.iota(jnp.int64, out_capacity)
-    src = jnp.searchsorted(offsets, j, side="right").astype(jnp.int32)
-    src = jnp.minimum(src, pcap - 1)
+    src = jnp.minimum(rank_sorted(offsets, j, "right"), pcap - 1)
     within = (j - (offsets[src] - counts[src])).astype(jnp.int32)
     bidx = jnp.take(order, jnp.minimum(within, bcap - 1))
     out_mask = (j < total) & jnp.take(probe_mask, src)

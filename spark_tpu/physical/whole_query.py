@@ -1141,6 +1141,7 @@ class _ProgramBuilder:
         if dense is not None:
             return self._join_dense(node, probe, build, metas, lk, rk,
                                     dense, semi_anti)
+        self._note_ranks(node, probe.cap, build.cap, out_cap)
 
         def eqs_of(d, v, idx, luts, bools, args):
             eqs, valids = [], []
@@ -1201,6 +1202,21 @@ class _ProgramBuilder:
                 return datas, valids, r.out_mask
 
         return _Lowered(metas, out_cap, emit)
+
+    def _note_ranks(self, node, pcap: int, bcap: int, out_cap: int) -> None:
+        """Which body `ops/joining.rank_sorted` takes at each of the sorted
+        join's three call sites (probe_join's two ranks of `pcap` hashes in
+        `bcap`, _expand's rank of `out_cap` slots in `pcap` offsets): the
+        same rule the trace asks, counted, and shown in the join's row."""
+        from ..ops.joining import rank_path
+
+        probe_path = rank_path(bcap, pcap)
+        expand_path = rank_path(pcap, out_cap)
+        self.ctx.metrics.add(f"join.rank_{probe_path}", 2)
+        self.ctx.metrics.add(f"join.rank_{expand_path}")
+        note = f"rank[probe={probe_path},expand={expand_path}]"
+        row = self._member_of[id(node)]
+        self.members[row] = f"{self.members[row][:99 - len(note)]} {note}"
 
     def _join_dense(self, node, probe: _Lowered, build: _Lowered, metas,
                     lk, rk, dense, semi_anti) -> _Lowered:

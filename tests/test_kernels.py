@@ -148,6 +148,115 @@ def test_join_overflow_reports_needed():
     assert int(r.needed) == 64  # 8x8 matches, capacity 16 → host must retry
 
 
+_I64 = np.iinfo(np.int64)
+_RANK_RNG = np.random.default_rng(26)
+
+
+def _rank_case(name):
+    """(sorted keys, queries) for one shape `rank_sorted` must get right."""
+    r = _RANK_RNG
+    if name == "duplicate_runs":
+        return np.sort(r.integers(0, 9, 200)), r.integers(-2, 11, 300)
+    if name == "join_sentinels":
+        # build hashes padded with I64_MAX (dead rows), probes carrying
+        # I64_MAX - 1 (unusable: must land before the padding, match none)
+        a = np.sort(np.concatenate([r.integers(_I64.min, _I64.max - 1, 40),
+                                    np.full(24, _I64.max)]))
+        v = np.concatenate([a[:50:3], np.full(9, _I64.max - 1),
+                            r.integers(_I64.min, _I64.max - 1, 20)])
+        return a, v
+    if name == "int64_extremes":
+        a = np.array([_I64.min, _I64.min, -1, 0, 0, _I64.max - 1, _I64.max,
+                      _I64.max])
+        return a, np.array([_I64.max, _I64.min, 0, _I64.max - 1, -1, 1,
+                            _I64.min + 1])
+    if name == "all_below":
+        return np.arange(100, 164), np.arange(-30, 0)
+    if name == "all_above":
+        return np.arange(100, 164), np.arange(500, 530)
+    if name == "iota_queries":
+        # _expand's shape: inclusive offsets with empty slots, slots 0..n-1
+        return np.cumsum(r.integers(0, 4, 120)), np.arange(256)
+    if name == "keys_far_more":
+        return np.sort(r.integers(-1000, 1000, 5000)), r.integers(-1100,
+                                                                  1100, 3)
+    if name == "queries_far_more":
+        return np.sort(r.integers(-50, 50, 3)), r.integers(-60, 60, 5000)
+    if name == "one_key":
+        return np.array([7]), np.array([6, 7, 8, 7])
+    if name == "one_query":
+        return np.array([1, 3, 3, 5]), np.array([3])
+    assert name == "one_each"
+    return np.array([4]), np.array([4])
+
+
+@pytest.mark.parametrize("path", ["merge", "search"])
+@pytest.mark.parametrize("side", ["left", "right", "both"])
+@pytest.mark.parametrize("case", [
+    "duplicate_runs", "join_sentinels", "int64_extremes", "all_below",
+    "all_above", "iota_queries", "keys_far_more", "queries_far_more",
+    "one_key", "one_query", "one_each"])
+def test_rank_sorted_is_searchsorted(case, side, path):
+    from spark_tpu.ops.joining import rank_sorted
+
+    a, v = (x.astype(np.int64) for x in _rank_case(case))
+    got = rank_sorted(jnp.asarray(a), jnp.asarray(v), side, path)
+    sides = ("left", "right") if side == "both" else (side,)
+    for g, s in zip(got if side == "both" else (got,), sides):
+        assert g.dtype == jnp.int32 and g.shape == v.shape
+        assert np.asarray(g).tolist() == \
+            np.searchsorted(a, v, side=s).tolist(), (case, s, path)
+
+
+Mi = 1 << 20
+
+
+@pytest.mark.parametrize("n_sorted,n_queries,path", [
+    (131072, 8 * Mi, "merge"),     # q3's item probe: 18 steps, 8 Mi queries
+    (2 * Mi, 8 * Mi, "merge"),     # q7's customer_demographics probe
+    (131072, 8 * Mi, "merge"),     # the date join's _expand: 8 Mi slots
+    (32 * Mi, 131072, "search"),   # date_dim probes the fact table
+    (8 * Mi, 131072, "search"),    # q3's item _expand: 131 072 slots
+    (131072, 131072, "search"),    # a discarded first program's joins
+    (1024, 1024, "search"),        # small inputs never pay for the sorts
+    (1024, 4 * Mi, "merge"),       # a stage-tier batch probing a small build
+    (0, 16, "search"), (16, 0, "search"),
+])
+def test_rank_path_is_a_rule_of_two_lengths(n_sorted, n_queries, path):
+    from spark_tpu.ops.joining import rank_path
+
+    assert rank_path(n_sorted, n_queries) == path
+
+
+@pytest.mark.parametrize("join_type", ["inner", "left_outer", "left_semi",
+                                       "left_anti"])
+def test_probe_join_same_on_both_rank_paths(join_type, monkeypatch):
+    """Hash-equal runs (duplicate keys on both sides), null keys, dead
+    rows, probes that match nothing: the two bodies of `rank_sorted` hand
+    `_expand` the same ranks, so every array of the result is the same."""
+    from spark_tpu.ops import joining as J
+
+    rng = np.random.default_rng(7)
+    bcap, pcap = 64, 128
+    bk = jnp.asarray(rng.integers(0, 12, bcap).astype(np.int64))
+    pk = jnp.asarray(rng.integers(-3, 15, pcap).astype(np.int64))
+    bvalid = jnp.asarray(rng.random(bcap) > 0.15)
+    pvalid = jnp.asarray(rng.random(pcap) > 0.15)
+    bmask = jnp.asarray(np.arange(bcap) < 50)
+    pmask = jnp.asarray(rng.random(pcap) > 0.1)
+    results = {}
+    for path in ("merge", "search"):
+        monkeypatch.setattr(J, "rank_path", lambda n, m, _p=path: _p)
+        bi = build_index([bk], [bvalid], bmask)
+        results[path] = probe_join(bi, [bk], [bvalid], [pk], [pvalid],
+                                   pmask, 1 << 11, join_type)
+    assert int(results["merge"].out_mask.sum()) > 0
+    for field, a, b in zip(J.JoinResult._fields, results["merge"],
+                           results["search"]):
+        assert a.dtype == b.dtype, field
+        assert np.asarray(a).tolist() == np.asarray(b).tolist(), field
+
+
 def test_hash_partition_counts():
     k = jnp.arange(1000, dtype=jnp.int64)
     mask = jnp.ones(1000, dtype=bool)

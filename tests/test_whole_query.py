@@ -198,6 +198,60 @@ def test_whole_tier_join_retry_predicted(tiers, spark):
     assert report.predicted_launches == measured
 
 
+def test_join_rank_paths_counted_and_shown(tiers, spark, monkeypatch):
+    """Each sorted join asks `ops/joining.rank_path` at its three call
+    sites (two ranks in `probe`, one in `expand`): the lowering counts the
+    answers, writes them into the join's members row, and the traced body
+    takes the same ones. Mini shapes keep the binary search; with the
+    merge's fixed cost taken away they take the merge, same rows."""
+    import pandas as pd
+
+    from spark_tpu.ops import joining as J
+    from spark_tpu.physical.compile import capture_programs
+    from tpcds_mini import register_tpcds
+
+    register_tpcds(spark)
+
+    def counts():
+        c = spark._metrics.snapshot()["counters"]
+        return {p: c.get(f"join.rank_{p}", 0) for p in ("merge", "search")}
+
+    def run(query):
+        before = counts()
+        with capture_programs() as programs:
+            out = spark.sql(query).toArrow().to_pandas()
+        delta = {p: n - before[p] for p, n in counts().items()}
+        return out, delta, programs
+
+    # a month no other test asks for: the programs are built here
+    query = Q3_SORTED.replace("d_moy = 11", "d_moy = 12")
+    spark.conf.set("spark.tpu.compile.tier", "stage")
+    ref = spark.sql(query).toArrow().to_pandas()
+    assert len(ref)
+    spark.conf.set("spark.tpu.compile.tier", "whole")
+    for fixed, path, other in ((0.0, "merge", "search"),
+                               (J.MERGE_FIXED_S, "search", "merge")):
+        monkeypatch.setattr(J, "MERGE_FIXED_S", fixed)
+        out, delta, programs = run(query if path == "merge" else Q3_SORTED)
+        assert programs and delta[other] == 0
+        # two joins, three call sites each, every program lowered
+        assert delta[path] == 6 * len(programs), (delta, len(programs))
+        rec = programs[-1]
+        joins = [m for s, m in zip(rec["scopes"], rec["members"])
+                 if s and s.endswith(".HashJoin")]
+        assert len(joins) == 2
+        note = f"rank[probe={path},expand={path}]"
+        assert all(m.endswith(note) for m in joins), joins
+        text = rec["kernel"]._kernel.lower(*rec["args"]).as_text(
+            debug_info=True)
+        assert f"probe/rank_{path}" in text and f"expand/rank_{path}" in text
+        assert f"rank_{other}" not in text
+        if path == "merge":
+            pd.testing.assert_frame_equal(ref, out, check_dtype=False)
+            shown = spark.sql(query).query_execution.explain_string("device")
+            assert note in shown, shown
+
+
 # ---------------------------------------------------------------------------
 # tier chooser: fallbacks + obs contract
 # ---------------------------------------------------------------------------
