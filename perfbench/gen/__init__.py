@@ -6,6 +6,12 @@ Every draw is from a stream keyed by (seed, table, name), so the data of
 one column does not depend on which others a configuration keeps. The
 program sees only the Arrow tables; the references read the numpy columns
 the Arrow tables were built from.
+
+Which seed a stream takes is the configuration's `seeding`: the streams it
+lists `from_the_run_seed` take `--seed`, every other stream takes its
+`structure_seed`. So every run of the configuration has the same keys,
+dates, tickets and nulls (the sizes of every join, filter and group: the
+work), and other measures (the answers).
 """
 
 from __future__ import annotations
@@ -47,9 +53,19 @@ class Col:
         return np.asarray(self.pool, dtype=object)[self.values]
 
 
-def rng_for(seed: int, table: str, column: str) -> np.random.Generator:
+@dataclass(frozen=True)
+class Seeds:
+    """What a table's generator is given as its seed."""
+    run: int
+    structure: int
+    from_run: frozenset          # "table.stream"
+
+
+def rng_for(seeds: Seeds, table: str, column: str) -> np.random.Generator:
+    seed = seeds.run if f"{table}.{column}" in seeds.from_run \
+        else seeds.structure
     return np.random.default_rng(
-        [int(seed), zlib.crc32(table.encode()), zlib.crc32(column.encode())])
+        [seed, zlib.crc32(table.encode()), zlib.crc32(column.encode())])
 
 
 def _validity_buffer(valid):
@@ -101,11 +117,14 @@ def table_rows(config: dict, scale: float = 1.0) -> dict:
 def generate(config: dict, seed: int, scale: float = 1.0) -> dict:
     """{table: {column: Col}} for the configuration, from the seed."""
     sizes = table_rows(config, scale)
+    seeding = config["seeding"]
+    seeds = Seeds(int(seed), int(seeding["structure_seed"]),
+                  frozenset(seeding["from_the_run_seed"]))
     data = {}
     for spec in config["tables"]:
         name = spec["name"]
         mod = importlib.import_module(f"perfbench.gen.tables.{name}")
-        cols = mod.generate(seed, sizes[name], list(spec["columns"]), sizes)
+        cols = mod.generate(seeds, sizes[name], list(spec["columns"]), sizes)
         missing = [c for c in spec["columns"] if c not in cols]
         if missing:
             raise KeyError(f"{name}: generator made no column {missing}")

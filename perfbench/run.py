@@ -5,13 +5,16 @@
 
 Set-up (everything before the window): make the tables from the seed,
 start the session, register the tables, run every query of the cell once
-through the cell's own door. The window: each stream of the traffic file
-runs its queries in its fixed order, one after the other (a closed loop:
-the only kind there is), and starts a query while less than `--seconds`
-have passed; the window is from the
-first submit to the last completion, and every query started counts.
-After the window: read the device's peak, stop the program, compute the
-plain numpy references and compare every execution's rows with them.
+through the cell's own door. `setup_s` is process start to the window's
+start, less the time the main thread waited for the benchmark's own
+generator once the backend was up (`setup_seconds`): what a user of the
+engine pays, who has their tables. The window: each stream of the traffic
+file runs its list of queries in its fixed order, one after the other (a
+closed loop: the only kind there is), in whole rounds (`stream_loop` has
+the rule); the window is from the first submit to the last completion,
+and every query started counts. After the window: read the device's
+peak, stop the program, compute the plain numpy references and compare
+every execution's rows with them.
 
 The last line of standard output is one JSON object. Without a TPU the
 run fails, unless `--rehearse` asks for the CPU rehearsal: a tiny scale,
@@ -127,22 +130,34 @@ def announced_tier(session, text: str) -> str:
 # ---------------------------------------------------------------------------
 
 def stream_loop(index, client, queries, texts, seconds, gate, annotate,
-                records):
+                records, at_most=None, clock=time.perf_counter):
+    """One stream's part of the window, in whole rounds. A round is the
+    stream's whole list. The first round always starts; another starts
+    only if the time passed plus the length of the round just finished
+    is under `seconds`, and never more than the traffic file's
+    `rounds_at_most`; a round that is started is finished. So every
+    query of the list has the same count in every window, and a round a
+    little shorter or longer changes the count by a whole round or not
+    at all. (`clock` is the tests' alone.)"""
     gate.wait()
     t0 = gate.t0
-    i = 0
-    while time.perf_counter() - t0 < seconds:
-        q = queries[i % len(queries)]
-        rec = {"stream": index, "query": q, "raw": None, "info": {},
-               "error": None}
-        rec["t_submit"] = time.perf_counter()
-        try:
-            rec["raw"], rec["info"] = client.run(texts[q], annotate)
-        except Exception as e:      # a failed query is a finding, counted
-            rec["error"] = f"{type(e).__name__}: {e}"
-        rec["t_done"] = time.perf_counter()
-        records.append(rec)
-        i += 1
+    rounds = 0
+    while True:
+        t_round = clock()
+        for q in queries:
+            rec = {"stream": index, "query": q, "round": rounds,
+                   "raw": None, "info": {}, "error": None}
+            rec["t_submit"] = clock()
+            try:
+                rec["raw"], rec["info"] = client.run(texts[q], annotate)
+            except Exception as e:  # a failed query is a finding, counted
+                rec["error"] = f"{type(e).__name__}: {e}"
+            rec["t_done"] = clock()
+            records.append(rec)
+        rounds += 1
+        now = clock()
+        if rounds == at_most or (now - t0) + (now - t_round) >= seconds:
+            return
 
 
 class Gate(threading.Event):
@@ -153,17 +168,25 @@ class Gate(threading.Event):
         self.set()
 
 
-def run_window(clients, streams, texts, seconds, annotate) -> list:
+def run_window(clients, streams, texts, seconds, at_most, annotate) -> list:
+    """The first stream runs in the caller's thread, the one that warmed
+    up (a window in a new thread had a slow first round: PR 28); every
+    other stream has a thread of its own."""
     records: list = []
     gate = Gate()
-    threads = [threading.Thread(
-        target=stream_loop, name=f"pb-stream-{i}",
-        args=(i, c, qs, texts, seconds, gate, annotate, records))
-        for i, (c, qs) in enumerate(zip(clients, streams))]
+
+    def loop(i):
+        stream_loop(i, clients[i], streams[i], texts, seconds, gate,
+                    annotate, records, at_most)
+
+    threads = [threading.Thread(target=loop, name=f"pb-stream-{i}",
+                                args=(i,), daemon=True)
+               for i in range(1, len(streams))]
     for t in threads:
         t.start()
     with annotate("window"):
         gate.open()
+        loop(0)
         for t in threads:
             t.join(seconds + ANSWER_WAIT_S)
     alive = [t.name for t in threads if t.is_alive()]
@@ -177,6 +200,29 @@ def percentile(values: list, p: float) -> float:
     tail is a latency some query really had."""
     v = sorted(values)
     return v[min(len(v) - 1, max(0, -(-len(v) * p // 100) - 1))]
+
+
+def window_values(records: list, fact_rows: int) -> dict:
+    """The window's three end-to-end numbers, from its records alone: the
+    window is from the first submit to the last completion, every query
+    started is in the latencies, every query answered in the rate."""
+    window_s = max(r["t_done"] for r in records) \
+        - min(r["t_submit"] for r in records)
+    latencies = [r["t_done"] - r["t_submit"] for r in records]
+    answered = sum(r["error"] is None for r in records)
+    return {"window_s": window_s, "latencies": latencies,
+            "fact_rows_per_s": fact_rows * answered / window_s,
+            "query_s.p50": percentile(latencies, 50),
+            "query_s.p95": percentile(latencies, 95)}
+
+
+def setup_seconds(marks: dict) -> float:
+    """`setup_s`: process start to the window's start, less the time the
+    main thread waited for the benchmark's own generator once the backend
+    was up. A user of the engine has their tables; they pay for the
+    process, the backend, the session, registration, the copy to the
+    device, the programs' load or compile, and the warm-up pass."""
+    return marks["window opens"] - marks["generator wait"]
 
 
 def compare_window(records: list, want: dict) -> tuple:
@@ -258,7 +304,9 @@ def run(args, break_path=None) -> dict:
     maker.start()
     device = find_device(cell["chips"], args.rehearse)
     mark("backend up")
+    t_wait = time.perf_counter()
     maker.join()
+    marks["generator wait"] = time.perf_counter() - t_wait   # a length
     if "error" in made:
         raise made["error"]
     data, tables = made.pop("data"), made.pop("tables")
@@ -286,16 +334,15 @@ def run(args, break_path=None) -> dict:
         clients = [entry.client(i) for i in range(len(streams))]
         mark("session up")
 
-        # warm-up: every query once through the first client (this is
-        # where a cold run compiles), and the first query of every other
-        # client, so that each has its session and its first plan
-        for q in distinct:
-            t0 = time.perf_counter()
-            clients[0].run(texts[q], no_span)
-            say(f"warm-up {q}: {time.perf_counter() - t0:.2f} s, "
-                f"{device_bytes()}")
-        for c, qs in zip(clients[1:], streams[1:]):
-            c.run(texts[qs[0]], no_span)
+        # warm-up: every stream's list once through its own client, here
+        # on the main thread (on a cold run this is where the programs
+        # compile)
+        for i, (c, qs) in enumerate(zip(clients, streams)):
+            for q in qs:
+                t0 = time.perf_counter()
+                c.run(texts[q], no_span)
+                say(f"warm-up {q} on stream {i}: "
+                    f"{time.perf_counter() - t0:.2f} s, {device_bytes()}")
         mark("warm")
 
         annotate = no_span
@@ -311,8 +358,9 @@ def run(args, break_path=None) -> dict:
 
         before = {"counters": engine_counters(),
                   "hidden": hidden_moved(entry.sessions())}
-        setup_s = time.perf_counter() - T_START
-        records = run_window(clients, streams, texts, args.seconds, annotate)
+        mark("window opens")
+        records = run_window(clients, streams, texts, args.seconds,
+                             traffic.get("rounds_at_most"), annotate)
         after = {"counters": engine_counters(),
                  "hidden": hidden_moved(entry.sessions())}
         if tracing:
@@ -328,10 +376,10 @@ def run(args, break_path=None) -> dict:
             entry.stop()
         session.stop()
 
-    window_s = max(r["t_done"] for r in records) \
-        - min(r["t_submit"] for r in records)
-    latencies = [r["t_done"] - r["t_submit"] for r in records]
     fact_rows = len(data["store_sales"]["ss_item_sk"].values)
+    values = window_values(records, fact_rows)
+    values["setup_s"] = setup_seconds(marks)
+    window_s, latencies = values["window_s"], values["latencies"]
 
     # the references run once the program is stopped: host numpy only
     t_ref = time.perf_counter()
@@ -349,7 +397,7 @@ def run(args, break_path=None) -> dict:
     ref_s = time.perf_counter() - t_ref
     say(f"window {window_s:.2f} s, {len(records)} queries "
         f"(stream, query, start, seconds: "
-        f"{[(r['stream'], r['query'], round(r['t_submit'] - records[0]['t_submit'], 2), round(r['t_done'] - r['t_submit'], 2)) for r in records]}), "
+        f"{[(r['stream'], r['query'], round(r['t_submit'] - records[0]['t_submit'], 3), round(r['t_done'] - r['t_submit'], 3)) for r in records]}), "
         f"launched {kinds}, announced {announced}, reference {ref_s:.1f} s")
 
     result = {"correct": correct, "attempted": len(records),
@@ -359,7 +407,7 @@ def run(args, break_path=None) -> dict:
         state = {"cell": cell, "records": records, "window_s": window_s,
                  "latencies": latencies, "fact_rows": fact_rows,
                  "before": before, "after": after, "data": data,
-                 "want": want, "device": device, "setup_s": setup_s,
+                 "want": want, "device": device, "setup_s": values["setup_s"],
                  "peaks": spec.peaks(device["kind"]), "trace": None}
         if tracing:
             from perfbench.trace import reduce as tr
@@ -376,15 +424,15 @@ def run(args, break_path=None) -> dict:
                     result["metrics"][m["name"]] = {"value": value,
                                                     "unit": m["unit"]}
         else:
-            values = {
-                "fact_rows_per_s": fact_rows * (len(records) - numbers[
-                    "unanswered"]) / window_s,
-                "query_s.p50": percentile(latencies, 50),
-                "query_s.p95": percentile(latencies, 95),
-                "setup_s": setup_s}
             for m in cell["end_to_end"]:
                 result["metrics"][m["name"]] = {"value": values[m["name"]],
                                                 "unit": m["unit"]}
+    result["window"] = {
+        "seconds": window_s,
+        "rounds": [1 + max(r["round"] for r in records if r["stream"] == i)
+                   for i in range(len(streams))],
+        "queries": {q: sum(r["query"] == q for r in records)
+                    for q in distinct}}
     result["set_up"] = marks
     result["compared"] = compared
     for name, c in compared.items():

@@ -138,7 +138,7 @@ def test_cell_finds_everything_by_name(cell):
     assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
     assert cell["chips"] in (1, 4) and LINE.match(cell["why"])
     loaded = spec.cell(cell["name"])
-    assert set(loaded["traffic"]) == {"why", "streams"}
+    assert set(loaded["traffic"]) - {"rounds_at_most"} == {"why", "streams"}
     names = [m["name"] for m in loaded["end_to_end"]]
     assert "setup_s" in names and len(names) >= 2
     assert loaded["per_layer"]
