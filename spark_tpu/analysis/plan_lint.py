@@ -2483,6 +2483,8 @@ class _Analyzer:
         )
         from ..physical.fusion import FusedAggregateExec, FusedLimitExec
         from ..physical.operators import attrs_schema
+        from ..physical.whole_query import window_layout_bytes
+        from ..physical.window import WindowExec
 
         kinds = Counter()
         notes = []
@@ -2603,6 +2605,15 @@ class _Analyzer:
                 cap, _tr = walk(n.child)
                 mem(n, cap)
                 return cap, None
+            if isinstance(n, WindowExec):
+                # whole_query._lower_window: the flow keeps its rows,
+                # their order and its capacity and gains a column per
+                # expression, inside the same program (no launch of its
+                # own); memory as _estimate_resident_bytes counts it
+                cap, tr = walk(n.child)
+                mem(n, cap)
+                hbm[0] += window_layout_bytes(n, cap)
+                return cap, tr
             if isinstance(n, O.UnionExec):
                 pairs = [walk(c) for c in n.children_plans]
                 cap = bucket_capacity(max(sum(c for c, _ in pairs), 1))

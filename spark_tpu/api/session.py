@@ -378,9 +378,17 @@ class TpuSession:
 
         mapping = {}
         for uniq, body in wplan.materializations:
-            body = self._splice_relations(body, mapping)
-            table = DataFrame(self, body).toArrow()
-            rel = self.createDataFrame(table).plan
+            # this part of a query runs inside sql(), before the outer
+            # plan has a QueryExecution: the span puts it on the timeline
+            # (the body's own execution has its spans under it); `uniq`
+            # is sql/parser._apply_ctes's `__cte_mat_<name>_<8 hex>`
+            name = uniq.removeprefix("__cte_mat_").rsplit("_", 1)[0]
+            with self.tracer.span("cte.materialize", cat="phase",
+                                  args={"cte": name}) as sp:
+                body = self._splice_relations(body, mapping)
+                table = DataFrame(self, body).toArrow()
+                rel = self.createDataFrame(table).plan
+                sp.set_args({"rows": table.num_rows})
             mapping[uniq.lower()] = rel
         return self._splice_relations(wplan.child, mapping)
 

@@ -1321,6 +1321,66 @@ class GreaterThanOrEqual(BinaryComparison):
         return l >= r
 
 
+_COMPARISONS = {c.symbol: c for c in (EqualTo, NotEqualTo, LessThan,
+                                      LessThanOrEqual, GreaterThan,
+                                      GreaterThanOrEqual)}
+
+
+def exact_numeric(dt: DataType) -> bool:
+    """DECIMAL or integral: a value that is an integer at a known scale."""
+    return isinstance(dt, (DecimalType, IntegralType))
+
+
+class QuotientComparison(Expression):
+    """`num / den <op> other` over DECIMAL or integral operands, decided on
+    the unscaled 64-bit integers: with e = scale(den) + scale(other) -
+    scale(num), `num * 10^e <op> other * den` for a positive `den`, both
+    sides negated for a negative one. `Divide` gives a float64 quotient,
+    and a float64 cannot say on which side of 0.1 a quotient of exactly a
+    tenth lies: the v5e, whose float64 is emulated, puts 127.83 / 1278.3
+    above it. NULL as the quotient is (a NULL operand, `den` = 0); where
+    64 bits do not hold the products, the float64 comparison's answer.
+    Written by the optimizer (`DecideQuotientComparisons`) alone."""
+
+    child_fields = ("num", "den", "other")
+
+    def __init__(self, num: Expression, den: Expression, other: Expression,
+                 op: str):
+        assert op in _COMPARISONS, op
+        self.num = num
+        self.den = den
+        self.other = other
+        self.op = op
+
+    @property
+    def dtype(self):
+        return boolean
+
+    def simple_string(self) -> str:
+        return (f"(({self.num.simple_string()} / {self.den.simple_string()})"
+                f" {self.op} {self.other.simple_string()})")
+
+    def eval(self, ctx):
+        inexact = _COMPARISONS[self.op](Divide(self.num, self.den),
+                                        self.other)
+        approx = ctx.eval(inexact)
+        vals = [ctx.eval(e) for e in (self.num, self.den, self.other)]
+        sn, sm, sk = (getattr(v.dtype, "scale", 0) for v in vals)
+        e = sm + sk - sn
+        up_l, up_r = 10 ** max(e, 0), 10 ** max(-e, 0)
+        if not ctx.is_trace or max(up_l, up_r) >= 2 ** 62:
+            return approx
+        jnp = _jnp()
+        n, m, k = (v.data.astype(jnp.int64) for v in vals)
+        nf, mf, kf = (jnp.abs(x.astype(jnp.float64)) for x in (n, m, k))
+        fits = (nf * float(up_l) < 2.0 ** 62) \
+            & (kf * mf * float(up_r) < 2.0 ** 62)
+        sign = jnp.sign(m)
+        exact = inexact._cmp(sign * n * up_l, sign * m * k * up_r)
+        return Val(boolean, jnp.where(fits, exact, approx.data),
+                   approx.validity, None)
+
+
 # ---------------------------------------------------------------------------
 # Boolean logic — Kleene three-valued
 # ---------------------------------------------------------------------------

@@ -37,6 +37,12 @@ class WindowLayout(NamedTuple):
     seg_size: jnp.ndarray    # int32 rows in the partition
 
 
+# Scopes, by the pattern of ops/joining.py: the one sort, the frame
+# computations and the scatter each carry a `jax.named_scope`, so that a
+# profile of a program that traced them (the per-partition kernel, the
+# whole-query program's `mNN.Window`) says where a window's time goes.
+
+@jax.named_scope("layout_sort")
 def build_layout(part_keys: Sequence[jnp.ndarray],
                  part_valids: Sequence[jnp.ndarray | None],
                  order_keys: Sequence[jnp.ndarray],
@@ -44,17 +50,22 @@ def build_layout(part_keys: Sequence[jnp.ndarray],
                  order_specs: Sequence[SortKeySpec],
                  row_mask: jnp.ndarray) -> WindowLayout:
     cap = row_mask.shape[0]
-    operands: list[jnp.ndarray] = [(~row_mask).astype(jnp.int32)]
-    n_pkeys_ops = 0
-    for k, v in zip(part_keys, part_valids):
+    # one flags operand for all the partition keys: dead rows last, and a
+    # bit a key that is null there. The order of the partitions among
+    # themselves is nobody's business, so the null flags need no place of
+    # their own between the keys, and every sort key less is minutes of
+    # the TPU compiler's time (a 13-key sort compiles five times as long
+    # as a 6-key one)
+    ft = jnp.int32 if len(part_keys) < 31 else jnp.int64
+    flags = (~row_mask).astype(ft) << len(part_keys)
+    operands: list[jnp.ndarray] = [flags]
+    for i, (k, v) in enumerate(zip(part_keys, part_valids)):
         if v is not None:
-            operands.append((~v).astype(jnp.int32))
-            operands.append(jnp.where(v, k, jnp.zeros_like(k)))
-            n_pkeys_ops += 2
-        else:
-            operands.append(k)
-            n_pkeys_ops += 1
-    n_order_start = len(operands)
+            flags = flags | ((~v).astype(ft) << i)
+            k = jnp.where(v, k, jnp.zeros_like(k))
+        operands.append(k)
+    operands[0] = flags
+    n_pkeys_ops = len(part_keys)
     for k, v, s in zip(order_keys, order_valids, order_specs):
         if v is not None:
             nf = s.nulls_first_effective
@@ -127,28 +138,44 @@ def w_ntile(lo: WindowLayout, n: int):
     return (rn0 * n // jnp.maximum(lo.seg_size, 1) + 1).astype(jnp.int32)
 
 
+def _float_avg(total, cnt):
+    """AVG as the float64 quotient; NULL over no value. The operator
+    passes its own `avg` to the frame kernels (physical/window.py: the
+    aggregate's finishing expression), so that a window's average and a
+    GROUP BY's are one computation, to one type."""
+    return total.astype(jnp.float64) / jnp.maximum(cnt, 1), cnt > 0
+
+
+def _agg_result(kind: str, total, cnt, avg):
+    """count / sum / avg of a frame from its total and its count of
+    non-null values (None for min/max, which the caller finishes)."""
+    if kind == "count":
+        return cnt, None
+    if kind == "sum":
+        return total, cnt > 0
+    if kind == "avg":
+        return (avg or _float_avg)(total, cnt)
+    return None
+
+
 def _sorted_vals(lo: WindowLayout, values, valid):
     v = jnp.take(values, lo.perm)
     w = lo.active if valid is None else (lo.active & jnp.take(valid, lo.perm))
     return v, w
 
 
-def w_agg_unbounded(lo: WindowLayout, values, valid, kind: str):
+def w_agg_unbounded(lo: WindowLayout, values, valid, kind: str, avg=None):
     """sum/count/min/max/avg over the whole partition, broadcast to rows."""
     cap = values.shape[0]
     v, w = _sorted_vals(lo, values, valid)
-    if kind == "count":
-        tot = jax.ops.segment_sum(w.astype(jnp.int64), lo.seg_id, cap)
-        return jnp.take(tot, lo.seg_id), None
     acc = jnp.float64 if jnp.issubdtype(v.dtype, jnp.floating) else jnp.int64
-    if kind in ("sum", "avg"):
-        s = jax.ops.segment_sum(jnp.where(w, v.astype(acc), 0), lo.seg_id, cap)
+    if kind in ("count", "sum", "avg"):
         c = jax.ops.segment_sum(w.astype(jnp.int64), lo.seg_id, cap)
-        if kind == "sum":
-            return jnp.take(s, lo.seg_id), jnp.take(c, lo.seg_id) > 0
-        c_safe = jnp.maximum(c, 1)
-        a = s.astype(jnp.float64) / c_safe
-        return jnp.take(a, lo.seg_id), jnp.take(c, lo.seg_id) > 0
+        s = c if kind == "count" else jax.ops.segment_sum(
+            jnp.where(w, v.astype(acc), 0), lo.seg_id, cap)
+        d, dv = _agg_result(kind, s, c, avg)    # per partition, then spread
+        return jnp.take(d, lo.seg_id), \
+            None if dv is None else jnp.take(dv, lo.seg_id)
     from .grouping import _max_ident, _min_ident
 
     if kind == "min":
@@ -161,7 +188,7 @@ def w_agg_unbounded(lo: WindowLayout, values, valid, kind: str):
     return jnp.take(m, lo.seg_id), jnp.take(c, lo.seg_id) > 0
 
 
-def w_agg_running(lo: WindowLayout, values, valid, kind: str):
+def w_agg_running(lo: WindowLayout, values, valid, kind: str, avg=None):
     """RANGE UNBOUNDED PRECEDING..CURRENT ROW (peers share the value)."""
     cap = values.shape[0]
     v, w = _sorted_vals(lo, values, valid)
@@ -177,13 +204,8 @@ def w_agg_running(lo: WindowLayout, values, valid, kind: str):
                                0)
     run_sum = jnp.take(csum, lo.peer_last) - before_seg_sum
     run_cnt = jnp.take(ccnt, lo.peer_last) - before_seg_cnt
-    if kind == "count":
-        return run_cnt, None
-    if kind == "sum":
-        return run_sum, run_cnt > 0
-    if kind == "avg":
-        return run_sum.astype(jnp.float64) / jnp.maximum(run_cnt, 1), \
-            run_cnt > 0
+    if kind in ("count", "sum", "avg"):
+        return _agg_result(kind, run_sum, run_cnt, avg)
     # running min/max via cummin/cummax reset at segment start: use
     # associative_scan over (value, seg_id) pairs
     big = jnp.where(w, v, _ident(kind, v.dtype))
@@ -204,7 +226,7 @@ def w_agg_running(lo: WindowLayout, values, valid, kind: str):
 
 
 def w_agg_rows(lo: WindowLayout, values, valid, kind: str,
-               lo_off, hi_off):
+               lo_off, hi_off, avg=None):
     """ROWS BETWEEN <lo_off> AND <hi_off> frame for sum/count/avg, via
     segment-clipped cumulative sums. Offsets are row deltas relative to the
     current row; None means unbounded on that side."""
@@ -233,19 +255,15 @@ def w_agg_rows(lo: WindowLayout, values, valid, kind: str,
 
     total = rng(csum)
     cnt = rng(ccnt)
-    if kind == "count":
-        return cnt, None
-    if kind == "sum":
-        return total, cnt > 0
-    if kind == "avg":
-        return total.astype(jnp.float64) / jnp.maximum(cnt, 1), cnt > 0
+    if kind in ("count", "sum", "avg"):
+        return _agg_result(kind, total, cnt, avg)
     if kind in ("min", "max"):
         return _range_minmax(v, w, lo_idx, hi_idx, empty, kind), cnt > 0
     raise ValueError(kind)
 
 
 def w_agg_value_range(lo: WindowLayout, order_key, values, valid, kind: str,
-                      lo_off, hi_off, kmin: int, band: int):
+                      lo_off, hi_off, kmin: int, band: int, avg=None):
     """RANGE BETWEEN <lo_off> AND <hi_off> with VALUE offsets over a single
     integral order key. Keys are banded per partition —
     enc = seg_id·band + (key − kmin) — so one global `searchsorted` finds
@@ -279,12 +297,8 @@ def w_agg_value_range(lo: WindowLayout, order_key, values, valid, kind: str,
 
     total = rng(csum)
     cnt = rng(ccnt)
-    if kind == "count":
-        return cnt, None
-    if kind == "sum":
-        return total, cnt > 0
-    if kind == "avg":
-        return total.astype(jnp.float64) / jnp.maximum(cnt, 1), cnt > 0
+    if kind in ("count", "sum", "avg"):
+        return _agg_result(kind, total, cnt, avg)
     if kind in ("min", "max"):
         return _range_minmax(v, w, lo_idx, hi_idx, empty, kind), cnt > 0
     raise ValueError(kind)
@@ -389,6 +403,7 @@ def w_nth_value(lo: WindowLayout, values, valid, n: int,
     return out, out_valid
 
 
+@jax.named_scope("scatter_back")
 def scatter_back(lo: WindowLayout, sorted_vals, sorted_valid=None):
     """Sorted-order results → original row order."""
     cap = sorted_vals.shape[0]

@@ -140,6 +140,11 @@ def canonical_key(e: Expression, id_to_pos: dict[int, int]) -> tuple:
     for k, v in sorted(e.__dict__.items()):
         if k in e.child_fields or k.startswith("_") or isinstance(v, Expression):
             continue
+        if k in e.equality_excluded_fields:
+            # a second view of the children (CaseWhen.branches pairs up
+            # branch_exprs): its text carries the attribute ids, and a key
+            # with them in it never matches the same query parsed again
+            continue
         if isinstance(v, (list, tuple)) and any(isinstance(x, Expression) for x in v):
             continue
         if isinstance(v, DataType):

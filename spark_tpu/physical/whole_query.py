@@ -187,6 +187,7 @@ def supported_whole_query(plan, conf,
     from . import operators as O
     from .exchange import BroadcastExchangeExec, ShuffleExchangeExec
     from .fusion import FusedAggregateExec, FusedLimitExec  # noqa: F401
+    from .window import WindowExec
 
     for node in _iter_inner(plan):
         if isinstance(node, (O.LocalTableScanExec, O.RangeExec)):
@@ -232,9 +233,57 @@ def supported_whole_query(plan, conf,
                     return False, (f"join key {k.name} is a nested "
                                    "dictionary type")
             continue
+        if isinstance(node, WindowExec):
+            why = _window_refusal(node)
+            if why:
+                return False, why
+            continue
         return False, (f"operator {type(node).__name__} has no "
                        "whole-query lowering")
     return True, ""
+
+
+# what `_lower_window` traces: ranks over an ORDER BY, and aggregates over
+# the whole partition or the default running frame
+_WINDOW_RANKS = frozenset({"row_number", "rank", "dense_rank"})
+_WINDOW_AGGS = frozenset(f"agg_{frame}_{op}"
+                         for frame in ("unbounded", "running")
+                         for op in ("sum", "avg", "min", "max", "count"))
+
+
+def _window_refusal(node) -> Optional[str]:
+    """Why this WindowExec has no whole-query lowering, naming the
+    function, frame or key at fault; None when it has one. Admitted:
+    `_WINDOW_RANKS` and `_WINDOW_AGGS` over numeric, date and decimal
+    values; partition keys of any plain or string type (equality on
+    dictionary codes); order keys that sort as they are stored."""
+    from ..errors import UnsupportedOperationError
+
+    try:
+        plans = node._plans()
+    except UnsupportedOperationError as e:
+        return f"window: {e}"
+    for al, (kind, param, arg) in zip(node.window_exprs, plans):
+        fn = al.child.function.sql_name()
+        if kind not in _WINDOW_RANKS and kind not in _WINDOW_AGGS:
+            if kind.startswith("agg_"):
+                ftype, lo, hi = al.child.frame
+                return (f"window frame {ftype.upper()} BETWEEN {lo} AND "
+                        f"{hi} of {fn} has no whole-query lowering "
+                        "(offset frames run on the stage tier)")
+            return f"window function {fn} has no whole-query lowering"
+        if arg is not None and dict_encoded(arg.dtype):
+            return (f"window function {fn} over the dictionary-encoded "
+                    f"{arg.name} has no whole-query lowering")
+    for k in node.partition_keys:
+        if dict_encoded(k.dtype) and not isinstance(k.dtype, StringType):
+            return (f"window partition key {k.name} is a nested "
+                    "dictionary type")
+    for o in node.order_keys:
+        if dict_encoded(o.child.dtype):
+            return (f"window order key {o.child.name} is a string or "
+                    "nested dictionary type (its codes do not sort)")
+    return None
 
 
 def _iter_inner(plan):
@@ -256,11 +305,17 @@ def supported_mesh_whole(plan, conf) -> tuple[bool, str, dict]:
     from ..config import MESH_ENABLED
     from .exchange import ShuffleExchangeExec
     from .partitioning import HashPartitioning
+    from .window import WindowExec
 
     if not conf.get(MESH_ENABLED):
         return False, "spark.tpu.mesh.enabled=false", {}
     counts: set[int] = set()
     for node in _iter_inner(plan):
+        if isinstance(node, WindowExec):
+            # admitted on one chip (_lower_window); a partition's rows
+            # would have to meet on one shard first
+            return False, ("operator WindowExec has no mesh-whole "
+                           "lowering"), {}
         if not isinstance(node, ShuffleExchangeExec):
             continue
         p = node.partitioning
@@ -304,6 +359,7 @@ def _estimate_resident_bytes(plan, conf) -> Optional[int]:
     from . import operators as O
     from .exchange import BroadcastExchangeExec, ShuffleExchangeExec
     from .fusion import FusedAggregateExec
+    from .window import WindowExec
 
     tile = int(conf.get(  # tpulint: ignore[host-sync]
         "spark.tpu.batch.capacity", 1 << 20))
@@ -353,7 +409,19 @@ def _estimate_resident_bytes(plan, conf) -> Optional[int]:
         if isinstance(node, FusedAggregateExec):
             # the traced pipeline's projected planes are live too
             total += cap * 16
+        elif isinstance(node, WindowExec):
+            total += window_layout_bytes(node, cap)
     return total
+
+
+def window_layout_bytes(node, cap: int) -> int:
+    """Bytes a lowered WindowExec holds beside its output tile: the layout
+    sort's operands (a key and its null flag, 12 B at most, per partition
+    and order key; the flags and the row index) and
+    ops/window.WindowLayout's nine planes. (analysis/plan_lint mirrors
+    the estimate with the same function.)"""
+    nkeys = len(node.partition_keys) + len(node.order_keys)
+    return cap * (12 * nkeys + 8 + 36)
 
 
 def _avg_compile_ms() -> float:
@@ -521,6 +589,56 @@ def apply_compile_tier(plan, conf, cluster: bool = False):
 # program builder
 # ---------------------------------------------------------------------------
 
+def _plan_key_packs(metas: Sequence["_MCol"]) -> tuple:
+    """Which of an aggregate's grouping keys (or a window's partition
+    keys) travel as one sort key. Equality is all such keys are for, and
+    a string key is dictionary codes below its dictionary's length, so
+    several of them fit, a bit field each, in one integer (code + 1, 0
+    for NULL: the null flag goes with it). A `lax.sort` costs the TPU
+    compiler roughly the square of its number of keys: q89's group-by on
+    five strings and a month is 4 sort keys so, and was 13. Returns packs
+    of (key position, bits), two keys or more each; field widths are
+    powers of two of the dictionary's length, so the program's cache key
+    moves only when a dictionary doubles."""
+    packs, cur, used = [], [], 0
+    for j, mc in enumerate(metas):
+        if not isinstance(mc.dtype, StringType) or mc.sdict is None:
+            continue
+        bits = max(1, len(mc.sdict).bit_length())  # 0 (NULL) .. len(dict)
+        if used + bits > 62:
+            packs.append(cur)
+            cur, used = [], 0
+        cur.append((j, bits))
+        used += bits
+    packs.append(cur)
+    return tuple(tuple(p) for p in packs if len(p) > 1)
+
+
+def _pack_keys(packs: tuple, keys: list, valids: list) -> tuple:
+    """(keys, validity planes) with each of `packs` as one key (traced)."""
+    if not packs:
+        return keys, valids
+    jnp = _jnp()
+    out_k, out_v = [], []
+    for pack in packs:
+        dt = jnp.int32 if sum(b for _j, b in pack) < 32 else jnp.int64
+        acc, shift = None, 0
+        for j, bits in pack:
+            code = jnp.clip(keys[j], 0, (1 << bits) - 2).astype(dt) + 1
+            if valids[j] is not None:
+                code = jnp.where(valids[j], code, 0)
+            acc = code if acc is None else acc | (code << shift)
+            shift += bits
+        out_k.append(acc)
+        out_v.append(None)
+    packed = {j for pack in packs for j, _b in pack}
+    for j, (k, v) in enumerate(zip(keys, valids)):
+        if j not in packed:
+            out_k.append(k)
+            out_v.append(v)
+    return out_k, out_v
+
+
 class _MCol(NamedTuple):
     """Host-side column metadata threaded through the shadow pass: the
     same (dtype, validity presence, dictionary) triple pipeline_host_pass
@@ -651,6 +769,7 @@ class _ProgramBuilder:
         from . import operators as O
         from .exchange import BroadcastExchangeExec, ShuffleExchangeExec
         from .fusion import FusedAggregateExec, FusedLimitExec
+        from .window import WindowExec
 
         if isinstance(node, (O.LocalTableScanExec, O.RangeExec,
                              O.ScanExec, _StageOutput)):
@@ -684,6 +803,10 @@ class _ProgramBuilder:
         if isinstance(node, O.HashJoinExec):
             self._member(node)
             return self._lower_join(node)
+        if isinstance(node, WindowExec):
+            low = self.lower(node.child)
+            self._member(node)
+            return self._lower_window(node, low)
         if isinstance(node, O.ComputeExec):
             low = self.lower(node.child)
             self._member(node)
@@ -884,9 +1007,11 @@ class _ProgramBuilder:
                     sdict = low.metas[vi].sdict
             buf_metas.append(_MCol(f.dataType,
                                    op not in ("count", "countstar"), sdict))
+        packs = _plan_key_packs([low.metas[i] for i in key_idx])
         self.key.append(("agg", node.mode, ops, key_idx, val_idx,
                          key_bool, tuple((bi, n) for bi, (_r, _i, n)
-                                         in sorted(smm.items()))))
+                                         in sorted(smm.items())))
+                        + ((packs,) if packs else ()))
 
         def pipe_vals(d, v, m):
             vd, vv = [], []
@@ -966,7 +1091,7 @@ class _ProgramBuilder:
                     kd = kd.astype(jnp.int32)
                 key_eqs.append(kd)
             key_valids = [v[i] for i in key_idx]
-            layout = G.group_rows(key_eqs, key_valids, m)
+            layout = G.group_rows(*_pack_keys(packs, key_eqs, key_valids), m)
             out_keys = [G.scatter_group_keys(layout, d[i], v[i])
                         for i in key_idx]
             vd, vv = pipe_vals(d, v, m)
@@ -976,6 +1101,65 @@ class _ProgramBuilder:
             datas = [kd for kd, _kv in out_keys] + [bd for bd, _ in bufs]
             valids = [kv for _kd, kv in out_keys] + [bv for _, bv in bufs]
             return datas, valids, out_mask
+
+        return _Lowered(metas, cap, emit)
+
+    # -- window ------------------------------------------------------------
+    def _lower_window(self, node, low: _Lowered) -> _Lowered:
+        """WindowExec inside the program: `physical/window.trace_window`,
+        the body the per-partition kernel traces, at the child flow's
+        capacity and under its live-row mask. The flow keeps its rows and
+        their order and gains one column per window expression. String
+        partition keys are the flow's dictionary codes (one dictionary a
+        column: equality is all a partition needs), several to a sort key
+        where they fit (`_plan_key_packs`)."""
+        jnp = _jnp()
+        from ..ops.sorting import SortKeySpec
+        from .window import trace_window
+
+        pos = {a.expr_id: i for i, a in enumerate(node.child.output)}
+        pk = tuple(pos[k.expr_id] for k in node.partition_keys)
+        ok = tuple(pos[o.child.expr_id] for o in node.order_keys)
+        ospecs = [SortKeySpec(o.ascending, o.nulls_first)
+                  for o in node.order_keys]
+        plans = node._plans()
+        finish = node._finish()
+        # value plane per expr: a column, the row mask as ones (count(*)
+        # counts frame rows), or nothing (the ranks)
+        vi = tuple(pos[arg.expr_id] if arg is not None
+                   else -1 if kind.endswith("_count") else None
+                   for kind, _param, arg in plans)
+        is_bool = {i: isinstance(low.metas[i].dtype, BooleanType)
+                   for i in pk + ok}
+        packs = _plan_key_packs([low.metas[i] for i in pk])
+        self.key.append(("window", pk, ok,
+                         tuple((s.ascending, s.nulls_first) for s in ospecs),
+                         tuple((kind, v) for (kind, _p, _a), v
+                               in zip(plans, vi)),
+                         tuple(sig for _want, _avg, sig in finish), packs))
+        metas = list(low.metas) + [
+            _MCol(al.child.dtype,
+                  kind.startswith("agg_") and not kind.endswith("_count"),
+                  None)
+            for al, (kind, _p, _a) in zip(node.window_exprs, plans)]
+        cap = low.cap
+
+        def emit(args, needed, _low=low):
+            d, v, m = _low.emit(args, needed)
+
+            def key(i):
+                return d[i].astype(jnp.int32) if is_bool[i] else d[i]
+
+            ones = m.astype(jnp.int32)
+            outs = trace_window(
+                plans, ospecs, finish,
+                *_pack_keys(packs, [key(i) for i in pk], [v[i] for i in pk]),
+                [key(i) for i in ok], [v[i] for i in ok],
+                [None if i is None else ones if i < 0 else d[i]
+                 for i in vi],
+                [None if i is None or i < 0 else v[i] for i in vi], m)
+            return (list(d) + [od for od, _ov in outs],
+                    list(v) + [ov for _od, ov in outs], m)
 
         return _Lowered(metas, cap, emit)
 
@@ -1586,7 +1770,10 @@ class WholeQueryExec(PhysicalPlan):
                                     "whole_query.dense_guard_retries")
                                 bumped = True
                     att.set_args({"program": module_name(kernel),
-                                  "discarded": bumped})
+                                  "discarded": bumped,
+                                  "window_members": sum(
+                                      (sc or "").endswith(".Window")
+                                      for sc in b.scopes)})
                 if bumped:
                     continue
                 if attempt:

@@ -21,7 +21,7 @@ from ..expr.window import (
     CumeDist, DenseRank, FirstValue, Lag, LastValue, Lead, NthValue, NTile,
     PercentRank, Rank, RowNumber, WindowExpression,
 )
-from ..types import DecimalType, StringType, float64, int32, int64
+from ..types import StringType, float64, int32, int64
 from .compile import GLOBAL_KERNEL_CACHE
 from .operators import PhysicalPlan, attrs_schema
 from .partitioning import AllTuples, ClusteredDistribution, UnspecifiedDistribution
@@ -31,6 +31,90 @@ def _jnp():
     import jax.numpy as jnp
 
     return jnp
+
+
+def trace_window(plans, ospecs, finish, pkeys, pvalids, okeys, ovalids,
+                 vdatas, vvalids, row_mask, kmin: int = 0, band: int = 0):
+    """The operator's traced body: one layout sort, each expression's
+    frame computation over the sorted layout, the scatter back to the
+    input's row order, and the cast to the expression's type. Every tier
+    traces THIS (the per-partition kernel below, the whole-query
+    program's `_lower_window`): there is no second copy of the frame
+    logic. `plans` is `WindowExec._plans()`, `finish` its `_finish()`;
+    returns one (data, validity | None) per expression, at the input's
+    capacity and in its row order."""
+    import jax
+
+    from ..ops import window as W
+
+    lo = W.build_layout(pkeys, pvalids, okeys, ovalids, ospecs, row_mask)
+    outs = []
+    for (kind, param, _), (want, avg, _sig), vd, vv in zip(
+            plans, finish, vdatas, vvalids):
+        with jax.named_scope("frame"):
+            if kind == "row_number":
+                sv, svalid = W.w_row_number(lo), None
+            elif kind == "rank":
+                sv, svalid = W.w_rank(lo), None
+            elif kind == "dense_rank":
+                sv, svalid = W.w_dense_rank(lo), None
+            elif kind == "percent_rank":
+                sv, svalid = W.w_percent_rank(lo), None
+            elif kind == "cume_dist":
+                sv, svalid = W.w_cume_dist(lo), None
+            elif kind == "ntile":
+                sv, svalid = W.w_ntile(lo, param), None
+            elif kind == "shift":
+                sv, svalid = W.w_shift(lo, vd, vv, param)
+            elif kind == "first_value":
+                sv, svalid = W.w_first_value(lo, vd, vv)
+            elif kind == "last_value":
+                sv, svalid = W.w_last_value(lo, vd, vv,
+                                            whole=param == "partition")
+            elif kind == "nth_value":
+                sv, svalid = W.w_nth_value(lo, vd, vv, param[0],
+                                           whole=param[1] == "partition")
+            elif kind.startswith("agg_vrange_"):
+                sv, svalid = W.w_agg_value_range(
+                    lo, okeys[0], vd, vv, kind.split("_")[-1],
+                    param[0], param[1], kmin, band, avg=avg)
+            elif kind.startswith("agg_rows_"):
+                sv, svalid = W.w_agg_rows(lo, vd, vv, kind.split("_")[-1],
+                                          param[0], param[1], avg=avg)
+            elif kind.startswith("agg_running_"):
+                sv, svalid = W.w_agg_running(lo, vd, vv,
+                                             kind.split("_")[-1], avg=avg)
+            elif kind.startswith("agg_unbounded_"):
+                sv, svalid = W.w_agg_unbounded(lo, vd, vv,
+                                               kind.split("_")[-1], avg=avg)
+            else:
+                raise ValueError(kind)
+            if str(sv.dtype) != want:
+                sv = sv.astype(want)
+        outs.append(W.scatter_back(lo, sv, svalid))
+    return outs
+
+
+def _average_finish(fn: Average, name: str, expr_id: int):
+    """AVG's (total, count) -> (data, validity) as the aggregate finishes
+    it: `aggregates.lower_aggregate_function`'s result expression (sum /
+    count, cast to AVG's type; NULL over no value) traced over the
+    frame's total and count. So a window's average and a GROUP BY's are
+    one computation to one type, AVG over DECIMAL included."""
+    from .aggregates import lower_aggregate_function
+    from .compile import trace_pipeline
+
+    spec = lower_aggregate_function(fn, name, expr_id)
+
+    def avg(total, cnt):
+        jnp = _jnp()
+        cap = total.shape[0]
+        d, v, _m = trace_pipeline(spec.buffer_attrs, [], [spec.result_alias],
+                                  [total, cnt], [None, None],
+                                  jnp.ones((cap,), dtype=bool), [], cap)
+        return d[0], v[0]
+
+    return avg
 
 
 class WindowExec(PhysicalPlan):
@@ -128,6 +212,21 @@ class WindowExec(PhysicalPlan):
                     f"window function {type(f).__name__}")
         return out
 
+    def _finish(self):
+        """(device dtype, AVG's finishing | None, key fragment) per window
+        expr: how `trace_window` turns a frame's value into the column."""
+        out = []
+        for al in self.window_exprs:
+            fn = al.child.function
+            want = str(al.child.dtype.device_dtype)
+            avg = None
+            sig = (want,)
+            if isinstance(fn, Average):
+                avg = _average_finish(fn, al.name, al.expr_id)
+                sig = (want, str(fn.child.dtype))
+            out.append((want, avg, sig))
+        return out
+
     def execute(self, ctx: ExecContext):
         from .adaptive import coalesce_after_exchange
 
@@ -139,7 +238,6 @@ class WindowExec(PhysicalPlan):
     def _run_partition(self, part) -> ColumnarBatch:
         import jax
 
-        from ..ops import window as W
         from ..ops.sorting import SortKeySpec
 
         jnp = _jnp()
@@ -153,6 +251,7 @@ class WindowExec(PhysicalPlan):
                   for o in self.order_keys]
 
         plans = self._plans()
+        finish = self._finish()
         vcols = []
         for kind, param, arg in plans:
             if arg is not None:
@@ -206,57 +305,15 @@ class WindowExec(PhysicalPlan):
                tuple((k, p, "ones" if isinstance(v, str) else
                       None if v is None else
                       (str(v.data.dtype), v.validity is not None))
-                     for (k, p, _), v in zip(plans, vcols)))
+                     for (k, p, _), v in zip(plans, vcols)),
+               tuple(sig for _want, _avg, sig in finish))
 
         def build():
             def kernel(pkeys, pvalids, okeys, ovalids, vdatas, vvalids,
                        row_mask):
-                lo = W.build_layout(pkeys, pvalids, okeys, ovalids, ospecs,
-                                    row_mask)
-                outs = []
-                for (kind, param, _), vd, vv in zip(plans, vdatas, vvalids):
-                    if kind == "row_number":
-                        sv, svalid = W.w_row_number(lo), None
-                    elif kind == "rank":
-                        sv, svalid = W.w_rank(lo), None
-                    elif kind == "dense_rank":
-                        sv, svalid = W.w_dense_rank(lo), None
-                    elif kind == "percent_rank":
-                        sv, svalid = W.w_percent_rank(lo), None
-                    elif kind == "cume_dist":
-                        sv, svalid = W.w_cume_dist(lo), None
-                    elif kind == "ntile":
-                        sv, svalid = W.w_ntile(lo, param), None
-                    elif kind == "shift":
-                        sv, svalid = W.w_shift(lo, vd, vv, param)
-                    elif kind == "first_value":
-                        sv, svalid = W.w_first_value(lo, vd, vv)
-                    elif kind == "last_value":
-                        sv, svalid = W.w_last_value(lo, vd, vv,
-                                                    whole=param ==
-                                                    "partition")
-                    elif kind == "nth_value":
-                        sv, svalid = W.w_nth_value(
-                            lo, vd, vv, param[0],
-                            whole=param[1] == "partition")
-                    elif kind.startswith("agg_vrange_"):
-                        sv, svalid = W.w_agg_value_range(
-                            lo, okeys[0], vd, vv, kind.split("_")[-1],
-                            param[0], param[1], kmin, band)
-                    elif kind.startswith("agg_rows_"):
-                        sv, svalid = W.w_agg_rows(lo, vd, vv,
-                                                  kind.split("_")[-1],
-                                                  param[0], param[1])
-                    elif kind.startswith("agg_running_"):
-                        sv, svalid = W.w_agg_running(lo, vd, vv,
-                                                     kind.split("_")[-1])
-                    elif kind.startswith("agg_unbounded_"):
-                        sv, svalid = W.w_agg_unbounded(lo, vd, vv,
-                                                       kind.split("_")[-1])
-                    else:
-                        raise ValueError(kind)
-                    outs.append(W.scatter_back(lo, sv, svalid))
-                return outs
+                return trace_window(plans, ospecs, finish, pkeys, pvalids,
+                                    okeys, ovalids, vdatas, vvalids,
+                                    row_mask, kmin, band)
 
             return jax.jit(kernel)
 
@@ -276,18 +333,6 @@ class WindowExec(PhysicalPlan):
         new_cols = list(batch.columns)
         for (d, v), al in zip(outs, self.window_exprs):
             dt = al.child.dtype
-            fn = al.child.function
-            if isinstance(dt, DecimalType) and isinstance(fn, Average) \
-                    and isinstance(getattr(fn.child, "dtype", None),
-                                   DecimalType):
-                # the kernel's avg is sum/count in the INPUT scale; the
-                # result decimal carries a wider scale (reference:
-                # Average resultType = DecimalType(p+4, s+4)); round
-                # half-to-even like the cast path, don't truncate
-                d = jnp.rint(d * (10.0 ** (dt.scale - fn.child.dtype.scale)))
-            want = dt.device_dtype
-            if str(d.dtype) != str(want):
-                d = d.astype(want)
             sdict = None
             if isinstance(dt, StringType):
                 # shift over strings keeps the source dictionary

@@ -895,6 +895,42 @@ class ExtractWindowExpressions(Rule):
         return plan.transform_up(rule)
 
 
+class ResolveSortOrdinals(Rule):
+    """ORDER BY <integer literal> names a position of the select list
+    (reference: SubstituteUnresolvedOrdinals + ResolveOrdinalInOrderByAnd
+    GroupBy). The parser binds it where the list is written out
+    (sql/parser.parse_sort_item); under `SELECT *` the list exists only
+    once the star is expanded, so it is bound here (TPC-DS q47's
+    `SELECT * FROM v2 ... ORDER BY sum_sales - avg_monthly_sales, 3`).
+    A literal outside the list stays a constant."""
+
+    def apply(self, plan):
+        def rule(node):
+            if not (isinstance(node, Sort)
+                    and isinstance(node.child, (Project, Aggregate))
+                    and node.child.resolved):
+                return node
+            try:
+                out = node.child.output
+            except AnalysisException:      # an aggregate not yet aliased
+                return node
+
+            def bind(o):
+                e = o.child
+                if isinstance(e, Literal) and type(e.value) is int \
+                        and 1 <= e.value <= len(out):
+                    return SortOrder(out[e.value - 1], o.ascending,
+                                     o.nulls_first)
+                return o
+
+            orders = [bind(o) for o in node.orders]
+            if all(a is b for a, b in zip(orders, node.orders)):
+                return node
+            return node.copy(orders=orders)
+
+        return plan.transform_up(rule)
+
+
 class ResolveSortHiddenRefs(Rule):
     """ORDER BY may reference columns of the FROM clause that are not in the
     SELECT list (reference: Analyzer ResolveMissingReferences) — resolve them
@@ -1256,6 +1292,7 @@ class Analyzer(RuleExecutor):
                 ResolveSubqueries(self),
                 GlobalAggregates(),
                 ResolveAggsInSortHaving(cs),
+                ResolveSortOrdinals(),
                 ResolveSortHiddenRefs(cs),
                 # AFTER the HAVING/ORDER rules: a real column reachable
                 # through the aggregate child must win over a session
@@ -1294,6 +1331,7 @@ class Analyzer(RuleExecutor):
             ResolveSubqueries(self),
             GlobalAggregates(),
             ResolveAggsInSortHaving(cs),
+            ResolveSortOrdinals(),
             ResolveSortHiddenRefs(cs),
             ExtractGenerators(),
             ExtractWindowFromAggregate(),

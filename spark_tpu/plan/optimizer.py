@@ -11,10 +11,13 @@ aggregate, collapse projects, and empty-relation propagation.
 from __future__ import annotations
 
 import datetime
+import decimal
 import math
 from typing import Sequence
 
-from ..types import BooleanType, NullType, boolean
+from ..types import (
+    BooleanType, DecimalType, DoubleType, NullType, boolean,
+)
 from .logical import (
     Aggregate, Distinct, Filter, Join, Limit, LocalRelation, LogicalPlan,
     LogicalRelation, Project, RangeRelation, Repartition, Sample, Sort,
@@ -25,8 +28,8 @@ from ..expr.expressions import (
     Add, Alias, And, AttributeReference, BinaryComparison, Cast, CaseWhen,
     Coalesce, Divide, EqualTo, Expression, GreaterThan, GreaterThanOrEqual,
     In, IsNotNull, IsNull, LessThan, LessThanOrEqual, Literal, Multiply, Not,
-    NotEqualTo, Or, Remainder, SortOrder, Subtract, UnaryMinus,
-    AggregateFunction,
+    NotEqualTo, Or, QuotientComparison, Remainder, SortOrder, Subtract,
+    UnaryMinus, AggregateFunction, If, exact_numeric,
 )
 
 __all__ = ["Optimizer", "split_conjuncts", "substitute_attrs"]
@@ -248,6 +251,81 @@ class SimplifyCasts(Rule):
         def rule(node):
             if node.expressions_resolved:
                 return node.transform_expressions(simp)
+            return node
+
+        return plan.transform_up(rule)
+
+
+_MIRRORED = {"=": "=", "!=": "!=", "<": ">", "<=": ">=", ">": "<", ">=": "<="}
+
+
+def _exact_literal(c: Expression) -> Literal | None:
+    """`c` as a literal of a DECIMAL or an integral type, if it is one. A
+    double stands for the shortest decimal that reads back as it, which is
+    what the query's text said: the parser reads `0.1` as a double where
+    the reference reads DECIMAL(1,1)."""
+    if not isinstance(c, Literal) or c.value is None:
+        return None
+    if exact_numeric(c.dtype):
+        return c
+    if isinstance(c.dtype, DoubleType) and math.isfinite(c.value):
+        _sign, digits, exponent = decimal.Decimal(repr(c.value)).as_tuple()
+        scale = max(-exponent, 0)
+        precision = max(len(digits) + max(exponent, 0), scale)
+        if precision <= DecimalType.MAX_PRECISION:
+            return Literal(decimal.Decimal(repr(c.value)),
+                           DecimalType(precision, scale))
+    return None
+
+
+class DecideQuotientComparisons(Rule):
+    """`a / b <op> c`, with a and b DECIMAL or integral and c a literal
+    that `_exact_literal` reads, is decided on integers
+    (`QuotientComparison`) and not on the float64 that `Divide` gives:
+    which months lie more than a tenth from their average (TPC-DS q89,
+    q47, q57) may not hang on how a platform rounds. The comparison is first moved into the values of a
+    CASE or an IF, as the reference's PushFoldableIntoBranches moves it:
+    the templates guard the quotient with `CASE WHEN b > 0 THEN a / b
+    END`."""
+
+    def apply(self, plan):
+        def into(value: Expression, c: Literal, op: str) -> Expression | None:
+            """`value <op> c` decided exactly, or None where `value` is not
+            made of exact quotients and NULLs alone."""
+            if isinstance(value, Literal) and value.value is None:
+                return Literal(None, boolean)
+            if isinstance(value, Divide):
+                if exact_numeric(value.left.dtype) \
+                        and exact_numeric(value.right.dtype):
+                    return QuotientComparison(value.left, value.right, c, op)
+                return None
+            if isinstance(value, If):
+                value = CaseWhen([(value.pred, value.then)], value.otherwise)
+            if isinstance(value, CaseWhen):
+                values = [into(v, c, op) for _p, v in value.branches] \
+                    + [into(value.else_expr, c, op)]
+                if all(v is not None for v in values) and not all(
+                        isinstance(v, Literal) for v in values):
+                    return CaseWhen(
+                        [(p, v) for (p, _), v in zip(value.branches, values)],
+                        values[-1])
+            return None
+
+        def decide(e: Expression) -> Expression:
+            if not isinstance(e, BinaryComparison) \
+                    or e.symbol not in _MIRRORED:
+                return e
+            for value, c, op in ((e.left, e.right, e.symbol),
+                                 (e.right, e.left, _MIRRORED[e.symbol])):
+                c = _exact_literal(c)
+                if c is not None:
+                    decided = into(value, c, op)
+                    return e if decided is None else decided
+            return e
+
+        def rule(node):
+            if node.expressions_resolved:
+                return node.transform_expressions(decide)
             return node
 
         return plan.transform_up(rule)
@@ -1471,6 +1549,7 @@ class Optimizer(RuleExecutor):
                 ConstantFolding(),
                 BooleanSimplification(),
                 SimplifyCasts(),
+                DecideQuotientComparisons(),
                 PruneFilters(),
                 PropagateEmptyRelation(),
                 CombineUnions(),

@@ -518,3 +518,38 @@ def test_string_minmax_fused_prediction_exact(fusion_conf, data, enabled):
     data.conf.set("spark.tpu.fusion.enabled", enabled)
     _assert_exact(data, "select k, min(s) mn, max(s) mx, count(*) c "
                         "from an_t where v > 0 group by k")
+
+
+Q_WINDOW = ("select label, k, sv, avg(sv) over (partition by label) a, "
+            "rank() over (partition by label order by sv desc) r from "
+            "(select label, k, sum(v) sv from an_t join an_dim on k = dk "
+            "group by label, k) t")
+
+
+@pytest.mark.parametrize("query,windows", [
+    (Q_WINDOW, 2),
+    (Q_WINDOW.replace("avg(sv)", "lag(sv)")
+     .replace("(partition by label) a",
+              "(partition by label order by k) a"), 0)],
+    ids=["lowered", "refused"])
+def test_window_whole_tier_prediction_exact(fusion_conf, data, query,
+                                            windows):
+    """A window over a join and an aggregate: with a lowering
+    (whole_query._lower_window) the mirror counts the one program and the
+    window's planes; refused by name (lag), the tier decision says so."""
+    data.conf.set("spark.tpu.compile.tier", "whole")
+    try:
+        report, measured = _predicted_vs_measured(data, query)
+        tier = report.tier or {}
+        if windows:
+            assert report.exact, report.inexact_reasons
+            assert report.predicted_launches == measured \
+                == {"whole_query": 1}, (report.predicted_launches, measured)
+            assert tier.get("tier") == "whole"
+            assert report.predicted_peak_hbm > 0
+        else:
+            assert tier.get("tier") == "stage"
+            assert "window function lag" in tier.get("reason", ""), tier
+            assert "whole_query" not in measured
+    finally:
+        data.conf.unset("spark.tpu.compile.tier")

@@ -136,6 +136,21 @@ def test_order_by_ordinal_and_group_by_ordinal(store):
     assert out["item"] == [1, 2, 3, 4]
 
 
+@pytest.mark.parametrize("query,qty", [
+    ("SELECT * FROM sales ORDER BY 1 DESC, 2", [5, 30, 20, 50, 10, 40, 60]),
+    ("SELECT * FROM (SELECT item, qty FROM sales) t WHERE qty > 5 "
+     "ORDER BY qty - item * 100, 2", [30, 20, 50, 10, 40, 60]),
+    ("SELECT * FROM sales ORDER BY price, 2 DESC",
+     [30, 60, 40, 10, 50, 20, 5]),
+    # a literal outside the list is a constant, as it was
+    ("SELECT * FROM sales ORDER BY qty, 9", [5, 10, 20, 30, 40, 50, 60])],
+    ids=["star", "star_over_subquery", "after_a_name", "out_of_range"])
+def test_order_by_ordinal_under_select_star(store, query, qty):
+    """An ordinal names a column of the expanded star (q47's
+    `SELECT * FROM v2 ... ORDER BY sum_sales - avg_monthly_sales, 3`)."""
+    assert q(store, query)["qty"] == qty
+
+
 def test_date_literal(spark):
     out = q(spark, "SELECT year(DATE '2021-03-15') AS y, "
                    "month(DATE '2021-03-15') AS m")
@@ -354,3 +369,65 @@ def test_session_variables(spark):
 
     with _pytest.raises(Exception, match="sv_threshold"):
         spark.sql("SELECT sv_threshold AS t").toArrow()
+
+
+@pytest.fixture()
+def ratios(spark):
+    """a DECIMAL(17,2), b DECIMAL(18,6), n and d BIGINT, x DOUBLE: rows on
+    both sides of a tenth of b and exactly on it, a negative and a zero
+    divisor, a NULL, and a row too large for 64-bit products."""
+    from decimal import Decimal as D
+
+    a = ["1150.47", "1150.46", "1150.48", "1406.13", "1406.14", "-127.83",
+         None, "5.00", "900000000000.00"]
+    b = ["1278.300000"] * 5 + ["-1278.300000", "1.000000", "0.000000",
+                               "300000.000000"]
+    spark.createDataFrame(pa.table({
+        "a": pa.array([None if v is None else D(v) for v in a],
+                      pa.decimal128(17, 2)),
+        "b": pa.array([D(v) for v in b], pa.decimal128(18, 6)),
+        "n": pa.array([1, 2, 3, -1, 7, 10, 0, 5, 2 ** 62], pa.int64()),
+        "d": pa.array([10, 20, 29, -10, 70, 99, 3, 0, 7], pa.int64()),
+        "x": pa.array([0.1] * 9, pa.float64()),
+    })).createOrReplaceTempView("ratios")
+    return spark
+
+
+@pytest.mark.parametrize("predicate,want,exact", [
+    ("CASE WHEN b > 0 THEN abs(a - b) / b ELSE NULL END > 0.1",
+     [False, True, False, False, True, None, None, None, True], True),
+    ("abs(a - b) / b >= 0.1",
+     [True, True, False, True, True, False, None, None, True], True),
+    ("0.1 < abs(a - b) / b",
+     [False, True, False, False, True, False, None, None, True], True),
+    ("if(b <> 0, a / b, NULL) = 0.1",
+     [False, False, False, False, False, True, None, None, False], True),
+    ("a / b != 0.9",
+     [False, True, True, True, True, True, None, None, True], True),
+    ("n / d <= 0.1",
+     [True, True, False, True, True, False, True, None, False], True),
+    ("n / d > 1",
+     [False, False, False, False, False, False, False, None, True], True),
+    # not a quotient of exact operands, or no literal: as it was
+    ("n / d > x",
+     [False, False, True, False, False, True, False, None, True], False),
+    ("x / d < 0.1",
+     [True, True, True, True, True, True, True, None, True], False)],
+    ids=["case_guard", "ge", "mirrored", "if_eq_negative_divisor", "ne",
+         "integral", "integral_literal", "column_other", "double_operand"])
+def test_decimal_quotient_compared_with_a_literal_is_decided_exactly(
+        ratios, predicate, want, exact):
+    """q89's and q47's `CASE WHEN avg > 0 THEN abs(sum - avg) / avg END >
+    0.1`: 127.83 / 1278.3 is a tenth and not more, whatever the
+    platform's float64 makes of it; NULL where the quotient is."""
+    from spark_tpu.expr.expressions import QuotientComparison
+
+    df = ratios.sql(f"SELECT {predicate} AS p FROM ratios")
+    rewritten = any(
+        isinstance(n, QuotientComparison)
+        for e in df.query_execution.optimized.expressions()
+        for n in e.iter_nodes())
+    assert rewritten == exact
+    assert df.toArrow().to_pydict()["p"] == want
+    kept = ratios.sql(f"SELECT a FROM ratios WHERE {predicate}").toArrow()
+    assert kept.num_rows == sum(w is True for w in want)

@@ -618,3 +618,350 @@ def test_mesh_quota_retry_reuses_staged_planes(tiers, spark, monkeypatch):
 
     assert GLOBAL_LEDGER.verify() == [], \
         "device ledger unbalanced after retry"
+
+
+# ---------------------------------------------------------------------------
+# window functions in the whole-query program (physical/whole_query.py
+# _lower_window over physical/window.trace_window)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def wdata(spark):
+    """Seeded rows with NULL keys, NULL values, ties, a partition whose
+    values are all NULL, and key columns of every admitted type."""
+    import datetime
+    from decimal import Decimal
+
+    rng = np.random.default_rng(29)
+    n = 700
+
+    def nulls(values, frac, typ=None):
+        mask = rng.random(n) < frac
+        return pa.array([None if m else v for v, m in zip(values, mask)],
+                        typ)
+
+    pi = rng.integers(0, 9, n)
+    day0 = datetime.date(1999, 1, 1)
+    vi = rng.integers(-40, 90, n).tolist()
+    vdec = [Decimal(int(x)).scaleb(-2) for x in rng.integers(-9999, 99999, n)]
+    for i in range(n):               # partition 3: no value at all
+        if pi[i] == 3:
+            vi[i] = None
+            vdec[i] = None
+    table = pa.table({
+        "uid": pa.array(np.arange(n, dtype=np.int32)),
+        "pi": nulls(pi.astype(np.int32).tolist(), 0.05, pa.int32()),
+        "pd": nulls([day0 + datetime.timedelta(int(x) % 5) for x in pi],
+                    0.05, pa.date32()),
+        "pdec": nulls([Decimal(int(x) % 4).scaleb(-1) for x in pi], 0.05,
+                      pa.decimal128(5, 1)),
+        "ps": nulls([f"store{x % 6}" for x in pi], 0.05, pa.string()),
+        "ps2": pa.array([f"co{x % 2}" for x in range(n)]),
+        "oi": nulls(rng.integers(0, 12, n).astype(np.int32).tolist(), 0.05,
+                    pa.int32()),
+        "od": nulls([day0 + datetime.timedelta(int(x))
+                     for x in rng.integers(0, 12, n)], 0.05, pa.date32()),
+        "odec": nulls([Decimal(int(x)).scaleb(-2)
+                       for x in rng.integers(0, 12, n)], 0.05,
+                      pa.decimal128(7, 2)),
+        "vi": nulls(vi, 0.1, pa.int32()),
+        "vf": nulls(rng.random(n).tolist(), 0.1, pa.float64()),
+        "vdec": nulls(vdec, 0.1, pa.decimal128(7, 2)),
+    })
+    spark.createDataFrame(table).createOrReplaceTempView("wq_w")
+    return spark
+
+
+def _window_rows(spark, tier, select, where=""):
+    spark.conf.set("spark.tpu.compile.tier", tier)
+    df = spark.sql(f"select uid, {select} from wq_w {where}")
+    phys = df.query_execution.physical
+    dec = getattr(phys, "decision", None) \
+        or getattr(phys, "_tier_decision", None)
+    t = df.toArrow()
+    rows = sorted(zip(*[c.to_pylist() for c in t.columns]))
+    return dec, rows, t.schema
+
+
+_AGGS = ", ".join(f"{fn}({v}) over (partition by {{pk}}) {fn}_{v}"
+                  for fn in ("sum", "avg", "min", "max", "count")
+                  for v in ("vi", "vf", "vdec")) \
+    + ", count(*) over (partition by {pk}) n"
+_RANKS = ", ".join(f"{fn}() over (partition by {{pk}} order by {{ok}}) {fn}_"
+                   for fn in ("rank", "dense_rank")) \
+    + ", row_number() over (partition by {pk} order by {ok}, uid) rn"
+_RUNNING = ", ".join(f"{fn}({v}) over (partition by {{pk}} order by {{ok}}) "
+                     f"{fn}_{v}"
+                     for fn in ("sum", "avg", "min", "max", "count")
+                     for v in ("vi", "vdec"))
+
+WINDOW_ADMITTED = (
+    [(f"whole_partition-by_{pk.replace(', ', '_')}", _AGGS.format(pk=pk))
+     for pk in ("pi", "pd", "pdec", "ps", "ps, ps2, pi")]
+    + [(f"ranks-by_{pk}-order_{ok.split()[0]}",
+        _RANKS.format(pk=pk, ok=ok))
+       for pk, ok in (("pi", "oi"), ("ps", "od desc"), ("pd", "odec"),
+                      ("ps, ps2", "oi desc nulls last, od"))]
+    + [(f"running-by_{pk}-order_{ok}", _RUNNING.format(pk=pk, ok=ok))
+       for pk, ok in (("pi", "oi"), ("ps", "od"), ("pdec", "odec"))]
+    + [("no_partition_key", "sum(vi) over () s, rank() over (order by oi) r")])
+
+
+@pytest.mark.parametrize("where", ["", "where uid < 0"],
+                         ids=["rows", "empty_input"])
+@pytest.mark.parametrize("select", [s for _n, s in WINDOW_ADMITTED],
+                         ids=[n for n, _s in WINDOW_ADMITTED])
+def test_window_whole_tier_gives_the_operator_tiers_rows(tiers, wdata,
+                                                         select, where):
+    """Every admitted function x frame x key type: the whole tier runs
+    the window inside its one program and returns the operator tier's
+    rows and types, NULL keys (one partition), NULL values (skipped; a
+    partition with none is NULL), ties and an empty input included."""
+    dec, rows, schema = _window_rows(wdata, "whole", select, where)
+    assert dec.tier == "whole", dec
+    before, misses = dict(KC.launches_by_kind), KC.misses
+    _dec, again, _s = _window_rows(wdata, "whole", select, where)
+    kinds = {k: v - before.get(k, 0) for k, v in KC.launches_by_kind.items()
+             if v != before.get(k, 0)}
+    assert kinds == {"whole_query": 1}, kinds
+    assert KC.misses == misses, "the same query built its program again"
+    _dec, want, want_schema = _window_rows(wdata, "operator", select, where)
+    assert schema == want_schema
+    assert rows == want == again
+    assert bool(rows) != bool(where)
+
+
+WINDOW_REFUSED = [
+    ("lag(vi) over (partition by pi order by oi, uid) x", "function lag"),
+    ("lead(vi, 2) over (partition by pi order by oi, uid) x",
+     "function lead"),
+    ("ntile(3) over (partition by pi order by oi, uid) x", "function ntile"),
+    ("nth_value(vi, 2) over (partition by pi order by oi, uid) x",
+     "function nthvalue"),
+    ("first_value(vi) over (partition by pi order by oi, uid) x",
+     "function firstvalue"),
+    ("percent_rank() over (partition by pi order by oi) x",
+     "function percentrank"),
+    ("sum(vi) over (partition by pi order by oi, uid rows between 1 "
+     "preceding and 1 following) x", "frame ROWS BETWEEN -1 AND 1 of sum"),
+    ("avg(vdec) over (partition by pi order by oi, uid rows between "
+     "unbounded preceding and current row) x",
+     "frame ROWS BETWEEN None AND 0 of average"),
+    ("sum(vi) over (partition by pi order by uid range between 2 preceding "
+     "and current row) x", "frame VRANGE BETWEEN -2 AND 0 of sum"),
+    ("rank() over (partition by pi order by ps) x",
+     "order key ps is a string"),
+    ("min(ps) over (partition by pi) x",
+     "function min over the dictionary-encoded ps"),
+]
+
+
+@pytest.mark.parametrize("select,reason", WINDOW_REFUSED,
+                         ids=[r.replace(" ", "_") for _s, r in
+                              WINDOW_REFUSED])
+def test_window_refused_by_name_runs_on_the_stage_tier(tiers, wdata, select,
+                                                       reason):
+    """What `_lower_window` does not trace is refused with a reason that
+    names the function, frame or key, and answers on the stage tier."""
+    dec, rows, _schema = _window_rows(wdata, "whole", select)
+    assert dec.tier == "stage", dec
+    assert "whole-query fallback: window" in dec.reason, dec.reason
+    assert reason in dec.reason, dec.reason
+    _dec, want, _s = _window_rows(wdata, "stage", select)
+    assert rows == want and rows
+
+
+def test_window_member_scopes_and_prediction(tiers, wdata):
+    """The window is a member of the program under `mNN.Window` with its
+    three phases as scopes; the attempt span says how many Window members
+    the program holds; plan_lint predicts the one launch."""
+    import time
+
+    from spark_tpu.obs.tracing import recorded_spans
+    from spark_tpu.physical.compile import capture_programs
+
+    wdata.conf.set("spark.tpu.compile.tier", "whole")
+    q = ("select uid, avg(vdec) over (partition by ps) a, rank() over "
+         "(partition by ps order by oi) r from wq_w where uid >= 0")
+    df = wdata.sql(q)
+    report = df.query_execution.analysis_report()
+    assert report.exact, report.inexact_reasons
+    assert (report.tier or {}).get("tier") == "whole"
+    t0 = time.perf_counter()
+    with capture_programs() as programs:
+        df.toArrow()
+    assert report.predicted_launches == _measured(lambda: wdata.sql(q))
+    rec = programs[-1]
+    windows = [s for s in rec["scopes"] if s and s.endswith(".Window")]
+    assert len(windows) == 2, rec["scopes"]
+    text = rec["kernel"]._kernel.lower(*rec["args"]).as_text(debug_info=True)
+    for phase in ("layout_sort", "frame", "scatter_back"):
+        assert f"{windows[0]}/{phase}" in text, phase
+    attempts = [s for s in recorded_spans(t0)
+                if s["name"] == "whole_query.attempt"]
+    assert attempts and attempts[-1]["args"]["window_members"] == 2
+
+
+def test_window_counts_in_the_resident_estimate(tiers, wdata):
+    from spark_tpu.physical.whole_query import _estimate_resident_bytes
+
+    wdata.conf.set("spark.tpu.compile.tier", "stage")
+    plain = wdata.sql("select uid, ps, oi, vi from wq_w")
+    win = wdata.sql("select uid, ps, oi, vi, rank() over (partition by ps "
+                    "order by oi) r from wq_w")
+    a = _estimate_resident_bytes(plain.query_execution.physical, wdata.conf)
+    b = _estimate_resident_bytes(win.query_execution.physical, wdata.conf)
+    cap = 1024                     # 700 rows in one bucket
+    # the window's own output tile, the sort's operands, the layout
+    assert b - a >= cap * (4 + 12 * 2 + 8 + 36), (a, b)
+
+
+def test_mesh_whole_refuses_a_window_by_name(tiers, wdata):
+    from spark_tpu.physical.whole_query import supported_mesh_whole
+
+    wdata.conf.set("spark.tpu.compile.tier", "stage")
+    df = wdata.sql("select ps, sum(vi) over (partition by ps) s from wq_w") \
+        .repartition(4, "ps")
+    ok, why, _det = supported_mesh_whole(df.query_execution.physical,
+                                         wdata.conf)
+    assert not ok and "WindowExec has no mesh-whole lowering" in why, why
+    wdata.conf.set("spark.tpu.compile.tier", "mesh-whole")
+    df = wdata.sql("select ps, sum(vi) over (partition by ps) s from wq_w") \
+        .repartition(4, "ps")
+    dec = df.query_execution.physical.decision
+    assert dec.tier == "whole" and "WindowExec" in dec.reason, dec
+
+
+@pytest.fixture(scope="module")
+def wtpcds():
+    """tests/tpcds's tables in a session of their own (the module's other
+    tests register tpcds_mini's under the same names)."""
+    from spark_tpu import TpuSession
+    from tests.tpcds.datagen import gen_tpcds_full
+
+    s = TpuSession("wq-tpcds", {"spark.sql.shuffle.partitions": 4,
+                                "spark.tpu.batch.capacity": 1 << 12,
+                                "spark.tpu.compile.tier": "whole"})
+    for name, tab in gen_tpcds_full(scale=0.1).items():
+        s.createDataFrame(tab).createOrReplaceTempView(name)
+    yield s
+    s.stop()
+
+
+@pytest.mark.parametrize("qname", ["q89", "q47", "q57", "q98", "q12", "q20"])
+def test_tpcds_window_reports_whole_tier_match_the_oracle(wtpcds, qname):
+    """The monthly-deviation reports and their siblings, windows and all,
+    as whole-query programs against the sqlite oracle's golden rows."""
+    import json
+    import os
+
+    from test_tpcds_full import GOLDEN_DIR, QUERY_DIR, _norm_rows
+    from tests.tpcds.oracle import compare_rows, strip_trailing_limit
+
+    sql = strip_trailing_limit(
+        open(os.path.join(QUERY_DIR, f"{qname}.sql")).read())
+    before = dict(KC.launches_by_kind)
+    df = wtpcds.sql(sql)
+    assert type(df.query_execution.physical).__name__ == "WholeQueryExec"
+    rows = _norm_rows(df.toArrow())
+    kinds = {k for k, v in KC.launches_by_kind.items()
+             if v != before.get(k, 0)}
+    assert kinds == {"whole_query"}, kinds
+    golden = json.load(open(os.path.join(GOLDEN_DIR, f"{qname}.json")))
+    assert golden["tier"] == "oracle"
+    ok, msg = compare_rows(rows, [tuple(r) for r in golden["rows"]])
+    assert ok, msg
+
+
+@pytest.mark.parametrize("sizes,want", [
+    ((10, 20, 850, 10, 1), (((0, 4), (1, 5), (2, 10), (3, 4), (4, 1)),)),
+    ((850,), ()),                           # a lone string key gains nothing
+    ((0, 3), (((0, 1), (1, 2)),)),          # an empty dictionary: all NULL
+    ((2 ** 30,) * 3, (((0, 31), (1, 31)),)),  # 62 bits a pack; the third
+    #                                           key is left as it is
+], ids=["q89s_group_by", "one_key", "empty_dictionary", "over_62_bits"])
+def test_string_keys_pack_into_one_sort_key(sizes, want):
+    """Grouping and partition keys that are dictionary codes travel as
+    bit fields of one integer (code + 1, 0 for NULL): equal packed keys
+    are equal tuples, NULL = NULL included, and keys of other types stay
+    behind the packs, with their validity."""
+    import jax.numpy as jnp
+
+    from spark_tpu.physical.whole_query import (
+        _MCol, _pack_keys, _plan_key_packs,
+    )
+    from spark_tpu.types import StringType, int32
+
+    class Dict(list):
+        def __init__(self, n):
+            self.n = n
+
+        def __len__(self):
+            return self.n
+
+    metas = [_MCol(int32, True, None)] \
+        + [_MCol(StringType(), True, Dict(n)) for n in sizes]
+    packs = _plan_key_packs(metas[1:])
+    assert packs == want
+    packs = _plan_key_packs(metas)           # positions count every key
+    assert packs == tuple(tuple((j + 1, b) for j, b in p) for p in want)
+    rng = np.random.default_rng(1)
+    n = 400
+    codes = [rng.integers(0, 3, n)] + [
+        rng.integers(0, max(1, min(s, 4)), n) for s in sizes]
+    valids = [rng.random(n) < 0.8 for _ in codes]
+    keys, kvalids = _pack_keys(
+        packs, [jnp.asarray(c, jnp.int32) for c in codes],
+        [jnp.asarray(v) for v in valids])
+    packed = {j for p in packs for j, _b in p}
+    assert len(keys) == len(codes) - len(packed) + len(packs)
+    assert [v is None for v in kvalids[:len(packs)]] == [True] * len(packs)
+
+    def tuples(ks, vs):
+        cols = [np.where(np.asarray(v), np.asarray(k), -1) if v is not None
+                else np.asarray(k) for k, v in zip(ks, vs)]
+        return list(zip(*[c.tolist() for c in cols]))
+
+    a, b = tuples(keys, kvalids), tuples(codes, valids)
+    for i in range(0, n, 7):
+        for j in range(i, n, 13):
+            assert (a[i] == a[j]) == (b[i] == b[j]), (i, j)
+
+
+def test_materialised_cte_is_on_the_timeline(wtpcds):
+    """q47's `v1` runs inside `session.sql()`, before the outer plan has a
+    QueryExecution: a `cte.materialize` span names it and the rows it
+    spliced back, and holds the body's own program."""
+    import os
+    import time
+
+    from spark_tpu.obs.tracing import recorded_spans
+    from test_tpcds_full import QUERY_DIR
+
+    t0 = time.perf_counter()
+    df = wtpcds.sql(open(os.path.join(QUERY_DIR, "q47.sql")).read())
+    spans = recorded_spans(t0)       # sql() alone: nothing collected yet
+    cte = [s for s in spans if s["name"] == "cte.materialize"]
+    assert len(cte) == 1 and cte[0]["args"]["cte"] == "v1"
+    assert cte[0]["args"]["rows"] > 0
+    inside = [s for s in spans if s["name"] == "whole_query.attempt"
+              and cte[0]["ts"] <= s["ts"] <= cte[0]["ts"]
+              + cte[0]["dur_ms"] / 1000]
+    assert inside and inside[-1]["args"]["window_members"] == 2
+    assert df.toArrow().num_rows > 0
+
+
+def test_case_when_query_builds_its_program_once(tiers, data):
+    """`canonical_key` leaves out CaseWhen's `branches` (a second view of
+    its children, whose text carries attribute ids): the same text parsed
+    again finds the program it built, on every tier. (q89's and q47's
+    filters are CASE WHEN ... END > 0.1; each execution compiled anew.)"""
+    q = ("select k, sv from (select k, sum(v) sv from wq_t group by k) t "
+         "where case when sv <> 0 then abs(sv - 10) / sv else null end "
+         "> 0.1")
+    for tier in ("whole", "stage", "operator"):
+        data.conf.set("spark.tpu.compile.tier", tier)
+        first = data.sql(q).toArrow()
+        misses = KC.misses
+        assert data.sql(q).toArrow().equals(first)
+        assert KC.misses == misses, tier

@@ -232,3 +232,61 @@ def test_range_value_frame_min(spark):
         FROM wrv ORDER BY t""").toArrow().to_pydict()
     # windows by VALUE of t: t=1→{9}; t=2→{9,3}; t=5→{7}; t=6→{7,1}; t=10→{5}
     assert out["m"] == [9, 3, 7, 1, 5]
+
+
+@pytest.mark.parametrize("frame,whole_partition", [
+    ("", True),
+    ("order by t", False),
+    ("order by t rows between unbounded preceding and unbounded following",
+     True),
+    ("order by t rows between unbounded preceding and current row", False)],
+    ids=["whole_partition", "running", "explicit_unbounded", "rows_to_here"])
+@pytest.mark.parametrize("value,arrow", [
+    ("vdec", "decimal128(18, 6)"), ("vi", "double"), ("vf", "double")])
+def test_window_avg_is_the_aggregates_avg(spark, frame, whole_partition,
+                                          value, arrow):
+    """A window's AVG finishes through the aggregate's own expression
+    (`aggregates.lower_aggregate_function`): over a whole partition it is
+    the GROUP BY's average, value and type, AVG over DECIMAL included;
+    over a running frame it is the average of the rows so far; a frame
+    with no non-null value is NULL."""
+    from decimal import Decimal
+
+    import numpy as np
+    import pyarrow as pa
+
+    rng = np.random.default_rng(3)
+    n = 120
+    g = rng.integers(0, 6, n)
+    cents = rng.integers(-99999, 9999999, n)
+    dead = (g == 4) | (rng.random(n) < 0.15)   # group 4 has no value
+    spark.createDataFrame(pa.table({
+        "g": g, "t": np.arange(n),
+        "vdec": pa.array([None if d else Decimal(int(c)).scaleb(-2)
+                          for c, d in zip(cents, dead)],
+                         pa.decimal128(17, 2)),
+        "vi": pa.array([None if d else int(c) for c, d in zip(cents, dead)],
+                       pa.int64()),
+        "vf": pa.array([None if d else c / 7.0 for c, d in zip(cents, dead)],
+                       pa.float64()),
+    })).createOrReplaceTempView("wavg")
+    over = f"partition by g {frame}"
+    out = spark.sql(f"select g, t, avg({value}) over ({over}) a from wavg "
+                    "order by g, t").toArrow()
+    assert str(out.schema.field("a").type) == arrow
+    got = out.to_pydict()
+    by_group = spark.sql(f"select g, avg({value}) a from wavg group by g") \
+        .toArrow()
+    assert by_group.schema.field("a").type == out.schema.field("a").type
+    want = dict(zip(*by_group.to_pydict().values()))
+    assert want[4] is None
+    if value == "vf":       # a double's sum depends on the order of adding
+        want = {k: v if v is None else pytest.approx(v, rel=1e-12)
+                for k, v in want.items()}
+    last = {}
+    for gi, ti, a in zip(got["g"], got["t"], got["a"]):
+        last[gi] = a
+        if whole_partition:
+            assert a == want[gi], (gi, ti)
+    # the running frames reach the whole partition at its last row
+    assert last == want
