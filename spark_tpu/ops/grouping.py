@@ -4,9 +4,32 @@ Role of the reference's HashAggregateExec + UnsafeFixedWidthAggregationMap
 (sqlx/aggregate/HashAggregateExec.scala:50, corej/unsafe/map/BytesToBytesMap.java)
 and its sort-based fallback (TungstenAggregationIterator). TPU-native design:
 no hash table at all — `lax.sort` (bitonic/radix, MXU-adjacent, fully
-data-parallel) groups equal keys adjacently, then `segment_sum`-family ops
-reduce each run. Static shapes throughout: output has the same capacity as
-input (worst case all rows distinct) with a row mask for live groups.
+data-parallel) groups equal keys adjacently, then each run of equal keys is
+reduced. Static shapes throughout: output has the same capacity as input
+(worst case all rows distinct) with a row mask for live groups.
+
+What follows the sort has two bodies, and `group_aggregate` is the one door
+to both. The *scatter* body is the plain one: gather every column into
+sorted order by `perm`, `segment_sum` into `cap` segments, write each group's
+key to slot `seg_id` with `.at[].set`. Every step of it is a per-element
+gather or scatter of `cap` scalars, and a TPU moves scalars one at a time:
+at 8 Mi slots on a v5e one sum and its count were 2.28 s and one key with
+its validity 0.25 s, and the hints that are true there
+(`indices_are_sorted`, `unique_indices`) bought nothing. The *scan* body
+never addresses by a computed index (the same sum and count in 0.04 s).
+The values travel through the group sort as operands, so they come out in
+sorted order, as the keys do; a sum or a count is an inclusive `cumsum`,
+whose value before each group's first row is carried, with the group's
+keys, to slot `seg_id` by ONE single-key `lax.sort` on "the slot, for a
+first row"; a group's total is then the difference of two adjacent slots.
+For 64-bit integer accumulators that difference is exact modulo 2^64
+whatever the running sum did, which is all a scatter-add promises too. A
+floating sum must not be a difference of running sums (cancellation), so
+it keeps the scatter-add, as min/max/first/bit ops do. `segment_path` picks
+the body from the static capacity: each `lax.sort` costs the TPU compiler
+20-60 s and more with every operand, so small capacities keep the scatter
+body, and the scan body's sorts are kept narrow (validity planes as the
+bits of one word, no key sent twice).
 """
 
 from __future__ import annotations
@@ -28,12 +51,20 @@ class GroupLayout(NamedTuple):
     num_groups: jnp.ndarray  # int32 scalar — number of live groups
 
 
-@jax.named_scope("group_sort")
 def group_rows(key_cols: Sequence[jnp.ndarray],
                key_valids: Sequence[jnp.ndarray | None],
                row_mask: jnp.ndarray) -> GroupLayout:
     """Sort rows so equal keys (SQL semantics: null == null, inactive rows
     last) are adjacent; derive segment structure."""
+    return _group_sort(key_cols, key_valids, row_mask, None)[0]
+
+
+@jax.named_scope("group_sort")
+def _group_sort(key_cols, key_valids, row_mask, carry):
+    """`group_rows`, with the arrays of `carry` sent through the sort as
+    operands: (layout, the keys as sorted [(data, validity | None)] with
+    zeros under a NULL, `carry` as sorted). With None for a `carry` this
+    is the scatter body's sort, which gathers what it needs by `perm`."""
     cap = row_mask.shape[0]
     inactive = (~row_mask).astype(jnp.int32)
     operands = [inactive]
@@ -45,10 +76,13 @@ def group_rows(key_cols: Sequence[jnp.ndarray],
             operands.append(c)
     num_keys = len(operands)
     operands.append(lax.iota(jnp.int32, cap))
-    sorted_ops = lax.sort(tuple(operands), num_keys=num_keys, is_stable=True)
-    perm = sorted_ops[-1]
+    sorted_ops = lax.sort((*operands, *(carry or ())), num_keys=num_keys,
+                          is_stable=True)
+    perm = sorted_ops[num_keys]
     skeys = sorted_ops[:num_keys]
-    active = jnp.take(row_mask, perm)
+    # the sorted flag is the mask in sorted order; the scatter body keeps
+    # its gather, so that its programs stay the ones that are compiled
+    active = jnp.take(row_mask, perm) if carry is None else skeys[0] == 0
 
     changed = jnp.zeros(cap, dtype=bool).at[0].set(True)
     for k in skeys:
@@ -58,7 +92,13 @@ def group_rows(key_cols: Sequence[jnp.ndarray],
     seg_ids = jnp.cumsum(start_flag.astype(jnp.int32)) - 1
     seg_ids = jnp.maximum(seg_ids, 0)
     num_groups = jnp.sum(start_flag.astype(jnp.int32))
-    return GroupLayout(perm, seg_ids, start_flag, active, num_groups)
+    keys, at = [], 1
+    for v in key_valids:
+        keys.append((skeys[at], None) if v is None
+                    else (skeys[at + 1], skeys[at] == 0))
+        at += 1 if v is None else 2
+    return (GroupLayout(perm, seg_ids, start_flag, active, num_groups),
+            keys, list(sorted_ops[num_keys + 1:]))
 
 
 @jax.named_scope("group_runs")
@@ -248,6 +288,182 @@ def apply_group_ops(layout: GroupLayout, ops: Sequence[str], val_datas,
         else:
             raise ValueError(op)
     return bufs
+
+
+# What each body costs on a TPU v5e, in seconds (PERF.md §6, PR 30; the
+# probe is `.probe/segment_probe.py`, git-ignored as `.probe/` is).
+# SCATTER_S: a slot through one scatter-add of the scatter body, or one
+# scatter by a permutation (77-80 ns at 1 Mi slots, 136-142 ns at 8 Mi; a
+# group key's gather and scatter are 29 ns). SCAN_S: a slot through the
+# scans and the sort that stand in their place (4.5 ns for a sum and its
+# count, 3 ns for a permutation undone). SORT_FIXED_S: what the scan body
+# must save a run to be worth a `lax.sort`, which costs the TPU compiler
+# 19-40 s and 15-35 s more for each operand beyond three; as
+# `ops/joining.MERGE_FIXED_S` is. The crossover is at 660 k slots: 1 Mi and
+# 8 Mi (the window reports' flows) scan, 128 Ki (q3's and q7's) does not.
+SCATTER_S = 80e-9
+SCAN_S = 5e-9
+SORT_FIXED_S = 0.05
+
+
+def segment_path(cap: int, acc_dtype=None) -> str:
+    """`"scan"` or `"scatter"`: the cheaper body for one step over a
+    sorted-segment layout of `cap` slots (a reduce, a group's keys, a
+    window's frame, a permutation undone), known at trace time. A
+    floating accumulator never takes the scan: its totals would be
+    differences of a running sum."""
+    if acc_dtype is not None and jnp.issubdtype(acc_dtype, jnp.floating):
+        return "scatter"
+    scatter = cap * SCATTER_S
+    scan = SORT_FIXED_S + cap * SCAN_S
+    return "scan" if scan < scatter else "scatter"
+
+
+def _scan_reduces(op: str, values) -> bool:
+    """Whether the scan body reduces `op` itself: counts, and sums into the
+    integer accumulator. The other ops keep `apply_group_ops`."""
+    return op in ("count", "countstar") or (
+        op == "sum" and not jnp.issubdtype(values.dtype, jnp.floating))
+
+
+def _place(items: list, x) -> int:
+    """`x`'s place in `items` (the very array, not an equal one), at the
+    end if it is not there yet."""
+    for at, have in enumerate(items):
+        if have is x:
+            return at
+    items.append(x)
+    return len(items) - 1
+
+
+class _Operands(list):
+    """Arrays bound for a sort's operands, each once. A `lax.sort` costs
+    the TPU compiler more than in proportion to its operands, so the
+    validity planes travel as the bits of one word."""
+
+    def __init__(self):
+        super().__init__()
+        self.planes: list = []
+
+    def place(self, x) -> int:
+        return _place(self, x)
+
+    def place_plane(self, x) -> int | None:
+        return None if x is None else _place(self.planes, x)
+
+    def words(self) -> list:
+        """The planes, 31 to an int32."""
+        out = []
+        for at in range(0, len(self.planes), 31):
+            word = jnp.int32(0)
+            for bit, plane in enumerate(self.planes[at:at + 31]):
+                word = word | (plane.astype(jnp.int32) << bit)
+            out.append(word)
+        return out
+
+    @staticmethod
+    def plane(words, at: int):
+        return (words[at // 31] >> (at % 31)) & 1 == 1
+
+
+def group_aggregate(key_eqs, key_valids, key_outs, row_mask,
+                    ops: Sequence[str], val_datas, val_valids,
+                    path: str | None = None):
+    """GROUP BY in one call: rows grouped by `key_eqs` (with `key_valids`,
+    null == null), each group's `key_outs` (None: the key it was grouped
+    by; with that key's validity, and anything under a NULL) and its
+    reduction of every (op, values, validity) triple in output slot
+    `seg_id`. Returns ([(key, validity | None)], [(buffer, validity |
+    None)], mask of live groups, number of groups). `path` forces a body,
+    for the tests; callers leave it to `segment_path`."""
+    cap = row_mask.shape[0]
+    if (path or segment_path(cap)) == "scatter":
+        layout = group_rows(key_eqs, key_valids, row_mask)
+        out_keys = [scatter_group_keys(layout, ke if ko is None else ko, kv)
+                    for ko, ke, kv in zip(key_outs, key_eqs, key_valids)]
+        bufs = apply_group_ops(layout, ops, val_datas, val_valids)
+        return out_keys, bufs, group_output_mask(layout), layout.num_groups
+
+    # what the scans read goes through the group sort, and an output key
+    # that is not its own sort key (the sort's output has those)
+    carry = _Operands()
+    scanned = [_scan_reduces(op, vd) for op, vd in zip(ops, val_datas)]
+    val_at = [(carry.place(vd) if op == "sum" else None,
+               carry.place_plane(vv) if op != "countstar" else None)
+              for op, vd, vv, scans in zip(ops, val_datas, val_valids,
+                                           scanned) if scans]
+    sent_key_at = [None if ko is None else carry.place(ko)
+                   for ko in key_outs]
+    layout, skeys, carried = _group_sort(key_eqs, key_valids, row_mask,
+                                         carry + carry.words())
+    planes = carried[len(carry):]
+    out_mask = group_output_mask(layout)
+
+    with jax.named_scope("segment_reduce"):
+        # running sums, each taken BEFORE its row: at a group's first row
+        # that is the sum over every group before it
+        before, grand = [], []
+        count_of: dict = {}    # a validity plane -> its count's place
+        sums = []              # per scanned op: (sum's place | None, count's)
+        for di, vi in val_at:
+            w = layout.active if vi is None \
+                else layout.active & carry.plane(planes, vi)
+            if vi not in count_of:
+                c = jnp.cumsum(w, dtype=jnp.int32)
+                count_of[vi] = len(before)
+                before.append(c - w)
+                grand.append(c[cap - 1])
+            if di is None:
+                sums.append((None, count_of[vi]))
+                continue
+            x = jnp.where(w, carried[di].astype(jnp.int64), jnp.int64(0))
+            c = jnp.cumsum(x)
+            sums.append((len(before), count_of[vi]))
+            before.append(c - x)
+            grand.append(c[cap - 1])
+        rest = [i for i, scans in enumerate(scanned) if not scans]
+        rest_bufs = apply_group_ops(
+            layout, [ops[i] for i in rest], [val_datas[i] for i in rest],
+            [val_valids[i] for i in rest])
+
+    # ONE sort brings every group's first row to slot seg_id (the other
+    # rows go behind them, in no order: a stable sort compiles for twice as
+    # long); the running sums and the group's keys ride along
+    with jax.named_scope("group_keys"):
+        keys = _Operands()
+        key_at = [(keys.place(sk if at is None else carried[at]),
+                   keys.place_plane(sv))
+                  for at, (sk, sv) in zip(sent_key_at, skeys)]
+        firsts = lax.sort(
+            (jnp.where(layout.start_flag, layout.seg_ids, cap), *before,
+             *keys, *keys.words()), num_keys=1, is_stable=False)[1:]
+        key_cols = firsts[len(before):]
+        null_words = key_cols[len(keys):]
+        out_keys = [
+            (jnp.where(out_mask, key_cols[di],
+                       jnp.zeros((), key_cols[di].dtype)),
+             None if vi is None else out_mask & keys.plane(null_words, vi))
+            for di, vi in key_at]
+
+    with jax.named_scope("segment_reduce"):
+        # a group's total: what came before the next group's first row (the
+        # grand total for the last group), less what came before its own
+        slot = lax.iota(jnp.int32, cap)
+        totals = []
+        for first, whole in zip(firsts, grand):
+            nxt = jnp.concatenate([first[1:], first[:1]])
+            nxt = jnp.where(slot + 1 == layout.num_groups, whole, nxt)
+            totals.append(jnp.where(out_mask, nxt - first,
+                                    jnp.zeros((), first.dtype)))
+        bufs, sums, rest_bufs = [], iter(sums), iter(rest_bufs)
+        for scans in scanned:
+            if not scans:
+                bufs.append(next(rest_bufs))
+                continue
+            si, ci = next(sums)
+            bufs.append((totals[ci].astype(jnp.int64), None) if si is None
+                        else (totals[si], totals[ci] > 0))
+    return out_keys, bufs, out_mask, layout.num_groups
 
 
 @jax.named_scope("dense_reduce")

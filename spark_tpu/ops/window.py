@@ -4,8 +4,23 @@ Role of the reference's WindowExec + window function frames
 (sqlx/window/WindowExec.scala, sqlcat/expressions/windowExpressions.scala).
 TPU-native design: one `lax.sort` by (partition keys, order keys) makes
 partitions and peer groups contiguous; every ranking/frame computation is
-then a cumsum/segment-op over the sorted layout, and results scatter back to
-the original row order. No per-row loops, no frame iterators.
+then a cumsum/segment-op over the sorted layout, and results go back to the
+original row order. No per-row loops, no frame iterators.
+
+Two steps have two bodies, picked by `ops/grouping.segment_path` from the
+static capacity as the aggregate's are (a TPU scatters and gathers scalars
+one at a time; scans and sorts run at memory speed; a `lax.sort` costs the
+TPU compiler 20-60 s, so small capacities keep the plain bodies):
+
+  a partition's count/sum/avg (`w_agg_unbounded`) — `segment_sum` into
+    `cap` segments and a gather back by `seg_id`, or an inclusive `cumsum`
+    whose value at the partition's last row, less its value before the
+    first, is carried to every row of the partition by `cummax` scans
+    (`_carried`). Exact for the 64-bit integer accumulators, modulo 2^64
+    as the scatter-add is; a floating sum would be a difference of running
+    sums, so it keeps the scatter-add;
+  the way back (`scatter_back`) — `zeros.at[perm].set`, or a sort on
+    `perm`: a permutation's inverse is a sort on it.
 
 Default frames (Spark semantics):
   ranking fns — whole partition by definition;
@@ -22,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .grouping import segment_path
 from .sorting import SortKeySpec, _directional
 
 
@@ -35,6 +51,7 @@ class WindowLayout(NamedTuple):
     peer_first: jnp.ndarray  # position of first row of the peer group
     peer_last: jnp.ndarray   # position of last row of the peer group
     seg_size: jnp.ndarray    # int32 rows in the partition
+    seg_first: jnp.ndarray   # bool per sorted row: first row of its partition
 
 
 # Scopes, by the pattern of ops/joining.py: the one sort, the frame
@@ -105,7 +122,7 @@ def build_layout(part_keys: Sequence[jnp.ndarray],
                                    num_segments=cap)
     seg_size = jnp.take(seg_size, seg_id)
     return WindowLayout(perm, active, pos, seg_start, seg_id, peer_id,
-                        peer_first, peer_last, seg_size)
+                        peer_first, peer_last, seg_size, pchange)
 
 
 # --- per-function computations (all return values in SORTED order) ---------
@@ -164,12 +181,34 @@ def _sorted_vals(lo: WindowLayout, values, valid):
     return v, w
 
 
-def w_agg_unbounded(lo: WindowLayout, values, valid, kind: str, avg=None):
-    """sum/count/min/max/avg over the whole partition, broadcast to rows."""
+def unbounded_path(kind: str, value_dtype, cap: int) -> str | None:
+    """The body `w_agg_unbounded` takes for the frame kind
+    `agg_unbounded_<fn>` over values of `value_dtype`: `"scan"` or
+    `"scatter"`; None for a frame that has no such choice."""
+    if kind not in ("agg_unbounded_count", "agg_unbounded_sum",
+                    "agg_unbounded_avg"):
+        return None
+    return segment_path(cap, _acc_dtype(value_dtype))
+
+
+def _acc_dtype(dtype):
+    return jnp.float64 if jnp.issubdtype(dtype, jnp.floating) else jnp.int64
+
+
+def w_agg_unbounded(lo: WindowLayout, values, valid, kind: str, avg=None,
+                    path: str | None = None):
+    """sum/count/min/max/avg over the whole partition, broadcast to rows.
+    `path` forces a body of count/sum/avg, for the tests; callers leave it
+    to `segment_path`."""
     cap = values.shape[0]
     v, w = _sorted_vals(lo, values, valid)
-    acc = jnp.float64 if jnp.issubdtype(v.dtype, jnp.floating) else jnp.int64
+    acc = _acc_dtype(v.dtype)
     if kind in ("count", "sum", "avg"):
+        if (path or segment_path(cap, acc)) == "scan":
+            c = _partition_total(lo, w.astype(jnp.int32)).astype(jnp.int64)
+            s = c if kind == "count" else _partition_total(
+                lo, jnp.where(w, v.astype(acc), 0))
+            return _agg_result(kind, s, c, avg)     # per row already
         c = jax.ops.segment_sum(w.astype(jnp.int64), lo.seg_id, cap)
         s = c if kind == "count" else jax.ops.segment_sum(
             jnp.where(w, v.astype(acc), 0), lo.seg_id, cap)
@@ -188,11 +227,37 @@ def w_agg_unbounded(lo: WindowLayout, values, valid, kind: str, avg=None):
     return jnp.take(m, lo.seg_id), jnp.take(c, lo.seg_id) > 0
 
 
+def _partition_total(lo: WindowLayout, x):
+    """Each row's partition's sum of the integers `x`, by scans alone: the
+    running sum at the partition's last row, less the running sum before its
+    first, each carried to the partition's other rows. Exact modulo 2^64."""
+    run = jnp.cumsum(x)
+    last = jnp.concatenate([lo.seg_first[1:], jnp.ones(1, dtype=bool)])
+    return _carried(last, run, reverse=True) - _carried(lo.seg_first, run - x)
+
+
+def _carried(flag, x, reverse: bool = False):
+    """`x` at the nearest flagged slot at or before each slot (at or after
+    it, `reverse`d); slot 0 (the last slot) is flagged. A cummax carries a
+    value along only if the values never fall, so each half of `x` rides
+    below its slot's number, which never does."""
+    cap = x.shape[0]
+    rank = lax.iota(jnp.int64, cap)
+    if reverse:
+        rank = cap - 1 - rank
+    wide = x.astype(jnp.int64)
+    halves = []
+    for half in (wide & 0xFFFFFFFF, (wide >> 32) & 0xFFFFFFFF):
+        key = jnp.where(flag, (rank << 32) | half, -1)
+        halves.append(lax.cummax(key, axis=0, reverse=reverse) & 0xFFFFFFFF)
+    return (halves[0] | (halves[1] << 32)).astype(x.dtype)
+
+
 def w_agg_running(lo: WindowLayout, values, valid, kind: str, avg=None):
     """RANGE UNBOUNDED PRECEDING..CURRENT ROW (peers share the value)."""
     cap = values.shape[0]
     v, w = _sorted_vals(lo, values, valid)
-    acc = jnp.float64 if jnp.issubdtype(v.dtype, jnp.floating) else jnp.int64
+    acc = _acc_dtype(v.dtype)
     vv = jnp.where(w, v.astype(acc), 0)
     csum = jnp.cumsum(vv)
     ccnt = jnp.cumsum(w.astype(jnp.int64))
@@ -234,7 +299,7 @@ def w_agg_rows(lo: WindowLayout, values, valid, kind: str,
 
     cap = values.shape[0]
     v, w = _sorted_vals(lo, values, valid)
-    acc = jnp.float64 if jnp.issubdtype(v.dtype, jnp.floating) else jnp.int64
+    acc = _acc_dtype(v.dtype)
     vv = jnp.where(w, v.astype(acc), 0)
     csum = jnp.cumsum(vv)
     ccnt = jnp.cumsum(w.astype(jnp.int64))
@@ -284,7 +349,7 @@ def w_agg_value_range(lo: WindowLayout, order_key, values, valid, kind: str,
     empty = hi_idx < lo_idx
 
     v, w = _sorted_vals(lo, values, valid)
-    acc = jnp.float64 if jnp.issubdtype(v.dtype, jnp.floating) else jnp.int64
+    acc = _acc_dtype(v.dtype)
     csum = jnp.cumsum(jnp.where(w, v.astype(acc), 0))
     ccnt = jnp.cumsum(w.astype(jnp.int64))
 
@@ -404,9 +469,17 @@ def w_nth_value(lo: WindowLayout, values, valid, n: int,
 
 
 @jax.named_scope("scatter_back")
-def scatter_back(lo: WindowLayout, sorted_vals, sorted_valid=None):
-    """Sorted-order results → original row order."""
+def scatter_back(lo: WindowLayout, sorted_vals, sorted_valid=None,
+                 path: str | None = None):
+    """Sorted-order results → original row order: a scatter by `perm`, or
+    a sort on it (a permutation's inverse is a sort on it). `path` forces
+    a body, for the tests; callers leave it to `segment_path`."""
     cap = sorted_vals.shape[0]
+    if (path or segment_path(cap)) == "scan":
+        cols = (sorted_vals,) if sorted_valid is None \
+            else (sorted_vals, sorted_valid)
+        back = lax.sort((lo.perm, *cols), num_keys=1, is_stable=False)[1:]
+        return back[0], None if sorted_valid is None else back[1]
     out = jnp.zeros(cap, dtype=sorted_vals.dtype).at[lo.perm].set(sorted_vals)
     ov = None
     if sorted_valid is not None:

@@ -394,20 +394,21 @@ class FusedAggregateExec(HashAggregateExec):
                         kd = kd.astype(jnp.int32)
                     key_eqs.append(kd)
                 key_valids = [out_valids[i] for i in key_idx]
-                layout = G.group_rows(key_eqs, key_valids, mask)
-                out_keys = [
-                    G.scatter_group_keys(layout, out_datas[i], out_valids[i])
-                    for i in key_idx]
                 vd, vv = pipe_vals(out_datas, out_valids, mask, rluts)
-                bufs = G.apply_group_ops(layout, ops, vd, vv)
-                bufs = rank_to_code(bufs, iluts)
-                out_mask = G.group_output_mask(layout)
-                return out_keys, bufs, out_mask
+                # a key is its own output, but for a boolean's type
+                out_keys, bufs, out_mask, _ng = G.group_aggregate(
+                    key_eqs, key_valids,
+                    [out_datas[i] if is_bool else None
+                     for i, is_bool in zip(key_idx, key_bool)],
+                    mask, ops, vd, vv)
+                return out_keys, rank_to_code(bufs, iluts), out_mask
 
             return jax.jit(kernel)
 
+        from ..ops.grouping import segment_path
+
         kernel = GLOBAL_KERNEL_CACHE.get_or_build(
-            ("fused_agg", "g") + base_key, build_grouped)
+            ("fused_agg", "g", segment_path(cap)) + base_key, build_grouped)
         with batch_cost_scope(batch):
             out_keys, bufs, out_mask = kernel(datas, valids,
                                               batch.row_mask, aux,

@@ -495,13 +495,8 @@ def _group_kernel(num_keys: int, ops: tuple[str, ...], cap: int,
     from ..ops import grouping as G
 
     def kernel(key_eqs, key_outs, key_valids, val_datas, val_valids, row_mask):
-        layout = G.group_rows(key_eqs, key_valids, row_mask)
-        out_keys = []
-        for ko, kv in zip(key_outs, key_valids):
-            out_keys.append(G.scatter_group_keys(layout, ko, kv))
-        bufs = G.apply_group_ops(layout, ops, val_datas, val_valids)
-        out_mask = G.group_output_mask(layout)
-        return out_keys, bufs, out_mask, layout.num_groups
+        return G.group_aggregate(key_eqs, key_valids, key_outs, row_mask,
+                                 ops, val_datas, val_valids)
 
     return jax.jit(kernel)
 
@@ -748,7 +743,9 @@ class HashAggregateExec(PhysicalPlan):
 
         key_cols = [batch.columns[pos[g.expr_id]] for g in self.grouping]
         key_eqs = [c.eq_keys() for c in key_cols]
-        key_outs = [c.data for c in key_cols]
+        # None where the key is its own output (all but strings, booleans)
+        key_outs = [None if c.data is e else c.data
+                    for c, e in zip(key_cols, key_eqs)]
         key_valids = [c.validity for c in key_cols]
 
         if not percentiles and not collects:
@@ -763,7 +760,9 @@ class HashAggregateExec(PhysicalPlan):
             if rle is not None:
                 return rle
 
-        kkey = ("gagg", len(key_cols), ops, cap,
+        from ..ops.grouping import segment_path
+
+        kkey = ("gagg", len(key_cols), ops, cap, segment_path(cap),
                 tuple(v is not None for v in key_valids),
                 tuple(v is not None for v in val_valids),
                 tuple(str(d.dtype) for d in key_eqs),

@@ -639,6 +639,25 @@ def _pack_keys(packs: tuple, keys: list, valids: list) -> tuple:
     return out_k, out_v
 
 
+def _unpack_keys(packs: tuple, packed: list, keys: list,
+                 valids: list) -> list:
+    """`_pack_keys` undone on an aggregate's output: [(key, validity |
+    None)] per original key from the [(key, validity | None)] of the
+    packed ones; `keys` and `valids` are the original ones, for their
+    types and for which of them has a validity (traced)."""
+    jnp = _jnp()
+    out = [None] * len(keys)
+    for pack, (acc, _none) in zip(packs, packed):
+        shift = 0
+        for j, bits in pack:
+            code = (acc >> shift) & ((1 << bits) - 1)
+            out[j] = ((jnp.maximum(code, 1) - 1).astype(keys[j].dtype),
+                      None if valids[j] is None else code != 0)
+            shift += bits
+    rest = iter(packed[len(packs):])
+    return [next(rest) if o is None else o for o in out]
+
+
 class _MCol(NamedTuple):
     """Host-side column metadata threaded through the shadow pass: the
     same (dtype, validity presence, dictionary) triple pipeline_host_pass
@@ -1012,6 +1031,16 @@ class _ProgramBuilder:
                          key_bool, tuple((bi, n) for bi, (_r, _i, n)
                                          in sorted(smm.items())))
                         + ((packs,) if packs else ()))
+        if node.grouping:
+            # which body `ops/grouping.group_aggregate` takes at this
+            # capacity: the same rule the trace asks, counted, shown in the
+            # row, and in the key where it is not the plain one
+            from ..ops.grouping import segment_path
+            path = segment_path(low.cap)
+            self.ctx.metrics.add(f"agg.segment_{path}")
+            self._note(node, f"segments[{path}]")
+            if path != "scatter":
+                self.key.append(("segments", path))
 
         def pipe_vals(d, v, m):
             vd, vv = [], []
@@ -1091,13 +1120,18 @@ class _ProgramBuilder:
                     kd = kd.astype(jnp.int32)
                 key_eqs.append(kd)
             key_valids = [v[i] for i in key_idx]
-            layout = G.group_rows(*_pack_keys(packs, key_eqs, key_valids), m)
-            out_keys = [G.scatter_group_keys(layout, d[i], v[i])
-                        for i in key_idx]
             vd, vv = pipe_vals(d, v, m)
-            bufs = G.apply_group_ops(layout, ops, vd, vv)
+            # the keys as the sort has them are the output's too: packed
+            # ones are taken apart again, a boolean gets its type back
+            sort_keys, sort_valids = _pack_keys(packs, key_eqs, key_valids)
+            out_keys, bufs, out_mask, _ng = G.group_aggregate(
+                sort_keys, sort_valids, [None] * len(sort_keys), m, ops,
+                vd, vv)
+            out_keys = [(kd.astype(bool) if is_bool else kd, kv)
+                        for (kd, kv), is_bool in zip(
+                            _unpack_keys(packs, out_keys, key_eqs,
+                                         key_valids), key_bool)]
             bufs = finish(rank_back(bufs))
-            out_mask = G.group_output_mask(layout)
             datas = [kd for kd, _kv in out_keys] + [bd for bd, _ in bufs]
             valids = [kv for _kd, kv in out_keys] + [bv for _, bv in bufs]
             return datas, valids, out_mask
@@ -1143,6 +1177,21 @@ class _ProgramBuilder:
                   None)
             for al, (kind, _p, _a) in zip(node.window_exprs, plans)]
         cap = low.cap
+        # which bodies `ops/window` takes at this capacity (the rule the
+        # trace asks): counted per expression, shown in the row, and in
+        # the key where they are not the plain ones
+        from ..ops.grouping import segment_path
+        from ..ops.window import unbounded_path
+        back = "sort" if segment_path(cap) == "scan" else "scatter"
+        self.ctx.metrics.add(f"window.unpermute_{back}", len(plans))
+        frames = sorted({unbounded_path(
+            kind, jnp.int32 if i < 0 else low.metas[i].dtype.device_dtype,
+            cap) for (kind, _p, _a), i in zip(plans, vi)
+            if i is not None} - {None})
+        note = "".join(f"frame={f}," for f in frames) + f"unpermute={back}"
+        self._note(node, f"segments[{note}]")
+        if "scan" in frames or back == "sort":
+            self.key.append(("segments", note))
 
         def emit(args, needed, _low=low):
             d, v, m = _low.emit(args, needed)
@@ -1398,7 +1447,10 @@ class _ProgramBuilder:
         expand_path = rank_path(pcap, out_cap)
         self.ctx.metrics.add(f"join.rank_{probe_path}", 2)
         self.ctx.metrics.add(f"join.rank_{expand_path}")
-        note = f"rank[probe={probe_path},expand={expand_path}]"
+        self._note(node, f"rank[probe={probe_path},expand={expand_path}]")
+
+    def _note(self, node, note: str) -> None:
+        """`note` at the end of the node's members row (100 characters)."""
         row = self._member_of[id(node)]
         self.members[row] = f"{self.members[row][:99 - len(note)]} {note}"
 

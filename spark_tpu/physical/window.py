@@ -296,7 +296,9 @@ class WindowExec(PhysicalPlan):
                 raise UnsupportedOperationError(
                     "RANGE frame key span too large to band")
 
-        key = ("window", cap, kmin, band,
+        from ..ops.grouping import segment_path
+
+        key = ("window", cap, segment_path(cap), kmin, band,
                tuple((str(c.eq_keys().dtype), c.validity is not None)
                      for c in pcols),
                tuple((str(c.sort_keys().dtype), c.validity is not None,

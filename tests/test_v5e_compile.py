@@ -1,0 +1,84 @@
+"""The kernels of the main path compiled for a described TPU v5e, at the
+size the benchmark runs them: what the chip's compiler would refuse, or
+would take minutes over, shows here and costs no chip time. One file, the
+topology inside a fixture (only the worker that is given this file loads
+the TPU's library), skipped where no v5e can be described."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from spark_tpu.ops import grouping as G
+from spark_tpu.ops import window as W
+
+CAP = 8 << 20      # the slots of q47's `v1` flow (tpcds_sf10_window.dev2)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled(fn, one_chip, *dtypes):
+    shapes = [jax.ShapeDtypeStruct((CAP,), dt, sharding=one_chip)
+              for dt in dtypes]
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _sorts(text):
+    """Operands (32-bit words: the compiler splits a 64-bit one in two) of
+    each sort in a compiled module."""
+    return sorted(len(re.findall(r"\b(?:[suf]\d+|pred)\[", ln.split(" sort(")[0]))
+                  for ln in text.splitlines() if " sort(" in ln)
+
+
+def _per_slot(text, op):
+    """`op`s (gather, scatter) whose result has CAP slots."""
+    return [ln for ln in text.splitlines()
+            if re.search(rf"\[{CAP}\]\S* {op}\(", ln)]
+
+
+def test_aggregate_by_scans_compiles_at_8mi(one_chip):
+    """A GROUP BY's scan body at v1's capacity, at its narrowest (one key,
+    one decimal sum: v1's own, with six keys, compiles for ten minutes
+    here): two sorts and no gather or scatter of CAP elements. A sort costs
+    the TPU compiler 15-35 s for each operand, so their number is pinned:
+    slot, count, sum (2), key; and flag, key, iota, price (2), its
+    validity's word."""
+    def body(key, price, price_ok, mask):
+        return G.group_aggregate([key], [None], [None], mask, ("sum",),
+                                 [price], [price_ok], path="scan")
+
+    text = _compiled(body, one_chip, jnp.int32, jnp.int64, jnp.bool_,
+                     jnp.bool_)
+    assert _sorts(text) == [5, 6], _sorts(text)
+    assert not _per_slot(text, "gather") and not _per_slot(text, "scatter")
+
+
+def test_window_by_scans_compiles_at_8mi(one_chip):
+    """A window's partition total by scans and the way back by a sort on
+    the permutation, at v1's capacity: no scatter of CAP elements, and the
+    two sorts' operands pinned (perm, total (2), its validity; flags, key,
+    iota)."""
+    def body(key, total, total_ok, mask):
+        lo = W.build_layout([key], [None], [], [], [], mask)
+        out = W.w_agg_unbounded(lo, total, total_ok, "sum", path="scan")
+        return W.scatter_back(lo, *out, path="scan")
+
+    text = _compiled(body, one_chip, jnp.int32, jnp.int64, jnp.bool_,
+                     jnp.bool_)
+    assert _sorts(text) == [3, 4], _sorts(text)
+    assert not _per_slot(text, "scatter")

@@ -873,6 +873,68 @@ def test_tpcds_window_reports_whole_tier_match_the_oracle(wtpcds, qname):
     assert ok, msg
 
 
+@pytest.mark.parametrize("qname", ["q89", "q47"])
+def test_tpcds_window_reports_same_on_both_segment_paths(wtpcds, qname,
+                                                         monkeypatch):
+    """The aggregate and the windows of the monthly-deviation reports by
+    scatters (what `ops/grouping.segment_path` picks at a few thousand
+    slots) and by scans and sorts (what it picks at the benchmark's 1 Mi
+    and 8 Mi, here with a sort's fixed cost taken away): equal tables,
+    the counters and the members rows say which body each member traced,
+    and the scopes keep their names."""
+    import os
+
+    from spark_tpu.ops import grouping as G
+    from spark_tpu.physical.compile import capture_programs
+    from test_tpcds_full import QUERY_DIR
+    from tests.tpcds.oracle import strip_trailing_limit
+
+    sql = strip_trailing_limit(
+        open(os.path.join(QUERY_DIR, f"{qname}.sql")).read())
+    names = ("agg.segment_scan", "agg.segment_scatter",
+             "window.unpermute_sort", "window.unpermute_scatter")
+
+    def run():
+        c = wtpcds._metrics.snapshot()["counters"]
+        before = {k: c.get(k, 0) for k in names}
+        with capture_programs() as programs:
+            table = wtpcds.sql(sql).toArrow()
+        c = wtpcds._metrics.snapshot()["counters"]
+        rec = next(r for r in programs
+                   if any(s and s.endswith(".Window") for s in r["scopes"]))
+        rows = {s.split(".")[1]: m for s, m in zip(rec["scopes"],
+                                                   rec["members"])
+                if s and s.endswith((".Window", ".HashAggregate"))}
+        return table, {k: c.get(k, 0) - before[k] for k in names}, rec, rows
+
+    plain, delta, _rec, rows = run()
+    assert delta["agg.segment_scan"] == 0 == delta["window.unpermute_sort"]
+    assert delta["agg.segment_scatter"] and delta["window.unpermute_scatter"]
+    assert rows["HashAggregate"].endswith(" segments[scatter]"), rows
+    assert rows["Window"].endswith("unpermute=scatter]"), rows
+    monkeypatch.setattr(G, "SORT_FIXED_S", 0.0)
+    forced, delta, rec, rows = run()
+    assert delta["agg.segment_scatter"] == 0 \
+        == delta["window.unpermute_scatter"]
+    assert delta["agg.segment_scan"] and delta["window.unpermute_sort"]
+    assert forced.num_rows and forced.equals(plain)
+    assert rows["HashAggregate"].endswith(" segments[scan]"), rows
+    assert rows["Window"].endswith(("segments[frame=scan,unpermute=sort]",
+                                    "segments[unpermute=sort]")), rows
+    text = rec["kernel"]._kernel.lower(*rec["args"]).as_text(debug_info=True)
+    for scope, phases in (("HashAggregate", ("group_sort", "group_keys",
+                                            "segment_reduce")),
+                          ("Window", ("layout_sort", "frame",
+                                      "scatter_back"))):
+        member = next(s for s in rec["scopes"] if s and s.endswith(scope))
+        for phase in phases:
+            assert f"{member}/{phase}" in text, (member, phase)
+    if qname == "q89":
+        shown = wtpcds.sql(sql).query_execution.explain_string("device")
+        assert "segments[scan]" in shown \
+            and "segments[frame=scan,unpermute=sort]" in shown, shown
+
+
 @pytest.mark.parametrize("sizes,want", [
     ((10, 20, 850, 10, 1), (((0, 4), (1, 5), (2, 10), (3, 4), (4, 1)),)),
     ((850,), ()),                           # a lone string key gains nothing

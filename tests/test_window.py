@@ -290,3 +290,68 @@ def test_window_avg_is_the_aggregates_avg(spark, frame, whole_partition,
             assert a == want[gi], (gi, ti)
     # the running frames reach the whole partition at its last row
     assert last == want
+
+
+@pytest.mark.parametrize("tier", ["stage", "operator"])
+def test_window_over_aggregate_same_on_both_segment_paths(spark, tier,
+                                                          monkeypatch):
+    """A report of q89's shape (windows over a GROUP BY on a string and
+    an integer key, decimal, integral and float sums, a group and a
+    partition with no value) below the whole tier: the per-partition
+    window kernel and the aggregate kernels trace `ops/`'s bodies by the
+    same rule, and with a sort's fixed cost taken away they take the scans
+    and the sorts, same table."""
+    from decimal import Decimal
+
+    import numpy as np
+
+    from spark_tpu.ops import grouping as G
+    from spark_tpu.physical.compile import GLOBAL_KERNEL_CACHE as KC
+
+    rng = np.random.default_rng(30)
+    n = 900
+    store = rng.integers(0, 5, n)
+    month = rng.integers(1, 13, n)
+    cents = rng.integers(-99999, 9999999, n)
+    dead = (store == 3) | ((store == 1) & (month == 2)) \
+        | (rng.random(n) < 0.1)
+    spark.createDataFrame(pa.table({
+        "store": pa.array([None if s == 4 and m < 3 else f"s{s}"
+                           for s, m in zip(store, month)]),
+        "moy": month,
+        "price": pa.array([None if d else Decimal(int(c)).scaleb(-2)
+                           for c, d in zip(cents, dead)],
+                          pa.decimal128(7, 2)),
+        "qty": pa.array([None if d else int(c) % 100
+                         for c, d in zip(cents, dead)], pa.int64()),
+        "w": pa.array([None if d else c / 7.0 for c, d in zip(cents, dead)],
+                      pa.float64()),
+    })).createOrReplaceTempView("seg_sales")
+    q = ("select store, moy, sp, sq, sw, n, "
+         "avg(sp) over (partition by store) ap, "
+         "sum(sq) over (partition by store) tq, "
+         "count(sw) over (partition by store) cw, "
+         "sum(sw) over (partition by store) tw, "
+         "rank() over (partition by store order by sp desc, moy) r "
+         "from (select store, moy, sum(price) sp, sum(qty) sq, sum(w) sw, "
+         "count(*) n from seg_sales group by store, moy) t "
+         "order by store, moy")
+    old = spark.conf.get("spark.tpu.compile.tier")
+    spark.conf.set("spark.tpu.compile.tier", tier)
+    try:
+        assert G.segment_path(1 << 12) == "scatter"
+        plain = spark.sql(q).toArrow()
+        monkeypatch.setattr(G, "SORT_FIXED_S", 0.0)
+        assert G.segment_path(1 << 12) == "scan"
+        built = KC.misses
+        forced = spark.sql(q).toArrow()
+        # the aggregate's and the window's kernels are other ones (the
+        # operator tier finds those that the stage tier's run built)
+        assert KC.misses >= built + (2 if tier == "stage" else 0)
+    finally:
+        spark.conf.set("spark.tpu.compile.tier", old)
+    assert plain.num_rows == 60 and forced.equals(plain)
+    got = plain.to_pydict()
+    assert got["sp"][got["store"].index("s3")] is None
+    assert set(a for s, a in zip(got["store"], got["ap"]) if s == "s3") \
+        == {None}
