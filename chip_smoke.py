@@ -3,8 +3,8 @@
 
 One process drives the engine's main path once, through the doors a user
 calls, at the full width of one plan family the repo supports: TPC-DS
-q3, q7 and q19 over the 2 880 000-row `store_sales` that bench.py calls
-SF1-equivalent — `TpuSession` → `session.sql(text)` → analyzer →
+q3, q7 and q19 over a 2 880 000-row `store_sales`, the row count of
+real SF1 — `TpuSession` → `session.sql(text)` → analyzer →
 optimizer → planner → `choose_tier` → `KernelCache` → `.toArrow()`, at
 default conf. Then the same three queries through `SQLEndpoint` (what
 `bin/sparktpu-sqlserver` starts) from the jax-free DB-API client, and,
@@ -36,7 +36,7 @@ from decimal import Decimal
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 # tests/tpcds/datagen.py scale 1.0 is 30 000 store_sales rows; 96 gives
-# the 2 880 000 of real SF1 (bench.py:TPCDS_GEN_SCALE)
+# the 2 880 000 of real SF1
 FULL_SCALE = 96.0
 CPU_SCALE = 4.0          # --cpu: big enough that every query returns rows
 SEED = 17

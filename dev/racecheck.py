@@ -5,7 +5,7 @@ Whole-repo AST pass over spark_tpu/ (no jax import, no device work; safe
 inside the tier-1 budget). Rules: shared-mutation, lock-order,
 bare-submit, worker-reinit — see spark_tpu/analysis/race_lint.py. The
 runtime half is utils/lockwatch.py, cross-checked by
-`dev/validate_trace.py --race`.
+tests/test_race_lint.py.
 
 Usage:
   python dev/racecheck.py [paths...] [--baseline dev/race_baseline.json]

@@ -10,13 +10,13 @@ Three cooperating passes:
   * analysis.plan_lint — plan/trace-level analyzer over an optimized
     physical plan: predicts kernel launches per batch per stage, explains
     why stage boundaries did or did not fuse, and flags recompile and
-    dtype-overflow hazards (surfaced via df.explain("analysis"),
-    QueryExecution.analysis_report(), and bench.py --analyze).
+    dtype-overflow hazards (surfaced via df.explain("analysis") and
+    QueryExecution.analysis_report()).
   * analysis.race_lint — whole-repo concurrency model: shared-mutation
     races, lock-order cycles, contextvar-losing thread spawns, and
     worker re-init gaps (CLI: dev/racecheck.py, baseline:
-    dev/race_baseline.json; runtime cross-check: utils/lockwatch.py +
-    dev/validate_trace.py --race).
+    dev/race_baseline.json; runtime cross-check: utils/lockwatch.py,
+    driven by tests/test_race_lint.py).
 """
 
 from .lint import (  # noqa: F401

@@ -56,7 +56,7 @@ per-(file,rule)-count baseline ``dev/race_baseline.json`` so existing
 debt doesn't block CI while NEW violations do.
 
 The model is also the contract the runtime half validates
-(utils/lockwatch.py + ``dev/validate_trace.py --race``): exported
+(utils/lockwatch.py, driven by tests/test_race_lint.py): exported
 ``lock_edges`` are unioned with OBSERVED acquisition orders (no cycle
 may appear), and every ``# guarded-by:`` annotation must be held where
 claimed at instrumented mutation sites.

@@ -140,8 +140,8 @@ class TpuSession:
         from ..utils import lockwatch as _lockwatch
 
         # runtime lock-discipline watching (spark.tpu.lockwatch.enabled)
-        # — off by default: raw unwrapped locks, zero overhead; the
-        # --race gate enables it per session / via SPARK_TPU_LOCKWATCH=1
+        # — off by default: raw unwrapped locks, zero overhead; on per
+        # session by the option, or by SPARK_TPU_LOCKWATCH=1
         _lockwatch.configure(self.conf)
         from ..exec import persist_cache as _persist
 

@@ -48,10 +48,6 @@ BATCH_CAPACITY = _register(ConfigEntry(
     "Static row capacity of a ColumnarBatch tile. All kernels are compiled "
     "for power-of-two capacity buckets to bound XLA recompilation.", int))
 
-MAX_BATCH_BUCKETS = _register(ConfigEntry(
-    "spark.tpu.batch.maxCapacity", 1 << 24,
-    "Upper bound for capacity-bucket growth on CapacityOverflowError.", int))
-
 AUTO_BROADCAST_THRESHOLD = _register(ConfigEntry(
     "spark.sql.autoBroadcastJoinThreshold", 10 * 1024 * 1024,
     "Max estimated build-side bytes for broadcast hash join "
@@ -284,12 +280,6 @@ ENCODING_ENABLED = _register(ConfigEntry(
     "of decoded values. Off = the decode-at-boundary oracle for "
     "differential testing.", _bool))
 
-CODEGEN_CACHE_SIZE = _register(ConfigEntry(
-    "spark.tpu.kernel.cacheSize", 1024,
-    "Max entries in the jitted-kernel cache (role of the reference's "
-    "CodeGenerator Janino class cache, codegen/CodeGenerator.scala:1557).",
-    int))
-
 # --- entries below were historically read by string literal at their use
 # sites; registered here so config has a single typed source of truth
 # (found and enforced by dev/tpulint.py's config-key rule) -----------------
@@ -358,8 +348,7 @@ TRACE_ENABLED = _register(ConfigEntry(
     "Always-on span tracing of the query lifecycle (parse/analyze/"
     "optimize/plan/stage/partition/exchange/collect; obs/tracing.py). "
     "Pure host bookkeeping — zero kernel launches, zero device syncs; "
-    "export with session.tracer.write_chrome_trace() or bench.py "
-    "--trace.", _bool))
+    "export with session.tracer.write_chrome_trace().", _bool))
 
 TRACE_MAX_SPANS = _register(ConfigEntry(
     "spark.tpu.trace.maxSpans", 100_000,
@@ -469,8 +458,7 @@ KERNEL_COST = _register(ConfigEntry(
     "accessed) at first invocation via the lowering — no second backend "
     "compile — with an argument/output-metadata fallback; launches then "
     "attribute flops/bytes to the executing operator for EXPLAIN "
-    "ANALYZE's achieved-GB/s roofline view and bench.py's measured "
-    "hbm_gbps.", _bool))
+    "ANALYZE's achieved-GB/s roofline view.", _bool))
 
 MEMORY_PEAK_GBPS = _register(ConfigEntry(
     "spark.tpu.memory.peakGbps", 0.0,
@@ -600,7 +588,7 @@ LOCKWATCH_ENABLED = _register(ConfigEntry(
     "Runtime lock-discipline validation (utils/lockwatch.py): swap "
     "registered process-global locks for watching proxies that record "
     "acquisition orders and held-lock sets at instrumented mutation "
-    "sites; dev/validate_trace.py --race cross-checks the records "
+    "sites; tests/test_race_lint.py cross-checks the records "
     "against the static race_lint model. Off (default) runs raw "
     "unwrapped locks — zero overhead. SPARK_TPU_LOCKWATCH=1 enables at "
     "import time and ships to cluster workers via their environment.",
@@ -885,10 +873,6 @@ class SQLConf:
     @property
     def case_sensitive(self) -> bool:
         return bool(self.get(CASE_SENSITIVE))
-
-    @property
-    def ansi_enabled(self) -> bool:
-        return bool(self.get(ANSI_ENABLED))
 
 
 def registry() -> dict[str, ConfigEntry]:

@@ -781,10 +781,10 @@ class LocalCluster:
 
     def lockwatch_edges(self) -> dict:
         """Collect each worker's lockwatch observations (order edges,
-        registered slot names, guard violations) over RPC so the --race
-        gate can fold executor-process lock behaviour into the same
-        cross-check it runs on the driver. Unreachable workers are
-        skipped — the gate asserts on who DID answer."""
+        registered slot names, guard violations) over RPC so
+        tests/test_race_lint.py can fold executor-process lock behaviour
+        into the same cross-check it runs on the driver. Unreachable
+        workers are skipped — the caller asserts on who DID answer."""
         with self._lock:
             workers = list(self._workers.items())
         out: dict = {}

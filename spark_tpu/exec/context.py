@@ -38,7 +38,7 @@ class ScopedMetrics(Metrics):
     """Per-query view over the session Metrics.
 
     Every add() lands on BOTH the session-global counters (unchanged
-    behavior: listeners, bench and the gates keep reading cumulative
+    behavior: listeners and tests keep reading cumulative
     session totals) and a query-local copy, so close-time consumers
     (query profiles, EXPLAIN ANALYZE counter deltas) read scope-exact
     per-query deltas instead of process-snapshot differences that

@@ -241,8 +241,8 @@ class QueryExecution:
             else getattr(self, "_preflight_report", None),
             cluster=getattr(self.session, "_sql_cluster", None) is not None)
         # execution always runs under a query scope: collects push one in
-        # to_arrow, but direct execute() callers (bench._run_blocked,
-        # tests) would otherwise stream worker heartbeat deltas with no
+        # to_arrow, but direct execute() callers (tests) would
+        # otherwise stream worker heartbeat deltas with no
         # query key — phantom entries the live store could never close
         qid = current_query()
         eph_token = None
@@ -744,8 +744,8 @@ class QueryExecution:
 
         # the report's whole point is per-operator annotation: force
         # metrics collection AND launch attribution for the runs EXPLAIN
-        # ANALYZE itself drives, even in sessions that disable them
-        # (bench-style), then restore the session's settings
+        # ANALYZE itself drives, even in sessions that disable them,
+        # then restore the session's settings
         conf = self.session.conf
         forced = (UI_OPERATOR_METRICS, KERNEL_ATTRIBUTION)
         saved = {e.key: conf.overrides().get(e.key)

@@ -91,8 +91,8 @@ class _OutBuffer:
         self.rows += n
         if self.metrics is not None:
             # bytes moved through the shuffle write (codes + validity
-            # planes; dictionaries ride by reference) — the compressed-
-            # execution scoreboard bench.py --encoded reads
+            # planes; dictionaries ride by reference): what compressed
+            # execution saves (tests/test_encoded_exec.py compares it)
             self.metrics.add("shuffle.bytes_shipped", sum(
                 d.nbytes + (v.nbytes if v is not None else 0)
                 for d, v, _ in cols))

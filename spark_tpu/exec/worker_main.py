@@ -394,7 +394,7 @@ def _handle_get_block(payload: bytes):
 
 
 def _handle_block_stats(payload: bytes) -> bytes:
-    """Block-store introspection for tests/CI gates: the chaos suite
+    """Block-store introspection for tests: the chaos suite
     asserts failed queries leave ZERO blocks behind on every worker."""
     with _STORE_LOCK:
         return pickle.dumps({
@@ -414,8 +414,8 @@ def _handle_free_shuffle(payload: bytes) -> bytes:
 
 
 def _handle_lockwatch_edges(_payload: bytes) -> bytes:
-    """Worker-side lock-discipline observations for the --race gate's
-    direct executor cross-check (PR 17 follow-on): the acquisition-
+    """Worker-side lock-discipline observations for the executor
+    cross-check in tests/test_race_lint.py: the acquisition-
     order edges, registered slot names, and guard violations THIS
     worker process recorded under SPARK_TPU_LOCKWATCH=1. Pure host
     reads of the lockwatch observation tables."""

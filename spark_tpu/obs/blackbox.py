@@ -54,9 +54,8 @@ kernel launches, no mid-query device syncs. Off
 (``spark.tpu.obs.bundles`` false) is structurally zero overhead: call
 sites gate on the module bool ``ENABLED`` (one attribute read, the
 utils/faults.py discipline). Armed-but-untriggered adds one
-finding-chain scan per query close and zero launches — the
-``dev/validate_trace.py --bundles`` gate proves the launch-count
-identity.
+finding-chain scan per query close and zero launches
+(tests/test_blackbox.py holds the launch-count identity).
 """
 
 from __future__ import annotations

@@ -14,7 +14,7 @@ labels and exports them three ways:
   * **Prometheus text format** — ``render_prometheus()`` backs the
     history server's ``/metrics`` endpoint and the SQL endpoint's
     ``{"metrics": true}`` request. ``parse_prometheus()`` is the
-    round-trip reader the gates and bench scrape with.
+    round-trip reader the tests scrape with.
 
   * **a bounded time-series ring** — a ticker thread samples the gauge
     surface every ``spark.tpu.metrics.tickInterval`` seconds into a
@@ -456,7 +456,7 @@ def configure(conf) -> None:
 
 def render_prometheus() -> str:
     """The process scrape (history server /metrics, SQL endpoint
-    {"metrics": true}, bench end-of-load scrape)."""
+    {"metrics": true})."""
     return REGISTRY.render_prometheus()
 
 

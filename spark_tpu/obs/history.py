@@ -57,8 +57,7 @@ query's last device interaction):
     improvements never fire); wall-clock and HBM drift raise advisory
     `info` findings (noisy on a shared box — never an error). Findings
     land in the live store, so EXPLAIN ANALYZE and live status surface
-    them; `dev/perfcheck.py` runs the same comparison across commits
-    against a committed baseline.
+    them.
 """
 
 from __future__ import annotations
@@ -466,8 +465,8 @@ class ProfileStore:
     ring-compaction mechanics live in the shared utils/diskstore.
     JsonlRing (one locking implementation for every on-disk metadata
     store — the persistent-cache manifest reuses it). Readers
-    (HistoryReader-style APIs below, the history-server profiles page,
-    dev/perfcheck.py) take no lock: JSONL lines are self-delimiting and
+    (HistoryReader-style APIs below, the history-server profiles page)
+    take no lock: JSONL lines are self-delimiting and
     a torn tail line is skipped."""
 
     def __init__(self, root: str, ring: int = 32):

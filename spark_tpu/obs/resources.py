@@ -347,8 +347,8 @@ class DeviceLedger:
                     "remote": {k: dict(v) for k, v in q["remote"].items()}}
 
     def verify(self) -> list[str]:
-        """Internal-consistency check (dev/validate_trace.py resource
-        gate): non-negative balances everywhere, attribution sums never
+        """Internal-consistency check (tests/test_resource_obs.py):
+        non-negative balances everywhere, attribution sums never
         exceeding the global ledger, identity table reconciling with the
         byte counter."""
         issues = []

@@ -423,8 +423,8 @@ def _flow_events(complete: list) -> list:
     "s" (start, anchored inside the parent slice) + "f" (finish, binding
     to the enclosing child slice) pair with a fresh numeric id. Parents
     that did not make it into the trace (disabled worker tracer, ring
-    eviction) emit nothing — the exporter never leaves a dangling arrow,
-    which is exactly what dev/validate_trace.py checks."""
+    eviction) emit nothing — the exporter never leaves a dangling arrow
+    (tests/test_observability.py's `_flow_edges` checks exactly that)."""
     by_fid = {}
     for ev in complete:
         fid = (ev.get("args") or {}).get("flow_id")

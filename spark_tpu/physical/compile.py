@@ -426,7 +426,7 @@ class KernelCache:
         values under the same names would clobber them in the
         querySucceeded payload — one fact, one metric family. Callers
         that want the raw process-global XLA disk traffic read
-        persist_cache.disk_counters() directly (bench, gates)."""
+        persist_cache.disk_counters() directly (the tests do)."""
         with self._lock:
             return {
                 "kernel_cache.hits": self.hits,

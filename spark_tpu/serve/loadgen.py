@@ -1,9 +1,10 @@
 """Concurrent load generator for the serving layer.
 
-Shared by `bench.py --serve` and the `--serve` CI gate
-(dev/validate_trace.py): N concurrent per-connection sessions replay a
+The serving tests' driver (tests/test_serving.py, test_race_lint.py,
+test_metrics_export.py; ROADMAP R-B5 replaces it with the benchmark's
+server cell): N concurrent per-connection sessions replay a
 mixed dashboard-style query set through one QueryService, and the
-report carries the numbers the serving acceptance gates on — per-pool
+report carries the numbers the serving tests assert on — per-pool
 completion counts and p50/p99 latency, peak queue depth, the
 weight-normalized fairness ratio, and the driver KernelCache launch
 delta across the run (to reconcile against the per-query attributed

@@ -1,8 +1,7 @@
 """Multi-tenant query service: session routing + fair-pool admission.
 
-The serving brain shared by the SQL endpoint (connect/sql_endpoint.py),
-`bench.py --serve`, and the `--serve` CI gate
-(dev/validate_trace.py). Role of the reference's
+The serving brain behind the SQL endpoint (connect/sql_endpoint.py).
+Role of the reference's
 SparkSQLSessionManager + SparkSQLOperationManager over a shared
 SparkContext (sql/hive-thriftserver): many logical sessions, one
 engine process — here with weighted fair-scheduler pools and plan-time

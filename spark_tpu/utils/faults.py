@@ -4,8 +4,7 @@ Role of the reference's chaos/fault-injection test hooks (the
 DistributedSuite kill-executor tests, FailureSuite's deterministic task
 failures, and the excludeOnFailure/HealthTracker suites all hand-roll
 their faults) generalized into one seeded, process-local registry the
-chaos suite (tests/test_chaos.py, dev/validate_trace.py --chaos) drives
-through regular session conf:
+chaos suite (tests/test_chaos.py) drives through regular session conf:
 
   spark.tpu.faults.enabled  master switch (default off)
   spark.tpu.faults.seed     deterministic seed for probabilistic rules

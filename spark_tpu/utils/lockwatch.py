@@ -3,13 +3,13 @@
 The static analyzer (analysis/race_lint.py) builds a whole-repo model of
 shared mutable state, the locks guarding it, and the lock-acquisition
 nesting graph — but a static model is only a claim. This module proves
-the claims at runtime, under the real concurrent loads CI already runs
-(the 8-session serve load, the 2-worker cluster chaos leg):
+the claims at runtime, under the real concurrent loads the tests run
+(tests/test_race_lint.py: a serve load, a 2-worker cluster chaos leg):
 
   * **acquisition orders** — every acquire of a watched lock while other
-    watched locks are held records a (held → acquired) edge. The gate
-    (dev/validate_trace.py --race) unions the observed edges with the
-    static nesting graph and fails on any cycle the static model missed
+    watched locks are held records a (held → acquired) edge. The tests
+    union the observed edges with the static nesting graph and fail on
+    any cycle the static model missed
     (a deadlock hazard that only manifests under a rare interleaving is
     still a hazard).
 
@@ -17,7 +17,7 @@ the claims at runtime, under the real concurrent loads CI already runs
     (the utils/counters.py locked counters, plus explicit `check_guard`
     probes at `# guarded-by:` annotated sites) record whether the lock
     the static model claims guards the mutation was ACTUALLY held.
-    Every annotation must be held where claimed or the gate fails.
+    Every annotation must be held where claimed.
 
 Zero overhead when idle — by construction, not by measurement:
 
@@ -32,7 +32,7 @@ Zero overhead when idle — by construction, not by measurement:
     (one attribute read — the same fast-path discipline utils/faults.py
     uses for its injection points).
 
-Activation: `enable()` / `disable()` (the gate and tests), the
+Activation: `enable()` / `disable()` (tests), the
 `SPARK_TPU_LOCKWATCH=1` environment variable (covers module-import-time
 lock creation and ships to cluster workers through the inherited
 environment), or `spark.tpu.lockwatch.enabled` via `configure(conf)`
@@ -201,8 +201,8 @@ def disable() -> None:
 def configure(conf) -> None:
     """Per-session switch through the registered config surface
     (spark.tpu.lockwatch.enabled). Never turns an env-var-enabled
-    process off — the gate exports SPARK_TPU_LOCKWATCH=1 so cluster
-    workers inherit watching through their spawn environment."""
+    process off — SPARK_TPU_LOCKWATCH=1 is how cluster workers
+    inherit watching through their spawn environment."""
     from ..config import LOCKWATCH_ENABLED
 
     want = bool(conf.get(LOCKWATCH_ENABLED))
@@ -245,7 +245,7 @@ def held_locks() -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# Observations (the gate's read surface)
+# Observations (the read surface)
 # ---------------------------------------------------------------------------
 
 def order_edges() -> dict[tuple, int]:

@@ -477,6 +477,34 @@ def test_plan_lint_runtime_filter_degrades_honestly(spark):
         spark.conf.unset("spark.tpu.adaptive.runtimeFilter")
 
 
+def test_runtime_filter_install_is_a_span_and_explain_analyze_classifies_it():
+    """With the filter armed the launch model says it is inexact; EXPLAIN
+    ANALYZE then classifies the difference and reports no error. The
+    install itself is on the timeline, and the ledger balances after."""
+    from spark_tpu.obs.resources import GLOBAL_LEDGER
+
+    s = _session("rf-analyze", {"spark.tpu.adaptive.runtimeFilter": "true",
+                                "spark.tpu.batch.capacity": 1 << 12})
+    try:
+        a = s.createDataFrame(pa.table({
+            "k": list(range(2000)), "v": list(range(2000))})).repartition(4)
+        b = s.createDataFrame(pa.table({
+            "k": [5, 6, 7], "w": [50, 60, 70]})).repartition(2)
+        df = (a.join(b, on="k").groupBy("k").agg(F.sum("v").alias("sv"))
+              .orderBy("k"))
+        report = df.query_execution.analyzed_report()
+        assert not report.has_unexplained_drift, report.render()
+        m = _counters(s, "adaptive.")
+        assert m.get("adaptive.runtime_filters_installed", 0) >= 1
+        assert m.get("adaptive.filter_rows_pruned", 0) >= 1000
+        installs = [d for d in s.tracer.since(0)
+                    if d["name"] == "adaptive.runtime_filter"]
+        assert installs, "the filter's install left no span"
+        assert GLOBAL_LEDGER.verify() == []
+    finally:
+        s.stop()
+
+
 def test_plan_lint_broadcast_join_stays_exact_with_adaptive(spark):
     """Exactness case: a broadcast join never takes a runtime filter
     (the build side is already local), so arming the layer must NOT

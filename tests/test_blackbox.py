@@ -454,3 +454,55 @@ class TestClusterPull:
             assert "pulled ring:" in render_postmortem(bdir, bid)
         finally:
             s.stop()
+
+    def test_slo_breach_on_the_cluster_captures_exactly_one_bundle(
+            self, tmp_path):
+        """The whole chain, nothing simulated: every worker task sleeps
+        past the pool's SLO (an injected fault; the rows stay right), the
+        service's release raises obs.slo, and that one finding captures
+        one bundle holding the workers' rings, their fault registries
+        and the profile, which the postmortem renders by itself."""
+        from spark_tpu.utils import faults
+
+        s = _session("bb-breach", tmp_path, extra={
+            "spark.sql.adaptive.enabled": "false",
+            "spark.tpu.cluster.enabled": "true",
+            "spark.tpu.cluster.workers": "2",
+            "spark.tpu.obs.profileDir": str(tmp_path / "profiles"),
+            "spark.tpu.metrics.export": "true",
+            "spark.tpu.serve.sloMs": "50",
+            "spark.tpu.faults.enabled": "true",
+            "spark.tpu.faults.seed": "7",
+            "spark.tpu.faults.points": "worker.task=always:sleep:0.2"})
+        try:
+            _seed(s, n=4000)
+            svc = QueryService(s)
+            table = svc.collect(s, s.table("bb_t").repartition(2))
+            want = s.table("bb_t").toArrow()
+            assert sorted(zip(*(c.to_pylist() for c in table.columns))) \
+                == sorted(zip(*(c.to_pylist() for c in want.columns)))
+            bdir = str(tmp_path / "bundles")
+            entries = blackbox.list_bundles(bdir)
+            assert [e["trigger_kind"] for e in entries] == ["obs.slo"], \
+                entries
+            bid = entries[0]["id"]
+            manifest = blackbox.load_bundle(bdir, bid)
+            workers = manifest["workers"]
+            assert workers
+            assert any(t.get("spans") for w in workers.values()
+                       for t in (w.get("tasks") or []))
+            assert any((w.get("faults") or {}).get("fired")
+                       for w in workers.values()), \
+                "no worker says its injected rule fired"
+            assert manifest["profile"] is not None
+            for fname in ("trace.json", "explain_analyze.txt",
+                          "metrics.prom"):
+                assert os.path.isfile(
+                    os.path.join(bdir, f"bundle-{bid}", fname)), fname
+            report = render_postmortem(bdir, bid)
+            for marker in ("Trigger timeline", "obs.slo",
+                           "Per-executor straggler / HBM map"):
+                assert marker in report, marker
+        finally:
+            faults.reset()
+            s.stop()

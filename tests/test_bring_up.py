@@ -1,4 +1,4 @@
-"""Nothing in the program hides the device: no CPU fallback in bench.py, no
+"""Nothing in the program hides the device: no
 compile refusal classified as a runtime fault, no guessed peak table or HBM
 size on an accelerator, a compile cache that is placed from outside, and a
 native library rebuilt when it is older than its source."""
@@ -11,50 +11,6 @@ import sys
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-# ---------------------------------------------------------------------------
-# bench.py
-# ---------------------------------------------------------------------------
-
-def _bench(*args):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               SPARK_TPU_BENCH_SCALE="0.001")
-    return subprocess.run([sys.executable, os.path.join(REPO, "bench.py"),
-                           *args], env=env, cwd=REPO, capture_output=True,
-                          text=True, timeout=300)
-
-
-def test_bench_without_a_tpu_fails_with_a_reason_and_no_record():
-    r = _bench("groupby")
-    assert r.returncode != 0
-    assert "no TPU" in r.stderr and "--smoke" in r.stderr
-    assert r.stdout.strip() == "", "a record was printed without a TPU"
-
-
-def test_bench_smoke_config_that_raises_fails_the_run():
-    r = _bench("--smoke", "no_such_config")
-    assert r.returncode != 0
-    recs = [json.loads(ln) for ln in r.stdout.splitlines()
-            if ln.startswith("{")]
-    assert any(x["metric"] == "no_such_config FAILED" for x in recs), recs
-    # the summary still comes out, and names the device like every record
-    assert recs[-1]["platform"] == "cpu" and "FAILED" in recs[-1]["metric"]
-
-
-def test_bench_child_legs_refuse_a_parent_that_holds_the_device(
-        monkeypatch):
-    sys.path.insert(0, REPO)
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    import bench
-
-    monkeypatch.setattr(bench, "_BACKEND_TOUCHED", True)
-    with pytest.raises(RuntimeError, match="holds the device"):
-        bench.bench_serve_restart()
-    # and main() orders them first, before it initialises a backend
-    order = sorted(["groupby", "serve", "tpcds", "serve_restart"],
-                   key=lambda c: c not in bench._CHILD_LEG_CONFIGS)
-    assert set(order[:2]) == {"serve", "serve_restart"}
 
 
 # ---------------------------------------------------------------------------

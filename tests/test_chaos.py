@@ -411,6 +411,32 @@ def test_shuffle_write_fault_is_transient_task_failure(chaos_spark):
     assert cluster.stats.get("transient_task_failures", 0) > before_t
 
 
+def test_worker_task_failover_keeps_attribution_exact(chaos_spark):
+    """A map task that fails once and runs again on another executor is
+    still accounted once: the launches attributed to operators equal
+    the driver's KernelCache delta plus what the workers shipped."""
+    s = chaos_spark
+    cluster = s._sql_cluster
+    _assert_rows(_shuffle_df(s), s)          # warm both workers
+    before_t = cluster.stats.get("transient_task_failures", 0)
+    _set_faults(s, "worker.task=once")
+    before = KC.launches
+    try:
+        df = _shuffle_df(s)
+        _assert_rows(df, s)
+        driver_delta = KC.launches - before
+    finally:
+        _clear_faults(s)
+        cluster.health.reset()
+    assert cluster.stats.get("transient_task_failures", 0) > before_t
+    worker = sum((df.query_execution._last_ctx.worker_kernel_kinds
+                  or {}).values())
+    attributed = sum(v for nd in df.query_execution.plan_graph()
+                     for v in (nd.get("launches") or {}).values())
+    assert worker > 0 and attributed == driver_delta + worker, (
+        attributed, driver_delta, worker)
+
+
 # ---------------------------------------------------------------------------
 # heartbeat: telemetry error counting, blackout → straggler + speculation
 # ---------------------------------------------------------------------------

@@ -259,6 +259,21 @@ def test_string_shuffle_agg_prediction_exact(edata, fusion):
         .repartition(5, "s").groupBy("s").count()))
 
 
+@pytest.mark.parametrize("fusion", ["true", "false"])
+def test_string_shuffle_agg_explain_analyze_no_drift(edata, fusion):
+    """EXPLAIN ANALYZE over the encoded path: what the dictionary-native
+    kernels launch for a string-keyed repartition + group-by is what the
+    launch model said, so no finding is an error."""
+    edata.conf.set("spark.tpu.fusion.enabled", fusion)
+    report = (edata.sql("select s, v from enc_t where v > 0")
+              .repartition(5, "s").groupBy("s").agg(F.sum("v").alias("sv"))
+              .query_execution.analyzed_report())
+    assert not report.has_unexplained_drift, report.render()
+    assert sum(report.measured.values()) > 0
+    assert not report.measured.get("krange3"), dict(report.measured)
+    assert not report.measured.get("gagg"), dict(report.measured)
+
+
 def test_string_probe_single_dispatch(edata):
     """Fused string probe: one dispatch per probe batch, no separate
     pipeline launch (the dict-hash lut rides as an aux input)."""

@@ -582,9 +582,6 @@ def test_live_ui_summary_includes_live_snapshot(live_cluster_spark):
 # ---------------------------------------------------------------------------
 
 def test_push_merge_exchange_edges_flow_through_merge_span():
-    import importlib.util
-    import os
-
     from spark_tpu.api.session import TpuSession
     from spark_tpu.exec.cluster import LocalCluster
     from tests.test_observability import _flow_edges
@@ -618,11 +615,11 @@ def test_push_merge_exchange_edges_flow_through_merge_span():
     assert merge_spans, "push-merge finalize recorded no producing span"
     assert all((e.get("args") or {}).get("flow_id", "").endswith("#merged")
                for e in merge_spans)
-    # every arrow resolves (no dangling endpoints), and at least one
-    # lands merge span → reduce-side fetch: the exchange edge no longer
-    # stops at the fetch
+    # every arrow is one start and one finish (`_flow_edges` asserts it)
+    # and both land inside a span; at least one lands merge span →
+    # reduce-side fetch: the exchange edge no longer stops at the fetch
     edges = _flow_edges(doc)
-    assert all(srd is not None and dst is not None for srd, dst in edges)
+    assert edges and all(srd is not None and dst is not None for srd, dst in edges)
     assert any(srd["name"].startswith("merge[")
                and dst["name"].startswith("fetch[")
                for srd, dst in edges), \
@@ -630,14 +627,6 @@ def test_push_merge_exchange_edges_flow_through_merge_span():
     # and a map task feeds the merge span (map → merge → fetch chain)
     assert any(srd["cat"] == "worker" and dst["name"].startswith("merge[")
                for srd, dst in edges), "no map-task → merge flow arrow"
-    # the CI validator's referential-integrity check agrees
-    spec = importlib.util.spec_from_file_location(
-        "validate_trace", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "dev", "validate_trace.py"))
-    vt = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(vt)
-    assert vt._check_flows(evs, complete) > 0
 
 
 # ---------------------------------------------------------------------------

@@ -286,6 +286,36 @@ def test_mesh_dispatch_attribution_total_matches_counter(mdata):
     assert mesh_attr, "mesh_stage dispatch not attributed to any operator"
 
 
+def test_mesh_stage_explain_analyze_reconciles_and_trace_nests(mdata):
+    """EXPLAIN ANALYZE over a fused repartition + aggregate on the mesh:
+    the mesh_stage dispatches are the predicted ones, nothing drifts
+    unexplained, every launch has an operator, the spans recorded around
+    the shard_map dispatch still nest, and the ledger balances once the
+    donated send buffers are gone."""
+    from test_observability import _assert_well_formed
+
+    _need_devices(8)
+    spark = mdata
+    spark.conf.set("spark.sql.shuffle.partitions", "8")
+    try:
+        df = (spark.sql("select k, v * 2 as v2 from mf_t where v > 0")
+              .repartition(8, "k").groupBy("k").agg(F.sum("v2").alias("s")))
+        report = df.query_execution.analyzed_report()
+    finally:
+        spark.conf.unset("spark.sql.shuffle.partitions")
+    assert not report.has_unexplained_drift, report.render()
+    assert report.measured.get("mesh_stage", 0) >= 1, dict(report.measured)
+    assert report.predicted.get("mesh_stage") == \
+        report.measured["mesh_stage"], report.render()
+    attributed = sum(v for nd in report.nodes
+                     for v in (nd.get("launches") or {}).values())
+    assert attributed == sum(report.measured.values()), report.render()
+    complete = _assert_well_formed(spark.tracer.to_chrome_trace())
+    assert any((e.get("args") or {}).get("launches", 0) > 0
+               for e in complete), "no span carries kernel attribution"
+    assert GLOBAL_LEDGER.verify() == []
+
+
 def test_mesh_zero_launch_obs_overhead(mdata):
     """The obs contract holds under shard_map: metrics + tracing add
     ZERO kernel launches to a mesh-fused query."""

@@ -157,6 +157,21 @@ def test_mesh_whole_single_dispatch_warm(tiers, data):
     assert _measured(lambda: _q_agg(data)) == {"mesh_whole": 1}
 
 
+def test_mesh_whole_decision_is_on_the_report_and_the_span(tiers, data):
+    _need_devices(4)
+    data.conf.set("spark.tpu.compile.tier", "mesh-whole")
+    df = _q_join_agg(data)
+    report = df.query_execution.analysis_report()
+    assert (report.tier or {}).get("tier") == "mesh-whole", report.tier
+    mark = data.tracer.mark()
+    df.toArrow()
+    programs = [d for d in data.tracer.since(mark)
+                if d["name"] == "whole_query.program"]
+    assert programs and all(
+        (d.get("args") or {}).get("tier") == "mesh-whole"
+        for d in programs), programs
+
+
 @pytest.mark.parametrize("name,q,by", QUERIES,
                          ids=[n for n, _q, _b in QUERIES])
 def test_mesh_lint_exact(tiers, data, name, q, by):

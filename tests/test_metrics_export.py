@@ -415,6 +415,51 @@ def test_pool_histograms_on_scrape_count_admitted_queries():
         s.stop()
 
 
+def test_concurrent_load_with_export_on_counts_every_admitted_query(
+        tmp_path):
+    """Under a concurrent load with the plane on: every admitted query
+    is released through exactly one pool histogram, the default sources
+    are on the scrape, the stored profiles still sum to the KernelCache's
+    delta, and the drain froze a ring the ticker had sampled into."""
+    from spark_tpu.obs.history import ProfileStore
+    from spark_tpu.serve.loadgen import run_serve_load
+
+    s = _session("mx-load", {
+        "spark.tpu.obs.profileDir": str(tmp_path),
+        "spark.tpu.scheduler.pools": "dash:2,batch:1",
+        "spark.tpu.serve.maxConcurrent": 2,
+        "spark.tpu.metrics.export": "true",
+        "spark.tpu.metrics.tickInterval": "0.1",
+    })
+    try:
+        _seed(s)
+        queries = ["select k, sum(v) s from mx_t group by k",
+                   "select k, v from mx_t where v > 0 order by v limit 16"]
+        svc = QueryService(s)
+        before = KC.launches
+        warm = svc.open_session()
+        for q in queries:
+            svc.execute_sql(warm, q)
+        report = run_serve_load(svc, queries, sessions=6, reps=2,
+                                pools=("dash", "batch"))
+        assert not report["errors"], report["errors"]
+        out = mx.parse_prometheus(mx.render_prometheus())
+        e2e = sum(v for (n, _l), v in out["samples"].items()
+                  if n == "spark_tpu_serve_pool_e2e_ms_count")
+        assert int(e2e) == len(queries) * (1 + 6 * 2)
+        assert "spark_tpu_kernel_launches" in out["types"]
+        store = ProfileStore(str(tmp_path))
+        attributed = sum(int(p["launch_total"])
+                         for qk in store.query_keys()
+                         for p in store.profiles(qk))
+        assert attributed == KC.launches - before
+        assert svc.drain(timeout=10.0)
+        assert (svc.drain_snapshot or {}).get("series"), \
+            "the drain froze an empty ring: the ticker never sampled"
+    finally:
+        s.stop()
+
+
 def test_executor_payload_shape():
     p = mx.executor_payload()
     assert "kernel.launches" in p and "kernel.compiles" in p

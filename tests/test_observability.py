@@ -516,10 +516,61 @@ def _flow_edges(doc):
                     best = sp
         return best
 
+    # every arrow is exactly one "s" and one "f": a repeated or a
+    # stepped ("t") endpoint renders as an arrow from or to nowhere
+    phases = {}
+    for e in evs:
+        if e.get("ph") in ("s", "t", "f"):
+            phases.setdefault(e["id"], []).append(e["ph"])
+    broken = {i: p for i, p in phases.items() if sorted(p) != ["f", "s"]}
+    assert not broken, f"broken flow arrows: {broken}"
     starts = {e["id"]: e for e in evs if e.get("ph") == "s"}
     ends = {e["id"]: e for e in evs if e.get("ph") == "f"}
-    assert set(starts) == set(ends), "unpaired flow events"
     return [(enclosing(starts[i]), enclosing(ends[i])) for i in starts]
+
+
+def _assert_well_formed(doc):
+    """What a trace viewer needs of an exported trace: complete events
+    with every field and no negative time, and on each thread track
+    spans that nest or are disjoint (1 us of slack for float rounding).
+    Returns the complete events."""
+    evs = doc["traceEvents"]
+    assert isinstance(evs, list) and evs
+    complete = [e for e in evs if e.get("ph") == "X"]
+    assert complete, "no complete span events"
+    for e in complete:
+        assert {"name", "ts", "dur", "pid", "tid"} <= set(e), e
+        assert e["ts"] >= 0 and e["dur"] >= 0, e
+    tracks = {}
+    for e in complete:
+        tracks.setdefault((e["pid"], e["tid"]), []).append(e)
+    for track, spans in tracks.items():
+        spans.sort(key=lambda e: (e["ts"], -e["dur"]))
+        stack = []
+        for e in spans:
+            while stack and e["ts"] >= stack[-1]["ts"] + stack[-1]["dur"] - 1:
+                stack.pop()
+            if stack:
+                outer = stack[-1]
+                assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1, (
+                    f"span {e['name']!r} partially overlaps "
+                    f"{outer['name']!r} on track {track}")
+            stack.append(e)
+    return complete
+
+
+def test_exported_trace_is_well_formed_and_spans_nest(data, tmp_path):
+    import json
+
+    spark = data
+    spark.sql(Q_JOIN).toArrow()
+    spark.sql("select v from obs_t").repartition(4) \
+        .filter("v > 0").toArrow()
+    with open(spark.tracer.write_chrome_trace(
+            str(tmp_path / "trace.json"))) as f:
+        complete = _assert_well_formed(json.load(f))
+    # lanes ran beside the main thread, so nesting was checked on several
+    assert len({(e["pid"], e["tid"]) for e in complete}) > 1
 
 
 def test_flow_events_link_execution_stage_and_lanes(data):
@@ -683,3 +734,18 @@ def test_cluster_trace_exports_cross_process_flow_arrows(cluster_spark):
         "no stage → worker-task flow arrow"
     assert any(d["name"].startswith("fetch[") and s["cat"] == "worker"
                for s, d in edges), "no map-task → reduce-fetch flow arrow"
+
+
+def test_cluster_trace_is_well_formed_with_worker_tracks(cluster_spark):
+    """Spans shipped from the worker processes keep the viewer's rules:
+    they land on thread tracks of their own, named `worker:<id>/...`, and
+    nest there like the driver's."""
+    spark = cluster_spark
+    _cq(spark).toArrow()
+    doc = spark.tracer.to_chrome_trace()
+    complete = _assert_well_formed(doc)
+    worker_tids = {m["tid"] for m in doc["traceEvents"]
+                   if m.get("ph") == "M" and m.get("name") == "thread_name"
+                   and str(m["args"]["name"]).startswith("worker:")}
+    assert worker_tids, "no worker thread track in the exported trace"
+    assert any(e["tid"] in worker_tids for e in complete)

@@ -487,28 +487,70 @@ s = TpuSession("pc-child", {
     "spark.sql.shuffle.partitions": 2,
     "spark.tpu.batch.capacity": 1 << 12,
     "spark.tpu.fusion.minRows": "0",
+    "spark.sql.adaptive.enabled": "false",
+    "spark.tpu.obs.profileDir": os.path.join(sys.argv[1], "profiles"),
 })
 rng = np.random.default_rng(3)
 s.createDataFrame(pa.table({
     "k": rng.integers(0, 9, 4000), "v": rng.integers(-20, 80, 4000),
 })).createOrReplaceTempView("pc_t")
-df = s.sql("select k, sum(v) s from pc_t where v > 0 group by k")
+s.createDataFrame(pa.table({
+    "k": np.repeat(np.arange(9), 3), "tag": np.arange(27),
+})).createOrReplaceTempView("pc_dim")
+
+# 1: the compile cache. The first run is the one that compiles (warm: is
+# served from disk), so its profile carries the attribution.
+q = lambda: s.sql("select k, sum(v) s from pc_t where v > 0 group by k")
+df = q()
 out = df.toArrow()
+prof = df.query_execution._last_profile or {}
+
+# 2: the whole tier's capacity ladder. The 3x-expanding join overflows
+# its first output bucket; a restart finds the final capacities in the
+# manifest.
+s.conf.set("spark.tpu.compile.tier", "whole")
+jq = lambda: s.sql("select p.k, count(*) n from pc_t p join pc_dim d "
+                   "on p.k = d.k group by p.k")
+jrep = jq().query_execution.analysis_report()
+c0 = dict(s._metrics.snapshot()["counters"])
+jout = jq().toArrow()
+c1 = dict(s._metrics.snapshot()["counters"])
+s.conf.unset("spark.tpu.compile.tier")
+delta = lambda k: c1.get(k, 0) - c0.get(k, 0)
+
+# 3: the result cache: fill it, then ask again
+s.conf.set("spark.tpu.cache.result.enabled", "true")
+a1 = q().toArrow()
+l0 = KC.launches
+a2 = q().toArrow()
 print("CHILD " + json.dumps({
     "fingerprint": df.query_execution.plan_fingerprint()["fingerprint"],
     "compiles": KC.misses,
     "disk": pc.disk_counters(),
     "disk_hit_compiles": KC.disk_hit_compiles,
     "rows": out.num_rows,
+    "profile_compiles": prof.get("compiles"),
+    "profile_disk_hit": prof.get("compiles_disk_hit"),
+    "profile_counters": sorted(prof.get("counters") or {}),
+    "wq": {"predicted": jrep.predicted_launches.get("whole_query"),
+           "exact": jrep.exact,
+           "dispatches": delta("whole_query.dispatches"),
+           "retries": delta("whole_query.capacity_retries"),
+           "rows": jout.to_pylist()},
+    "rc_hits": int(s._metrics.snapshot()["counters"]
+                   .get("result_cache.hit", 0)),
+    "rc_repeat_launches": KC.launches - l0,
+    "rc_equal": a1.equals(a2),
 }))
 '''
 
 
-def test_fingerprint_and_compile_cache_across_subprocesses(tmp_path):
-    """The satellite's durability proof: a cold subprocess populates the
-    XLA disk cache; a FRESH subprocess re-runs the same query with the
-    identical fingerprint and ZERO true cold XLA compiles (every
-    backend compile served from disk)."""
+@pytest.fixture(scope="module")
+def cold_and_warm(tmp_path_factory):
+    """The same three legs in two REAL processes sharing one
+    spark.tpu.cache.dir: the first finds it empty, the second is the
+    restart."""
+    cache = str(tmp_path_factory.mktemp("pc_restart"))
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     # the harness pins the XLA disk cache off (conftest); this is the
@@ -518,7 +560,7 @@ def test_fingerprint_and_compile_cache_across_subprocesses(tmp_path):
 
     def child(tag):
         proc = subprocess.run(
-            [sys.executable, "-c", _CHILD, str(tmp_path)],
+            [sys.executable, "-c", _CHILD, cache],
             env=env, cwd=root, stdout=subprocess.PIPE,
             stderr=subprocess.PIPE, text=True, timeout=300)
         lines = [ln for ln in proc.stdout.splitlines()
@@ -527,8 +569,15 @@ def test_fingerprint_and_compile_cache_across_subprocesses(tmp_path):
             f"{tag} child failed: {proc.stderr[-500:]}"
         return json.loads(lines[-1][len("CHILD "):])
 
-    cold = child("cold")
-    warm = child("warm")
+    return child("cold"), child("warm")
+
+
+def test_fingerprint_and_compile_cache_across_subprocesses(cold_and_warm):
+    """The satellite's durability proof: a cold subprocess populates the
+    XLA disk cache; a FRESH subprocess re-runs the same query with the
+    identical fingerprint and ZERO true cold XLA compiles (every
+    backend compile served from disk)."""
+    cold, warm = cold_and_warm
     assert cold["fingerprint"] == warm["fingerprint"], \
         "fingerprint unstable across processes — persistent keys dead"
     assert cold["disk"]["compile.disk_miss"] >= 1
@@ -538,3 +587,38 @@ def test_fingerprint_and_compile_cache_across_subprocesses(tmp_path):
     assert warm["disk_hit_compiles"] >= 1, \
         "no kernel classified as disk-served on the warm restart"
     assert warm["rows"] == cold["rows"]
+
+
+def test_restarted_process_profile_says_its_compiles_came_from_disk(
+        cold_and_warm):
+    cold, warm = cold_and_warm
+    assert cold["profile_compiles"] >= 1 and not cold["profile_disk_hit"], \
+        cold
+    assert warm["profile_disk_hit"] >= 1, warm
+    assert warm["profile_disk_hit"] == warm["profile_compiles"], \
+        "a restart's profile shows a compile the disk did not serve"
+    assert "compile.disk_hit" in warm["profile_counters"], \
+        warm["profile_counters"]
+
+
+def test_manifest_seed_collapses_the_ladder_in_a_restarted_process(
+        cold_and_warm):
+    cold, warm = cold_and_warm
+    assert cold["wq"]["retries"] >= 1 and cold["wq"]["dispatches"] >= 2, \
+        f"the join never overflowed, so the seed has nothing to show: {cold}"
+    assert cold["wq"]["exact"] \
+        and cold["wq"]["predicted"] == cold["wq"]["dispatches"]
+    assert warm["wq"]["retries"] == 0 and warm["wq"]["dispatches"] == 1, \
+        f"the restart climbed the ladder again: {warm['wq']}"
+    # the launch model reads the same seed
+    assert warm["wq"]["exact"] and warm["wq"]["predicted"] == 1
+    assert warm["wq"]["rows"] == cold["wq"]["rows"]
+
+
+def test_result_cache_answers_a_restarted_process_without_a_launch(
+        cold_and_warm):
+    cold, warm = cold_and_warm
+    for leg in (cold, warm):
+        assert leg["rc_repeat_launches"] == 0 and leg["rc_equal"], leg
+    # the restart's first ask is already a hit: the cold process stored it
+    assert warm["rc_hits"] > cold["rc_hits"] >= 1, (cold, warm)

@@ -407,36 +407,3 @@ def test_degraded_whole_tier_renders_member_attribution(tmp_path):
     finally:
         faults.reset()
         s.stop()
-
-
-# ---------------------------------------------------------------------------
-# perfcheck comparator (the CI gate's pure logic)
-# ---------------------------------------------------------------------------
-
-def test_perfcheck_compare_flags_counter_drift():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "perfcheck", os.path.join(os.path.dirname(__file__), "..",
-                                  "dev", "perfcheck.py"))
-    pc = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pc)
-    base = {"queries": {"qk": {"detail": "agg", "compiles_steady": 0,
-                               "launches": {"pipeline": 2},
-                               "counters": {}}}}
-    clean = {"qk": {"detail": "agg", "compiles_steady": 0,
-                    "launches": {"pipeline": 2}, "counters": {}}}
-    regs, notes = pc.compare(clean, base)
-    assert regs == []
-    worse = {"qk": {"detail": "agg", "compiles_steady": 1,
-                    "launches": {"pipeline": 3, "gagg": 1},
-                    "counters": {"scheduler.stage_retries": 1}}}
-    regs, _ = pc.compare(worse, base)
-    assert len(regs) == 4  # 2 kinds + compiles + retry counter
-    regs, _ = pc.compare({}, base)
-    assert regs and "missing" in regs[0]
-    better = {"qk": {"detail": "agg", "compiles_steady": 0,
-                     "launches": {"pipeline": 1}, "counters": {}}}
-    regs, notes = pc.compare(better, base)
-    assert regs == [] and notes, "improvement must pass with a note"

@@ -14,6 +14,7 @@ pass-through); and the locked counters lose no updates under racing
 threads while validating their own guard under watching.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -31,6 +32,14 @@ EXEC = "spark_tpu/exec/fx_mod.py"        # obs-scoped AND worker-shipped
 API = "spark_tpu/api/fx_api.py"          # neither
 
 _RAW_LOCK_TYPE = type(threading.Lock())
+
+
+@functools.lru_cache(maxsize=None)
+def _repo_model():
+    """The static model of the repo as it stands: one whole-repo AST pass
+    for every test that reads it."""
+    return race_lint.build_model([os.path.join(REPO, "spark_tpu")],
+                                 repo_root=REPO)
 
 
 def _rules(sources):
@@ -339,10 +348,26 @@ def test_repo_baseline_is_empty():
 
 
 def test_static_lock_graph_is_acyclic():
-    model = race_lint.build_model([os.path.join(REPO, "spark_tpu")],
-                                  repo_root=REPO)
+    model = _repo_model()
     cyc = lockwatch.find_cycle(model.lock_edges)
     assert cyc is None, f"static lock-order cycle: {cyc}"
+
+
+def test_registered_watch_slots_are_in_the_static_inventory():
+    """The static and the runtime half share one lock namespace: a slot
+    registered for watching that the static model does not know means
+    the two have drifted apart. The metrics plane's and the black box's
+    locks are registered, so neither left the net."""
+    import spark_tpu.api.session  # noqa: F401  (registers the engine's slots)
+    import spark_tpu.obs.blackbox  # noqa: F401
+    import spark_tpu.obs.export  # noqa: F401
+
+    names = {n for n in lockwatch.registered_names()
+             if not n.startswith(("counter.", "t_"))}   # t_: this file's
+    assert {"obs.export.MetricsRegistry._lock", "obs.export._TS_LOCK",
+            "obs.blackbox._LOCK"} <= names, sorted(names)
+    model = _repo_model()
+    assert names <= set(model.locks), sorted(names - set(model.locks))
 
 
 def test_cli_runs_clean_and_fails_on_new(tmp_path):
@@ -536,11 +561,47 @@ def test_counter_bump_validates_own_guard_when_watched():
         lockwatch.reset_observations()
 
 
+def test_transport_retry_counts_under_its_own_lock_when_watched():
+    """A real absorbed flap drives RETRY_STATS' instrumented bump: the
+    guard check fires at that site, finds the lock held, and disable()
+    puts the raw lock back."""
+    from spark_tpu.config import SQLConf
+    from spark_tpu.net.transport import (
+        RETRY_STATS, RetryPolicy, RpcClient, RpcServer,
+    )
+    from spark_tpu.utils import faults
+
+    server = RpcServer("rl")
+    server.register("echo", lambda p: p)
+    addr = server.start()
+    lockwatch.enable()
+    lockwatch.reset_observations()
+    try:
+        faults.configure(SQLConf({
+            "spark.tpu.faults.enabled": "true",
+            "spark.tpu.faults.points": "rpc.call=first:1"}))
+        before = RETRY_STATS["absorbed"]
+        with RpcClient(addr, "rl") as c:
+            assert c.call("echo", b"y", retry=RetryPolicy(
+                attempts=3, base_ms=1.0, deadline_s=5.0)) == b"y"
+        assert RETRY_STATS["absorbed"] > before
+        assert any(site.startswith("net.transport.RETRY_STATS")
+                   for site, _lock in lockwatch.guard_checks()), \
+            lockwatch.guard_checks()
+        assert lockwatch.violations() == []
+    finally:
+        faults.reset()
+        server.stop()
+        lockwatch.disable()
+        lockwatch.reset_observations()
+    assert isinstance(RETRY_STATS._lock, _RAW_LOCK_TYPE)
+
+
 # ---------------------------------------------------------------------------
 # integration: a real concurrent serve load under lockwatch
 # ---------------------------------------------------------------------------
 
-def test_concurrent_serve_load_under_lockwatch():
+def test_concurrent_serve_load_under_lockwatch(tmp_path):
     """The gate's serve leg in miniature: cloned sessions collecting
     concurrently with every registered lock watched — zero guard
     violations, observed acquisition orders union the static nesting
@@ -549,6 +610,8 @@ def test_concurrent_serve_load_under_lockwatch():
     import pyarrow as pa
 
     from spark_tpu import TpuSession
+    from spark_tpu.obs.history import ProfileStore
+    from spark_tpu.physical.compile import GLOBAL_KERNEL_CACHE as KC
     from spark_tpu.serve import QueryService
     from spark_tpu.serve.loadgen import run_serve_load
 
@@ -559,6 +622,7 @@ def test_concurrent_serve_load_under_lockwatch():
         "spark.tpu.batch.capacity": 1 << 11,
         "spark.tpu.fusion.minRows": "0",
         "spark.tpu.serve.maxConcurrent": 2,
+        "spark.tpu.obs.profileDir": str(tmp_path),
     })
     try:
         rng = np.random.default_rng(3)
@@ -567,13 +631,17 @@ def test_concurrent_serve_load_under_lockwatch():
             "v": rng.integers(-20, 60, 1500).astype(np.int64),
         })).createOrReplaceTempView("rl_t")
         service = QueryService(session)
+        before = KC.launches
         report = run_serve_load(
             service, ["select k, sum(v) s from rl_t group by k"],
             sessions=3, reps=1)
         assert not report["errors"], report["errors"]
+        store = ProfileStore(str(tmp_path))
+        assert sum(int(p["launch_total"]) for qk in store.query_keys()
+                   for p in store.profiles(qk)) == KC.launches - before, \
+            "the proxies perturbed what the obs layer attributes"
         assert lockwatch.violations() == []
-        model = race_lint.build_model([os.path.join(REPO, "spark_tpu")],
-                                      repo_root=REPO)
+        model = _repo_model()
         merged = set(lockwatch.order_edges()) \
             | {tuple(e) for e in model.lock_edges}
         assert lockwatch.find_cycle(merged) is None
@@ -583,3 +651,63 @@ def test_concurrent_serve_load_under_lockwatch():
         session.stop()
         lockwatch.disable()
         lockwatch.reset_observations()
+
+
+def test_cluster_workers_watch_and_report_over_rpc(monkeypatch):
+    """The executor half: with SPARK_TPU_LOCKWATCH=1 in the environment
+    the two workers of a cluster watch their own locks, a query that
+    meets a block-fetch flap is still right, and what each worker
+    observed (the `lockwatch_edges` RPC) holds to the same rules as the
+    driver: no guard violation, no slot the static model does not know,
+    and no acquisition order that closes a cycle with the static graph."""
+    import numpy as np
+    import pyarrow as pa
+
+    from spark_tpu import TpuSession
+    from spark_tpu.utils import faults
+
+    monkeypatch.setenv("SPARK_TPU_LOCKWATCH", "1")
+    lockwatch.enable()
+    lockwatch.reset_observations()
+    session = TpuSession("race-lint-cluster", {
+        "spark.sql.shuffle.partitions": "2",
+        "spark.tpu.batch.capacity": 1 << 11,
+        "spark.sql.adaptive.enabled": "false",
+        "spark.tpu.cluster.enabled": "true",
+        "spark.tpu.cluster.workers": "2",
+        "spark.tpu.faults.enabled": "true",
+        "spark.tpu.faults.seed": "13",
+        "spark.tpu.faults.points": "block.fetch=first:2",
+    })
+    try:
+        rng = np.random.default_rng(13)
+        keys = rng.integers(0, 24, 3000)
+        vals = rng.integers(-40, 90, 3000)
+        session.createDataFrame(pa.table({"k": keys, "v": vals})) \
+            .createOrReplaceTempView("rl_c")
+        faults.configure(session.conf)
+        got = sorted((r["k"], r["v"]) for r in
+                     session.table("rl_c").repartition(2).collect())
+        assert got == sorted(zip(keys.tolist(), vals.tolist()))
+        workers = session._sql_cluster.lockwatch_edges()
+        observed = set(lockwatch.order_edges())
+        assert lockwatch.violations() == []
+    finally:
+        faults.reset()
+        session.stop()
+        lockwatch.disable()
+        lockwatch.reset_observations()
+    model = _repo_model()
+    assert len(workers) == 2, f"workers that answered: {sorted(workers)}"
+    for eid, w in sorted(workers.items()):
+        assert w["enabled"], f"{eid} did not inherit the watching"
+        assert not w["violations"], (eid, w)
+        unknown = [n for n in w["names"] if not n.startswith("counter.")
+                   and n not in model.locks]
+        assert not unknown, (eid, unknown)
+        observed |= {(a, b) for a, b, _n in w["edges"]}
+    # both may not get a task, but whoever ran one took watched locks
+    assert sum(w["acquires"] for w in workers.values()) > 0
+    cyc = lockwatch.find_cycle(
+        observed | {tuple(e) for e in model.lock_edges})
+    assert cyc is None, f"observed orders close a cycle: {cyc}"

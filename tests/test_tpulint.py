@@ -205,3 +205,74 @@ def test_cli_runs_clean_and_fails_on_new(tmp_path):
     assert r.returncode == 1
     data = json.loads(r.stdout)
     assert data["total"] == 1 and data["new"][0]["rule"] == "host-sync"
+
+
+# ---------------------------------------------------------------------------
+# every registered option is read: a key nothing reads tells its user nothing
+# ---------------------------------------------------------------------------
+
+# Spark's own names, which client code written for Spark sets and reads
+# back: registered so that setting them is no error, honoured by nothing
+# yet (ROADMAP D3: a PR that touches the analyzer honours or refuses them).
+ACCEPTED_AND_IGNORED = {
+    "spark.sql.ansi.enabled": "no ANSI overflow or cast errors in expr/",
+    "spark.sql.session.timeZone": "timestamps are UTC throughout",
+}
+
+
+def _unread_options(sources, keys):
+    """Keys of `keys` whose constant and literal occur nowhere in
+    `sources` ({relpath: text}) but where they are registered. A
+    `SQLConf` property that reads the constant counts only if the
+    property's name is itself read outside config.py."""
+    import re
+    from collections import Counter
+
+    conf_rel = next(r for r in sources if r.endswith("spark_tpu/config.py"))
+    everything = "\n".join(sources.values())
+    outside_conf = "\n".join(t for r, t in sources.items() if r != conf_rel)
+    constant = {m.group(2): m.group(1) for m in re.finditer(
+        r'^(\w+) = _register\(ConfigEntry\(\s*"([^"]+)"', everything, re.M)}
+    props = {}          # constant -> the properties that read it
+    for m in re.finditer(
+            r"@property\s+def (\w+)\(self\)[^\n]*\n\s+return [^\n]*"
+            r"self\.get\((\w+)\)", sources[conf_rel]):
+        props.setdefault(m.group(2), []).append(m.group(1))
+    words = Counter(re.findall(r"\w+", everything))
+    quoted = Counter(re.findall(r"""["']([\w.]+)["']""", everything))
+
+    unread = []
+    for key in keys:
+        const = constant[key]
+        mine = props.get(const, ())
+        # the registering statement is one occurrence of each
+        if words[const] - 1 - len(mine) > 0 or quoted[key] - 1 > 0:
+            continue
+        if any(re.search(rf"\.{p}\b", outside_conf) for p in mine):
+            continue
+        unread.append(key)
+    return sorted(unread)
+
+
+def test_every_registered_option_is_read_somewhere():
+    import spark_tpu  # noqa: F401  (every registering module is imported)
+    from spark_tpu import config
+
+    sources = {}
+    for root, _dirs, files in os.walk(os.path.join(REPO, "spark_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                with open(path) as fh:
+                    sources[os.path.relpath(path, REPO)] = fh.read()
+    assert _unread_options(sources, config.registry()) \
+        == sorted(ACCEPTED_AND_IGNORED)
+    # and the rule sees a key that only its registration names
+    planted = dict(sources)
+    planted["spark_tpu/config.py"] += (
+        '\nPLANTED_UNREAD = _register(ConfigEntry(\n'
+        '    "spark.tpu.planted.unread", 1, "", int))\n'
+        "\n    @property\n    def planted_unread(self) -> int:\n"
+        "        return int(self.get(PLANTED_UNREAD))\n")
+    assert "spark.tpu.planted.unread" in _unread_options(
+        planted, [*config.registry(), "spark.tpu.planted.unread"])

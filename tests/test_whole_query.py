@@ -384,6 +384,24 @@ def test_whole_tier_explain_surfaces_decision(tiers, data, capsys):
     assert "whole_query" in out
 
 
+def test_whole_tier_decision_rides_the_span_and_explain_analyze_agrees(
+        tiers, data):
+    """The tier decision is an argument of the `whole_query.program`
+    span, and EXPLAIN ANALYZE over the whole tier measures the one
+    predicted dispatch: no finding is an error."""
+    data.conf.set("spark.tpu.compile.tier", "whole")
+    mark = data.tracer.mark()
+    report = data.sql(Q_JOIN_AGG).query_execution.analyzed_report()
+    assert not report.has_unexplained_drift, report.render()
+    assert set(report.measured) == {"whole_query"}, dict(report.measured)
+    assert dict(report.predicted) == dict(report.measured)
+    programs = [d for d in data.tracer.since(mark)
+                if d["name"] == "whole_query.program"]
+    assert programs and all(
+        (d.get("args") or {}).get("tier") == "whole" for d in programs), \
+        programs
+
+
 def test_operator_tier_boundary_explained(tiers, data):
     data.conf.set("spark.tpu.compile.tier", "operator")
     report = data.sql(Q_AGG).query_execution.analysis_report()
