@@ -7,7 +7,7 @@ design: pointer-chasing hash tables don't vectorize; instead the build side is
 sorted by a combined 64-bit key hash (`lax.sort`), each probe row finds its
 match range as two ranks in the sorted hashes (`rank_sorted`, left and
 right), and the variable-fanout output is flattened into a STATIC-capacity
-batch by a cumsum of the counts and the rank of each output slot in it. Hash
+batch by a cumsum of the counts and each output slot's place in it. Hash
 false-positives are eliminated by gathering and comparing the actual key
 columns (so 64-bit hashing is a grouping accelerator, not a correctness
 assumption).
@@ -19,6 +19,26 @@ keys, both sides, were 7.05 s on a v5e. The merge ranks the queries by sorting
 them together with the keys, counting the keys before each with a cumsum and
 sorting the counts back: sorts and scans, which run at memory speed (the same
 ranks in 0.067 s). `rank_path` picks between them from the two static lengths.
+
+`_expand` flattens the match ranges: probe row i owns the output slots
+[offsets[i] - ecounts[i], offsets[i]), and slot j's row `src` never falls as
+j grows. What a slot needs of its probe row (where its run began, where the
+run lies in the sorted build side, the probe key, and for `take_probe` any
+probe column) it has by one of two bodies. The *gather* body ranks every
+slot in the offsets and gathers each value by `src`: 8 Mi slots fetching
+from 131 072 probe rows paid 8 Mi scalar gathers a value, six or seven
+values a join. The *fill* body sends each owner's value to the owner's first
+slot (`pcap` scattered scalars) and carries it along the run with a scan:
+`src` is a `cummax` of the scattered row numbers, so nothing is ranked and
+the rank's two sorts leave the program, and any other value is a `cumsum` of
+the steps from one owner's value to the next, exact in wrapping integers. A
+dead or null-keyed probe row owns no slot, so the fill fetches no validity
+at all. `src_path` picks between them from the two static lengths: a small
+side probing a large one fills, a flow probing a dimension (`pcap` >=
+`out_cap`) keeps its gathers, whose scatters would cost more. What is still
+gathered (by the build row: a permutation's order) takes its validity planes
+along as the bits of one byte (`take_planes`): a gathered byte costs what a
+gathered bool costs.
 
 Output capacity overflow is reported via a scalar (`needed`) that the host
 checks to retry at the next capacity bucket (SURVEY.md §7 'Hard parts' (1)).
@@ -48,6 +68,27 @@ GATHER_S = 15e-9
 MERGE_S = 7e-9
 MERGE_FIXED_S = 0.05
 
+# What a probe row's value at every output slot costs `_expand`, per body
+# (PERF.md §6, PR 32: the probe, chip call 1, device ms, the least of 5).
+# By a gather by `src`, from 131 072 rows into 8 Mi slots: an int32 60.6 ms,
+# a bool 68.4, an int64 249-252 (7.2, 8.2 and 30 ns a slot; from 1 Ki rows
+# into 32 Mi slots 227, 272 and 481 ms): GATHER_S above stands for the mix.
+# By the fill: SCATTER_S, a scalar sent to its owner's first slot, is 11 ns
+# for 131 072 int32 at rising places (1.44 ms; 0.9 ms for 1 Ki), but 71-142
+# ns for 8 Mi scalars by a permutation (PR 30), and where `pcap` nears
+# `out_cap` the fill's scatters are of that kind, so the rule takes the dear
+# end. SCAN_S, a slot through the `cumsum` that carries a value along its
+# run: 0.27-0.5 ns for 32 bits (2.3 ms at 8 Mi, 8.0 at 32 Mi; a `cummax`
+# 3.8 and 13.8), so 1 ns for a 64-bit value's two halves. Whole fills: `src`
+# 4.5 ms at 8 Mi and 14.1-14.8 at 32 Mi, an int32 4.0 and 8.4-10.0; an int64
+# as one 64-bit scatter and scan was 20.5-21.4 and 40.4-58.3 and compiled for
+# 37-42 s at 32 Mi where an int32 takes 6 (hence the halves), and a 64-bit
+# `cummax` carrying `src` with 32 bits of payload 22.4-23.1 and 53-70, for
+# 75-204 s of compile (hence the `cumsum` of steps). The fill adds no
+# `lax.sort` and takes the rank's two out.
+SCATTER_S = 80e-9
+SCAN_S = 1e-9
+
 
 def rank_path(n_sorted: int, n_queries: int) -> str:
     """`"merge"` or `"search"`: the cheaper body of `rank_sorted` for
@@ -56,6 +97,14 @@ def rank_path(n_sorted: int, n_queries: int) -> str:
     search = n_queries * steps * GATHER_S
     merge = MERGE_FIXED_S + (n_sorted + n_queries) * MERGE_S
     return "merge" if merge < search else "search"
+
+
+def src_path(pcap: int, out_cap: int) -> str:
+    """`"fill"` or `"gather"`: the cheaper way for `_expand` to have, at
+    each of `out_cap` output slots, a value of the probe row `src` that
+    owns the slot, both lengths known at trace time."""
+    fill = pcap * SCATTER_S + out_cap * SCAN_S
+    return "fill" if fill < out_cap * GATHER_S else "gather"
 
 
 def rank_sorted(a: jnp.ndarray, v: jnp.ndarray, side: str,
@@ -112,7 +161,8 @@ class BuildSide(NamedTuple):
 
 # Scopes: each separate loop of the join carries its own `jax.named_scope`
 # (`build_sort`, `probe`, `expand`), so that a profile of a program that
-# traced these bodies says which of them the device is in.
+# traced these bodies says which of them the device is in; inside them
+# `rank_<path>`, `src_fill` and `pack_valid` say which body ran.
 
 @jax.named_scope("build_sort")
 def build_index(key_cols: Sequence[jnp.ndarray],
@@ -130,12 +180,87 @@ def build_index(key_cols: Sequence[jnp.ndarray],
     return BuildSide(sh, perm)
 
 
+class SrcRuns(NamedTuple):
+    """The output's runs, for the fill body: probe row i's slots are
+    [start[i], start[i] + ecounts[i])."""
+
+    start: jnp.ndarray   # int32[pcap] the row's first output slot; out_cap
+    #                      where it owns none (or none below out_cap)
+    prev: jnp.ndarray    # int32[pcap] the nearest owner before the row, -1:
+    #                      none
+
+
 class JoinResult(NamedTuple):
     probe_idx: jnp.ndarray   # int32[OC] source probe-row index per output row
     build_idx: jnp.ndarray   # int32[OC] source build-row index (clipped when unmatched)
     matched: jnp.ndarray     # bool[OC] true => real build match (false => null-extended)
     out_mask: jnp.ndarray    # bool[OC] live output rows
     needed: jnp.ndarray      # int32 scalar: total rows the join wanted to emit
+    runs: SrcRuns | None = None  # where `src_path` said "fill": take_probe's
+
+
+def take_probe(r: JoinResult, x: jnp.ndarray) -> jnp.ndarray:
+    """`x[r.probe_idx]`, a probe-side column at every output slot, on the
+    body `src_path` picked for the join."""
+    if r.runs is None:
+        return jnp.take(x, r.probe_idx)
+    return _fill(r.runs, x, r.probe_idx.shape[0])
+
+
+def _fill(runs: SrcRuns, x: jnp.ndarray, oc: int) -> jnp.ndarray:
+    """`x[src]` with no gather at the output's size. `src` never falls
+    from one slot to the next, so the value at a slot is the sum of the
+    steps between consecutive owners up to it: each owner's step from the
+    owner before it goes to its first slot (`pcap` scattered scalars), and
+    one `cumsum` carries them along the runs. Exact for any bits: the sum
+    telescopes in wrapping int32, which a 64-bit value rides as two halves
+    (an int64 scatter and scan cost the chip five times an int32's, and its
+    compiler eight times)."""
+    dt = x.dtype
+    if dt.itemsize == 8:
+        wide = x if dt == jnp.int64 else lax.bitcast_convert_type(
+            x, jnp.int64)
+        low = _fill(runs, wide.astype(jnp.int32), oc)
+        high = _fill(runs, (wide >> 32).astype(jnp.int32), oc)
+        wide = (high.astype(jnp.int64) << 32) | (
+            low.astype(jnp.int64) & 0xFFFFFFFF)
+        return wide if dt == jnp.int64 else lax.bitcast_convert_type(wide, dt)
+    whole = dt == jnp.bool_ or jnp.issubdtype(dt, jnp.integer)
+    bits = x.astype(jnp.int32) if whole else lax.bitcast_convert_type(
+        x, jnp.int32)                  # the engine's narrowest float is 32
+    with jax.named_scope("src_fill"):
+        before = jnp.where(runs.prev >= 0,
+                           jnp.take(bits, jnp.maximum(runs.prev, 0)), 0)
+        steps = jnp.zeros(oc, dtype=jnp.int32).at[runs.start].set(
+            bits - before, mode="drop")
+        out = jnp.cumsum(steps, dtype=jnp.int32)
+    return out.astype(dt) if whole else lax.bitcast_convert_type(out, dt)
+
+
+def take_planes(planes: Sequence[jnp.ndarray | None], fetch) -> list:
+    """Each bool plane of one side at the output's slots (`None` stays
+    `None`), for one fetch per eight planes: they ride as the bits of uint8
+    words, packed at the side's own capacity, and `fetch` (a gather by the
+    side's row index, or `take_probe`) moves the words. A gathered byte
+    costs what a gathered bool costs, whatever the table's size (an int32
+    word would cost up to twice that from a 32 Mi table); a side with one
+    plane fetches it as it is."""
+    live = [p for p in planes if p is not None]
+    if len(live) < 2:
+        return [None if p is None else fetch(p) for p in planes]
+    with jax.named_scope("pack_valid"):
+        words = []
+        for at in range(0, len(live), 8):
+            word = jnp.uint8(0)
+            for bit, plane in enumerate(live[at:at + 8]):
+                word = word | (plane.astype(jnp.uint8) << bit)
+            words.append(fetch(word))
+        out, at = [], 0
+        for p in planes:
+            out.append(None if p is None else
+                       (words[at // 8] >> (at % 8)) & 1 == 1)
+            at += p is not None
+        return out
 
 
 def probe_join(build: BuildSide,
@@ -169,6 +294,25 @@ def probe_join(build: BuildSide,
                    counts)
 
 
+@jax.named_scope("src_fill")
+def _src_runs(offsets, ecounts, oc: int) -> tuple:
+    """The fill body's bookkeeping: (`SrcRuns`, `src`, each row's first
+    slot). Probe row i owns the slots [offsets[i] - ecounts[i], offsets[i])
+    if it has any; the last row also owns what lies beyond the last offset,
+    as the gather body's clipped `src` has it, so every slot has an owner
+    and both bodies give the same arrays, dead slots included."""
+    pcap = offsets.shape[0]
+    row = lax.iota(jnp.int32, pcap)
+    start = offsets - ecounts
+    owns = ((ecounts > 0) | (row == pcap - 1)) & (start < oc)
+    at = jnp.where(owns, start, oc)
+    last = lax.cummax(jnp.where(owns, row, -1), axis=0)
+    prev = jnp.concatenate([jnp.full(1, -1, jnp.int32), last[:-1]])
+    src = lax.cummax(jnp.zeros(oc, jnp.int32).at[at].set(row, mode="drop"),
+                     axis=0)
+    return SrcRuns(at, prev), src, start
+
+
 @jax.named_scope("expand")
 def _expand(build, build_key_cols, build_key_valids, probe_key_cols,
             probe_key_valids, probe_mask, oc, join_type, pcap, lo,
@@ -182,22 +326,52 @@ def _expand(build, build_key_cols, build_key_valids, probe_key_cols,
     # expansion (cheap: one gather per key col) and fix the semi/anti/outer
     # masks after expansion via a max-scatter back to probe rows.
 
+    if join_type not in ("inner", "left_semi", "left_anti", "left_outer"):
+        raise ValueError(f"unsupported join type {join_type}")
     if join_type in ("left_semi", "left_anti", "left_outer"):
         ecounts = jnp.maximum(counts, jnp.where(probe_mask, 1, 0))
     else:
         ecounts = counts
 
-    offsets = jnp.cumsum(ecounts)  # inclusive, int64 under x64
+    offsets = jnp.cumsum(ecounts)  # inclusive; int32, as the counts are
     total = offsets[pcap - 1] if pcap > 0 else jnp.int64(0)
 
     j = lax.iota(jnp.int64, oc)
-    src = jnp.minimum(rank_sorted(offsets, j, "right"), pcap - 1)
-    base = offsets[src] - ecounts[src]
-    within = (j - base).astype(jnp.int32)
-    in_range = j < total
+    bcap = build.perm.shape[0]
+    fill = src_path(pcap, oc) == "fill"
+    if fill:
+        runs, src, start = _src_runs(offsets, ecounts, oc)
 
-    has_build = within < counts[src]
-    bpos = jnp.minimum(build.perm.shape[0] - 1, lo[src] + within)
+        def by_src(x):
+            return _fill(runs, x, oc)
+
+        in_range = j < total
+        # A row owns slots only if it is live with a usable key, or live
+        # (the outer kinds' one slot): no slot below `total` reads a dead
+        # probe row, and none with a build row behind it a null probe key,
+        # so neither mask is fetched.
+        j32 = lax.iota(jnp.int32, oc)
+        if join_type == "inner":
+            within = None
+            has_build = in_range          # every slot owned is a pair
+        else:
+            within = j32 - by_src(start)
+            has_build = within < by_src(counts)
+        # lo[src] + within, the slot's place in the sorted build side
+        bpos = jnp.minimum(bcap - 1, j32 + by_src(lo - start))
+    else:
+        runs = None
+        src = jnp.minimum(rank_sorted(offsets, j, "right"), pcap - 1)
+
+        def by_src(x):
+            return jnp.take(x, src)
+
+        base = offsets[src] - ecounts[src]
+        within = (j - base).astype(jnp.int32)
+        in_range = j < total
+
+        has_build = within < counts[src]
+        bpos = jnp.minimum(bcap - 1, lo[src] + within)
     bidx = jnp.take(build.perm, bpos)
 
     # verify true key equality (null keys already excluded via sentinels)
@@ -205,19 +379,23 @@ def _expand(build, build_key_cols, build_key_valids, probe_key_cols,
     for bc, bv, pc_, pv in zip(build_key_cols, build_key_valids,
                                probe_key_cols, probe_key_valids):
         b_val = jnp.take(bc, bidx)
-        p_val = jnp.take(pc_, src)
+        p_val = by_src(pc_)
         eq = b_val == p_val
         if bv is not None:
             eq = eq & jnp.take(bv, bidx)
-        if pv is not None:
+        if pv is not None and not fill:
             eq = eq & jnp.take(pv, src)
         pair_ok = pair_ok & eq
 
-    live_probe = jnp.take(probe_mask, src)
+    live_probe = None if fill else jnp.take(probe_mask, src)
+
+    def alive():
+        return in_range if fill else in_range & live_probe
 
     if join_type == "inner":
-        out_mask = in_range & live_probe & pair_ok
-        return JoinResult(src, bidx, pair_ok, out_mask, total.astype(jnp.int64))
+        out_mask = alive() & pair_ok
+        return JoinResult(src, bidx, pair_ok, out_mask,
+                          total.astype(jnp.int64), runs)
 
     # count of VERIFIED matches per probe row (scatter-add over output rows)
     vmatch = jnp.zeros(pcap, dtype=jnp.int32).at[src].add(
@@ -225,23 +403,18 @@ def _expand(build, build_key_cols, build_key_valids, probe_key_cols,
 
     if join_type == "left_semi":
         first_slot = within == 0
-        out_mask = in_range & live_probe & first_slot & (jnp.take(vmatch, src) > 0)
-        return JoinResult(src, bidx, pair_ok, out_mask, total.astype(jnp.int64))
-
-    if join_type == "left_anti":
+        out_mask = alive() & first_slot & (by_src(vmatch) > 0)
+    elif join_type == "left_anti":
         first_slot = within == 0
-        out_mask = in_range & live_probe & first_slot & (jnp.take(vmatch, src) == 0)
-        return JoinResult(src, bidx, pair_ok, out_mask, total.astype(jnp.int64))
-
-    if join_type == "left_outer":
-        # matched rows pass; unmatched probe rows emit exactly one null-extended
-        # row in their first slot
-        no_match = jnp.take(vmatch, src) == 0
+        out_mask = alive() & first_slot & (by_src(vmatch) == 0)
+    else:
+        # left_outer: matched rows pass; unmatched probe rows emit exactly
+        # one null-extended row in their first slot
+        no_match = by_src(vmatch) == 0
         null_row = no_match & (within == 0)
-        out_mask = in_range & live_probe & (pair_ok | null_row)
-        return JoinResult(src, bidx, pair_ok, out_mask, total.astype(jnp.int64))
-
-    raise ValueError(f"unsupported join type {join_type}")
+        out_mask = alive() & (pair_ok | null_row)
+    return JoinResult(src, bidx, pair_ok, out_mask, total.astype(jnp.int64),
+                      runs)
 
 
 @jax.named_scope("cross")

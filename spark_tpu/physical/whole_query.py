@@ -1421,15 +1421,18 @@ class _ProgramBuilder:
                     needed.spans.append(
                         (lo_o, hi_o, dup.astype(jnp.int32)))
             with jax.named_scope("gather"):
-                datas = [jnp.take(x, r.probe_idx) for x in pd]
-                valids = [None if x is None
-                          else jnp.take(x, r.probe_idx) for x in pv]
+                # probe columns by the join's `src` on the body the join
+                # took; each side's validity planes as one byte a fetch
+                datas = [J.take_probe(r, x) for x in pd]
+                valids = J.take_planes(pv, lambda w: J.take_probe(r, w))
                 if semi_anti:
                     return datas, valids, r.out_mask
                 null_build = ~r.matched
-                for x, xv in zip(bd, bv):
+                planes = J.take_planes(
+                    bv, lambda w: jnp.take(w, r.build_idx))
+                for x, plane in zip(bd, planes):
                     datas.append(jnp.take(x, r.build_idx))
-                    base = jnp.take(xv, r.build_idx) if xv is not None \
+                    base = plane if plane is not None \
                         else jnp.ones(_oc, dtype=bool)
                     valids.append(base & ~null_build)
                 return datas, valids, r.out_mask
@@ -1439,15 +1442,21 @@ class _ProgramBuilder:
     def _note_ranks(self, node, pcap: int, bcap: int, out_cap: int) -> None:
         """Which body `ops/joining.rank_sorted` takes at each of the sorted
         join's three call sites (probe_join's two ranks of `pcap` hashes in
-        `bcap`, _expand's rank of `out_cap` slots in `pcap` offsets): the
-        same rule the trace asks, counted, and shown in the join's row."""
-        from ..ops.joining import rank_path
+        `bcap`, _expand's rank of `out_cap` slots in `pcap` offsets), and
+        how `_expand` has a probe row's values at the output's slots
+        (`src_path`; the fill ranks nothing): the same rules the trace
+        asks, counted, and shown in the join's row."""
+        from ..ops.joining import rank_path, src_path
 
         probe_path = rank_path(bcap, pcap)
-        expand_path = rank_path(pcap, out_cap)
+        src = src_path(pcap, out_cap)
+        expand_path = "none" if src == "fill" else rank_path(pcap, out_cap)
         self.ctx.metrics.add(f"join.rank_{probe_path}", 2)
-        self.ctx.metrics.add(f"join.rank_{expand_path}")
-        self._note(node, f"rank[probe={probe_path},expand={expand_path}]")
+        if src != "fill":
+            self.ctx.metrics.add(f"join.rank_{expand_path}")
+        self.ctx.metrics.add(f"join.src_{src}")
+        self._note(node, f"rank[probe={probe_path},expand={expand_path}] "
+                         f"src={src}")
 
     def _note(self, node, note: str) -> None:
         """`note` at the end of the node's members row (100 characters)."""

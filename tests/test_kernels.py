@@ -2,10 +2,12 @@
 and codegen'd — here numpy is the oracle for every jitted kernel;
 SURVEY.md §4)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from join_reference import expand_of_pr31
 from spark_tpu.ops import (
     SortKeySpec, build_index, cross_join, group_rows, group_output_mask,
     hash_columns, hash_partition, limit_mask, mix64, partition_ids,
@@ -228,33 +230,175 @@ def test_rank_path_is_a_rule_of_two_lengths(n_sorted, n_queries, path):
     assert rank_path(n_sorted, n_queries) == path
 
 
-@pytest.mark.parametrize("join_type", ["inner", "left_outer", "left_semi",
-                                       "left_anti"])
+JOIN_TYPES = ["inner", "left_outer", "left_semi", "left_anti"]
+
+
+def _join_case(name):
+    """(build key, its validity, build mask, probe key, its validity, probe
+    mask, out capacity) for one shape of join."""
+    rng = np.random.default_rng(7)
+    bcap, pcap, oc, keys = 64, 128, 1 << 11, 12
+    if name in ("fan_out", "overflow"):
+        # every key many times on both sides; `overflow` wants more
+        # slots than the capacity has
+        bcap, keys, oc = 256, 6, (1 << 13 if name == "fan_out" else 1 << 10)
+    elif name == "fill_unforced":
+        bcap, pcap, oc, keys = 4096, 1024, 1 << 16, 300
+    bk = rng.integers(0, keys, bcap).astype(np.int64)
+    pk = rng.integers(-3, keys + 3, pcap).astype(np.int64)
+    bvalid = rng.random(bcap) > 0.15
+    pvalid = rng.random(pcap) > 0.15
+    bmask = np.arange(bcap) < bcap * 3 // 4
+    pmask = rng.random(pcap) > 0.1
+    if name == "no_usable_probe":      # every probe row dead or null-keyed
+        pvalid &= ~pmask
+    return tuple(jnp.asarray(x) for x in (bk, bvalid, bmask, pk, pvalid,
+                                          pmask)) + (oc,)
+
+
+def _joined(case, join_type):
+    bk, bvalid, bmask, pk, pvalid, pmask, oc = case
+    bi = build_index([bk], [bvalid], bmask)
+    return probe_join(bi, [bk], [bvalid], [pk], [pvalid], pmask, oc,
+                      join_type)
+
+
+def _same_arrays(a, b, what):
+    a, b = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(a) == len(b), what
+    for at, (x, y) in enumerate(zip(a, b)):
+        assert x.dtype == y.dtype and x.shape == y.shape, (what, at)
+        assert np.array_equal(np.asarray(x), np.asarray(y),
+                              equal_nan=True), (what, at)
+
+
+@pytest.mark.parametrize("join_type", JOIN_TYPES)
 def test_probe_join_same_on_both_rank_paths(join_type, monkeypatch):
     """Hash-equal runs (duplicate keys on both sides), null keys, dead
     rows, probes that match nothing: the two bodies of `rank_sorted` hand
     `_expand` the same ranks, so every array of the result is the same."""
     from spark_tpu.ops import joining as J
 
-    rng = np.random.default_rng(7)
-    bcap, pcap = 64, 128
-    bk = jnp.asarray(rng.integers(0, 12, bcap).astype(np.int64))
-    pk = jnp.asarray(rng.integers(-3, 15, pcap).astype(np.int64))
-    bvalid = jnp.asarray(rng.random(bcap) > 0.15)
-    pvalid = jnp.asarray(rng.random(pcap) > 0.15)
-    bmask = jnp.asarray(np.arange(bcap) < 50)
-    pmask = jnp.asarray(rng.random(pcap) > 0.1)
+    monkeypatch.setattr(J, "src_path", lambda p, o: "gather")
     results = {}
     for path in ("merge", "search"):
         monkeypatch.setattr(J, "rank_path", lambda n, m, _p=path: _p)
-        bi = build_index([bk], [bvalid], bmask)
-        results[path] = probe_join(bi, [bk], [bvalid], [pk], [pvalid],
-                                   pmask, 1 << 11, join_type)
+        results[path] = _joined(_join_case("as_is"), join_type)
     assert int(results["merge"].out_mask.sum()) > 0
-    for field, a, b in zip(J.JoinResult._fields, results["merge"],
-                           results["search"]):
-        assert a.dtype == b.dtype, field
-        assert np.asarray(a).tolist() == np.asarray(b).tolist(), field
+    _same_arrays(results["merge"], results["search"], join_type)
+
+
+@pytest.mark.parametrize("join_type", JOIN_TYPES)
+@pytest.mark.parametrize("case", ["as_is", "fan_out", "overflow",
+                                  "no_usable_probe", "fill_unforced"])
+def test_probe_join_same_on_both_src_paths(case, join_type, monkeypatch):
+    """What `_expand` fetches by the non-decreasing `src` it may fill by
+    position instead (`src_path`): every array of the result, dead slots
+    and an overflowing `needed` included, and every probe column taken
+    "by `src`" is the same, element for element, on both bodies and in the
+    body PR 31 had."""
+    from spark_tpu.ops import joining as J
+
+    data = _join_case(case)
+    pcap, oc = data[3].shape[0], data[6]
+    rng = np.random.default_rng(32)
+    reals = rng.normal(size=pcap)
+    reals[::7] = np.nan
+    columns = [data[3], data[4], jnp.asarray(reals),
+               jnp.asarray(reals.astype(np.float32)),
+               jnp.asarray(rng.integers(-128, 127, pcap).astype(np.int8)),
+               jnp.asarray(rng.integers(-2**31, 2**31 - 1, pcap)
+                           .astype(np.int32))]
+    got = {}
+    for path in ("fill", "gather", "pr31"):
+        if path == "pr31":
+            monkeypatch.setattr(J, "_expand", expand_of_pr31)
+        elif case != "fill_unforced" or path == "gather":
+            monkeypatch.setattr(J, "src_path", lambda p, o, _p=path: _p)
+        else:
+            assert J.src_path(pcap, oc) == "fill"
+        r = _joined(data, join_type)
+        assert (r.runs is not None) == (path == "fill")
+        taken = [J.take_probe(r, x) for x in columns]
+        assert all(t.dtype == x.dtype for t, x in zip(taken, columns))
+        got[path] = (tuple(r[:5]), taken)
+    needed = int(got["gather"][0][4])
+    assert (needed > oc) == (case == "overflow")
+    assert (needed == 0) == (case == "no_usable_probe"
+                             and join_type == "inner")
+    _same_arrays(got["gather"], got["pr31"], (case, join_type, "gather"))
+    _same_arrays(got["fill"], got["pr31"], (case, join_type, "fill"))
+
+
+@pytest.mark.parametrize("join_type", JOIN_TYPES)
+def test_gathering_expand_lowers_to_the_text_of_pr31(join_type, monkeypatch):
+    """Where the rule says "gather" (every small join: 1 Ki slots from
+    1 Ki probe rows here, the rule unforced) the program is, byte for
+    byte, the one it was."""
+    from spark_tpu.ops import joining as J
+
+    cap = 1 << 10
+    assert J.src_path(cap, cap) == "gather"
+
+    def program(bk, bvalid, bmask, pk, pvalid, pmask):
+        return tuple(_joined((bk, bvalid, bmask, pk, pvalid, pmask, cap),
+                             join_type))[:5]
+
+    shapes = [jax.ShapeDtypeStruct((cap,), t)
+              for t in (jnp.int64, bool, bool, jnp.int64, bool, bool)]
+    now = jax.jit(program).lower(*shapes).as_text()
+    monkeypatch.setattr(J, "_expand", expand_of_pr31)
+    assert jax.jit(program).lower(*shapes).as_text() == now
+
+
+@pytest.mark.parametrize("pcap,out_cap,path", [
+    (131072, 8 * Mi, "fill"),      # q3 m01, q7 m03, v1 m02: date_dim probes
+    (1024, 32 * Mi, "fill"),       # q89 m02: store probes the fact table
+    (8 * Mi, 8 * Mi, "gather"),    # v1's item and store joins
+    (32 * Mi, 4 * Mi, "gather"),   # q89's item join
+    (4 * Mi, 1 * Mi, "gather"),    # q89's date join
+    (131072, 131072, "gather"),    # a discarded first program's joins
+    (1024, 1024, "gather"),        # a small join, as the tests' are
+    (1024, 64 * 1024, "fill"),
+])
+def test_src_path_is_a_rule_of_two_lengths(pcap, out_cap, path):
+    from spark_tpu.ops.joining import src_path
+
+    assert src_path(pcap, out_cap) == path
+
+
+@pytest.mark.parametrize("planes", [1, 2, 7, 8, 9, 31, 33])
+def test_take_planes_is_a_gather_of_each(planes):
+    """Validity planes ride through a fetch as the bits of uint8 words,
+    eight to a word: each comes back as its own gather would have it, a
+    `None` plane stays `None`, and a lone plane is fetched as it is."""
+    from spark_tpu.ops.joining import take_planes
+
+    rng = np.random.default_rng(planes)
+    cap, oc = 512, 2048
+    side = [jnp.asarray(rng.random(cap) > 0.3) for _ in range(planes)]
+    for at in range(1, planes + 3, 4):
+        side.insert(at, None)
+    idx = jnp.asarray(rng.integers(0, cap, oc).astype(np.int32))
+    fetched = []
+
+    def fetch(word):
+        assert word.shape == (cap,)
+        fetched.append(word.dtype)
+        return jnp.take(word, idx)
+
+    got = take_planes(side, fetch)
+    assert fetched == ([jnp.bool_] if planes == 1
+                       else [jnp.uint8] * -(-planes // 8))
+    assert len(got) == len(side)
+    for plane, g in zip(side, got):
+        if plane is None:
+            assert g is None
+            continue
+        assert g.dtype == jnp.bool_
+        assert np.array_equal(np.asarray(g),
+                              np.asarray(plane)[np.asarray(idx)])
+    assert take_planes([None, None], fetch) == [None, None]
 
 
 def test_hash_partition_counts():

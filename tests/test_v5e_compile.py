@@ -12,6 +12,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from spark_tpu.ops import grouping as G
+from spark_tpu.ops import joining as J
 from spark_tpu.ops import window as W
 
 CAP = 8 << 20      # the slots of q47's `v1` flow (tpcds_sf10_window.dev2)
@@ -82,3 +83,35 @@ def test_window_by_scans_compiles_at_8mi(one_chip):
                      jnp.bool_)
     assert _sorts(text) == [3, 4], _sorts(text)
     assert not _per_slot(text, "scatter")
+
+
+def test_join_filled_by_position_compiles_at_8mi(one_chip):
+    """The date join of q3, q7 and v1 (131 072 probe rows into 8 Mi slots)
+    past its build sort, on the body `src_path` picks there: what a slot
+    has of its probe row comes by scatters of 131 072 scalars and scans, so
+    the only gathers of CAP elements are by the build row (the sorted
+    side's permutation, the key, its validity, one build column with its
+    validity byte), and nothing of CAP elements is sorted."""
+    pcap = 131072
+    assert J.src_path(pcap, CAP) == "fill"
+
+    def body(sorted_hash, perm, bkey, bkey_ok, price, price_ok, pkey, year,
+             lo, counts, pmask):
+        r = J._expand(J.BuildSide(sorted_hash, perm), [bkey], [bkey_ok],
+                      [pkey], [None], pmask, CAP, "inner", pcap, lo, counts)
+        planes = J.take_planes([bkey_ok, price_ok],
+                               lambda w: jnp.take(w, r.build_idx))
+        return (J.take_probe(r, year), jnp.take(price, r.build_idx), planes,
+                r.out_mask, r.needed)
+
+    build = [jax.ShapeDtypeStruct((4 * CAP,), dt, sharding=one_chip)
+             for dt in (jnp.int64, jnp.int32, jnp.int32, jnp.bool_,
+                        jnp.int32, jnp.bool_)]
+    probe = [jax.ShapeDtypeStruct((pcap,), dt, sharding=one_chip)
+             for dt in (jnp.int32, jnp.int32, jnp.int32, jnp.int32,
+                        jnp.bool_)]
+    text = jax.jit(body).lower(*build, *probe).compile().as_text()
+    assert len(_per_slot(text, "gather")) == 5, _per_slot(text, "gather")
+    assert len(_per_slot(text, "scatter")) <= 4
+    assert not [ln for ln in text.splitlines()
+                if " sort(" in ln and f"[{CAP}]" in ln.split(" sort(")[0]]

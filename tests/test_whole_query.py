@@ -240,7 +240,7 @@ def test_join_rank_paths_counted_and_shown(tiers, spark, monkeypatch):
         joins = [m for s, m in zip(rec["scopes"], rec["members"])
                  if s and s.endswith(".HashJoin")]
         assert len(joins) == 2
-        note = f"rank[probe={path},expand={path}]"
+        note = f"rank[probe={path},expand={path}] src=gather"
         assert all(m.endswith(note) for m in joins), joins
         text = rec["kernel"]._kernel.lower(*rec["args"]).as_text(
             debug_info=True)
@@ -250,6 +250,82 @@ def test_join_rank_paths_counted_and_shown(tiers, spark, monkeypatch):
             pd.testing.assert_frame_equal(ref, out, check_dtype=False)
             shown = spark.sql(query).query_execution.explain_string("device")
             assert note in shown, shown
+
+
+def test_join_src_paths_counted_and_shown(tiers, spark, monkeypatch):
+    """Each sorted join asks `ops/joining.src_path` how `_expand` has a
+    probe row's values at the output's slots: the lowering counts the
+    answer, ends the join's members row with it, and the traced body takes
+    the same one. Mini shapes keep the gathers, in the very text `_expand`
+    lowered to before the rule was there; forced to fill they rank nothing
+    in `expand`, carry their validity planes as one word, same rows."""
+    import jax
+    import pandas as pd
+
+    from join_reference import expand_of_pr31
+    from spark_tpu.ops import joining as J
+    from spark_tpu.physical.compile import capture_programs
+    from tpcds_mini import register_tpcds
+
+    register_tpcds(spark)
+    names = ("join.src_fill", "join.src_gather", "join.rank_search",
+             "join.rank_merge")
+
+    def counts():
+        c = spark._metrics.snapshot()["counters"]
+        return {n: c.get(n, 0) for n in names}
+
+    def run(query):
+        before = counts()
+        with capture_programs() as programs:
+            out = spark.sql(query).toArrow().to_pandas()
+        assert programs
+        delta = {n: v - before[n] for n, v in counts().items()}
+        rec = programs[-1]
+        joins = [m for s, m in zip(rec["scopes"], rec["members"])
+                 if s and s.endswith(".HashJoin")]
+        assert len(joins) == 2
+        return out, delta, len(programs), rec, joins
+
+    def lowered(rec, scopes=True):
+        # a function of its own each time: nothing traced before is reused
+        fn = rec["kernel"]._kernel.__wrapped__
+        return jax.jit(lambda *a: fn(*a)).lower(*rec["args"]).as_text(
+            debug_info=scopes)
+
+    # the rule as it stands: two joins a lowering, each gathers, and
+    # `_expand` lowers to what PR 31's did
+    spark.conf.set("spark.tpu.compile.tier", "whole")
+    out, delta, n, rec, joins = run(Q3_SORTED)
+    assert delta == {"join.src_fill": 0, "join.src_gather": 2 * n,
+                     "join.rank_search": 6 * n, "join.rank_merge": 0}
+    note = "rank[probe=search,expand=search] src=gather"
+    assert all(m.endswith(note) for m in joins), joins
+    text = lowered(rec)
+    assert "expand/rank_search" in text and "src_fill" not in text
+    text = lowered(rec, scopes=False)
+    with monkeypatch.context() as m:
+        m.setattr(J, "_expand", expand_of_pr31)
+        assert lowered(rec, scopes=False) == text
+
+    # a month no other test asks for: the programs are built here
+    query = Q3_SORTED.replace("d_moy = 11", "d_moy = 10")
+    spark.conf.set("spark.tpu.compile.tier", "stage")
+    ref = spark.sql(query).toArrow().to_pandas()
+    assert len(ref)
+    spark.conf.set("spark.tpu.compile.tier", "whole")
+    monkeypatch.setattr(J, "src_path", lambda pcap, out_cap: "fill")
+    out, delta, n, rec, joins = run(query)
+    assert delta == {"join.src_fill": 2 * n, "join.src_gather": 0,
+                     "join.rank_search": 4 * n, "join.rank_merge": 0}
+    note = "rank[probe=search,expand=none] src=fill"
+    assert all(m.endswith(note) for m in joins), joins
+    text = lowered(rec)
+    assert "expand/src_fill" in text and "gather/src_fill" in text
+    assert "gather/pack_valid" in text and "expand/rank_" not in text
+    pd.testing.assert_frame_equal(ref, out, check_dtype=False)
+    shown = spark.sql(query).query_execution.explain_string("device")
+    assert note in shown, shown
 
 
 # ---------------------------------------------------------------------------
