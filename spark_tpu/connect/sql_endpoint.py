@@ -10,10 +10,17 @@ Thrift, and `spark_tpu.connect.sql_endpoint.connect()` provides a
 DB-API 2.0 connection/cursor so Python tools (and anything that speaks
 DB-API) can query the engine like any database:
 
-    conn = connect("127.0.0.1", port)
+    conn = connect("127.0.0.1", port, token=token)
     cur = conn.cursor()
     cur.execute("select k, sum(v) from t group by k")
     cur.fetchall()
+
+Authentication: a server started with a `token` runs nothing for a
+connection until its first line is `{"auth": <token>}` with the same
+token (compared in constant time); anything else first is answered with
+a typed UNAUTHENTICATED error and the connection is closed. A server
+without a token binds loopback addresses only. `connect(...,
+token=...)` sends that first line.
 
 Session model (spark_tpu/serve/): each connection gets its OWN cloned
 session (TpuSession.newSession) — SET and temp views are
@@ -33,15 +40,27 @@ queue-timeout rejection, and plan-time HBM admission. A
 (queued/running/rejected, latency percentiles, SLO findings).
 stop() drains gracefully: new statements are rejected with a typed
 SERVER_DRAINING error while in-flight queries finish and flush their
-query profiles."""
+query profiles.
+
+Tracing (obs/tracing.py): every request is an `endpoint.request` span
+(line read to response flushed) on the connection's session's tracer,
+and a result set's way onto the wire an `endpoint.encode` span (Arrow
+table to rows to the JSON line written and flushed; `rows`, `bytes`).
+The server session's counters `endpoint.requests` and
+`endpoint.auth_refused` count the lines served and the connections
+turned away."""
 
 from __future__ import annotations
 
+import hmac
+import ipaddress
 import json
 import socket
 import socketserver
 import threading
 from typing import Any
+
+UNAUTHENTICATED = "UNAUTHENTICATED"
 
 
 def _json_cell(v) -> Any:
@@ -57,16 +76,31 @@ def _json_cell(v) -> Any:
     return v
 
 
+def _is_loopback(host: str) -> bool:
+    if host == "localhost":
+        return True
+    try:
+        return ipaddress.ip_address(host).is_loopback
+    except ValueError:
+        return False
+
+
 class SQLEndpoint:
     """JSON-lines SQL server over a serving session pool (see module
-    docstring: session-per-connection, fair-scheduler pool admission,
-    graceful drain)."""
+    docstring: a token, session-per-connection, fair-scheduler pool
+    admission, graceful drain)."""
 
     def __init__(self, session, host: str = "127.0.0.1", port: int = 0,
-                 service=None):
+                 service=None, token: str | None = None):
         from ..serve.service import QueryService
 
+        if not token and not _is_loopback(host):
+            raise ValueError(
+                f"SQLEndpoint will not bind {host!r} without a token: "
+                "anyone who reaches the port could run SQL (pass token=, "
+                "or bind a loopback address)")
         self.session = session
+        self.token = token or None
         self.service = service if service is not None \
             else QueryService(session)
         outer = self
@@ -76,19 +110,16 @@ class SQLEndpoint:
                 # per-connection session, cloned lazily on the first
                 # statement so a {"session": "shared"} opt-in sent
                 # first binds the connection to the server session
-                state = {"session": None}
-                for line in self.rfile:
-                    line = line.strip()
-                    if not line:
-                        continue
-                    try:
-                        req = json.loads(line)
-                        resp = outer._run(req, state)
-                    except Exception as e:  # protocol-level failure
-                        resp = _error_resp(e)
-                    self.wfile.write(
-                        (json.dumps(resp) + "\n").encode())
-                    self.wfile.flush()
+                state = {"session": None,
+                         "authenticated": outer.token is None}
+                try:
+                    for line in self.rfile:
+                        line = line.strip()
+                        if line and not outer._serve(line, state,
+                                                     self.wfile):
+                            return
+                finally:
+                    outer.service.close_session(state["session"])
 
         class Server(socketserver.ThreadingTCPServer):
             allow_reuse_address = True
@@ -98,15 +129,72 @@ class SQLEndpoint:
         self.host, self.port = self._server.server_address
         self._thread: threading.Thread | None = None
 
+    # -- one line of one connection ---------------------------------------
+    def _serve(self, line: bytes, state: dict, wfile) -> bool:
+        """Answer one request line; False closes the connection."""
+        if not state["authenticated"]:
+            # nothing is parsed further, planned or run for a connection
+            # that has not shown the token
+            if self._authenticates(line):
+                state["authenticated"] = True
+                _send(wfile, {"ok": True, "auth": True})
+                return True
+            self.session._metrics.add("endpoint.auth_refused")
+            _send(wfile, {"error": "the connection's first line must be "
+                                   "{\"auth\": <the server's token>}",
+                          "error_class": UNAUTHENTICATED})
+            return False
+        self.session._metrics.add("endpoint.requests")
+        sess = None
+        try:
+            req = json.loads(line)
+            if req.get("sql") or req.get("session"):
+                sess = self._conn_session(state, req)
+        except Exception as e:  # protocol-level failure, or draining
+            _send(wfile, _error_resp(e))
+            return True
+        tracer = (sess if sess is not None else self.session).tracer
+        with tracer.span("endpoint.request", cat="serve"):
+            try:
+                out = self._run(req, sess)
+                if not isinstance(out, dict):
+                    with tracer.span("endpoint.encode", cat="serve",
+                                     args={"rows": out.num_rows}) as sp:
+                        payload = _encode_table(out)
+                        sp.set_args({"bytes": len(payload)})
+                        wfile.write(payload)
+                        wfile.flush()
+                    return True
+            except Exception as e:
+                out = _error_resp(e)
+            _send(wfile, out)
+        return True
+
+    def _authenticates(self, line: bytes) -> bool:
+        try:
+            offered = json.loads(line).get("auth")
+        except Exception:
+            return False
+        return isinstance(offered, str) and hmac.compare_digest(
+            offered.encode(), self.token.encode())
+
     def _conn_session(self, state: dict, req: dict):
-        if req.get("session") == "shared":
+        if req.get("session") == "shared" \
+                and state["session"] is not self.session:
             # explicit opt-in rebinds the connection (legacy behavior)
+            self.service.close_session(state["session"])
             state["session"] = self.service.open_session("shared")
         if state["session"] is None:
             state["session"] = self.service.open_session()
         return state["session"]
 
-    def _run(self, req: dict, state: dict) -> dict:
+    def _run(self, req: dict, sess):
+        """The response to one request: a dict, or the Arrow table of a
+        statement's result for `_serve` to encode."""
+        if "auth" in req:
+            # a client's token sent again, or to a server that asks for
+            # none: acknowledged, nothing to do
+            return {"ok": True, "auth": True}
         if req.get("status"):
             return {"status": self.service.status()}
         if req.get("metrics"):
@@ -133,26 +221,13 @@ class SQLEndpoint:
         sql = req.get("sql")
         if not sql:
             if req.get("session"):
-                # session-mode-only request: bind and acknowledge
-                try:
-                    self._conn_session(state, req)
-                    return {"ok": True, "session": req.get("session")}
-                except Exception as e:
-                    return _error_resp(e)
+                # session-mode-only request: bound by _serve, acknowledged
+                return {"ok": True, "session": req.get("session")}
             return {"error": "request must carry a 'sql' field"}
-        try:
-            sess = self._conn_session(state, req)
-            t = self.service.execute_sql(sess, sql)
-            if t is None or not hasattr(t, "column_names"):
-                return {"columns": [], "types": [], "rows": []}
-            cols = t.column_names
-            types = [str(c.type) for c in t.columns]
-            pylists = [c.to_pylist() for c in t.columns]
-            rows = [[_json_cell(v) for v in row]
-                    for row in zip(*pylists)] if cols else []
-            return {"columns": cols, "types": types, "rows": rows}
-        except Exception as e:
-            return _error_resp(e)
+        t = self.service.execute_sql(sess, sql)
+        if t is None or not hasattr(t, "column_names"):
+            return {"columns": [], "types": [], "rows": []}
+        return t
 
     def start(self) -> "SQLEndpoint":
         # race-lint: ignore[bare-submit] — HTTP accept loop for the whole
@@ -177,6 +252,23 @@ class SQLEndpoint:
         self._server.shutdown()
         self._server.server_close()
         return drained
+
+
+def _send(wfile, resp: dict) -> None:
+    wfile.write((json.dumps(resp) + "\n").encode())
+    wfile.flush()
+
+
+def _encode_table(t) -> bytes:
+    """A result set as one response line: rows as JSON arrays, decimals
+    as strings, each column's Arrow type beside its name."""
+    cols = t.column_names
+    pylists = [c.to_pylist() for c in t.columns]
+    rows = [[_json_cell(v) for v in row]
+            for row in zip(*pylists)] if cols else []
+    return (json.dumps({"columns": cols,
+                        "types": [str(c.type) for c in t.columns],
+                        "rows": rows}) + "\n").encode()
 
 
 def _error_resp(e: Exception) -> dict:
@@ -277,11 +369,24 @@ def _sql_quote(v) -> str:
 
 
 class Connection:
-    def __init__(self, host: str, port: int, timeout: float = 60.0):
+    """`timeout` is the socket's: it bounds the wait for one response,
+    so a client whose first statement compiles a program on the server
+    passes one that outlasts the compile."""
+
+    def __init__(self, host: str, port: int, timeout: float = 60.0,
+                 token: str | None = None):
         self._sock = socket.create_connection((host, port),
                                               timeout=timeout)
         self._file = self._sock.makefile("rwb")
         self._lock = threading.Lock()
+        if token is not None:
+            try:
+                resp = self._request({"auth": token})
+                if resp.get("error"):
+                    raise Error(resp["error"], resp.get("error_class"))
+            except BaseException:
+                self.close()
+                raise
 
     def _request(self, req: dict) -> dict:
         with self._lock:
@@ -339,5 +444,5 @@ class Connection:
 
 
 def connect(host: str = "127.0.0.1", port: int = 10000,
-            timeout: float = 60.0) -> Connection:
-    return Connection(host, port, timeout)
+            timeout: float = 60.0, token: str | None = None) -> Connection:
+    return Connection(host, port, timeout, token)

@@ -5,12 +5,17 @@ session-per-connection isolation, fair-scheduler pools, and graceful
 drain — SIGTERM (and Ctrl-C) stop accepting statements immediately
 (typed SERVER_DRAINING errors on the wire), let in-flight queries
 finish and flush their query profiles, then exit.
+
+Every connection authenticates with the server's token (`--token`, or
+one generated here): the first stdout line is {"host", "port", "token"},
+and a client passes it to `sql_endpoint.connect(host, port, token=...)`.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import secrets
 import signal
 import threading
 
@@ -19,6 +24,9 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="sparktpu-sqlserver")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--port", type=int, default=10000)
+    p.add_argument("--token", default=None,
+                   help="the secret every connection's first line must "
+                        "carry; generated if omitted")
     p.add_argument("--conf", action="append", default=[], metavar="K=V")
     p.add_argument("--cache-dir", default=None, metavar="DIR",
                    help="enable the persistent caches rooted here "
@@ -51,8 +59,10 @@ def main(argv=None) -> int:
     if args.session_mode:
         conf.setdefault("spark.tpu.serve.sessionMode", args.session_mode)
     session = TpuSession("sqlserver", conf)
-    ep = SQLEndpoint(session, host=args.host, port=args.port).start()
-    print(json.dumps({"host": ep.host, "port": ep.port}), flush=True)
+    ep = SQLEndpoint(session, host=args.host, port=args.port,
+                     token=args.token or secrets.token_hex(16)).start()
+    print(json.dumps({"host": ep.host, "port": ep.port,
+                      "token": ep.token}), flush=True)
 
     stop_evt = threading.Event()
 

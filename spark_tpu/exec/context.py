@@ -25,6 +25,12 @@ class Metrics:
         with self._lock:
             self.counters[name] += v
 
+    def peak(self, name: str, v: int) -> None:
+        """A counter that keeps the most it was shown."""
+        with self._lock:
+            if v > self.counters[name]:
+                self.counters[name] = v
+
     def time(self, name: str):
         return _Timer(self, name)
 

@@ -210,18 +210,36 @@ class DAGScheduler:
         self.max_attempts = max_attempts
         self.bus = listener_bus
 
+    # KernelCache counters that the query's own ledger (obs/metrics.py
+    # QueryKernelLedger) counts too
+    _LEDGER_KEYS = (("kernel_cache.launches", "launches"),
+                    ("kernel_cache.misses", "compiles"),
+                    ("kernel_cache.compile_ms", "compile_ms"),
+                    ("kernel_cache.disk_hit_compiles", "disk_hit_compiles"))
+
     def run(self, plan: PhysicalPlan) -> list:
         from ..physical.compile import GLOBAL_KERNEL_CACHE
 
+        ledger = getattr(self.ctx, "kernel_ledger", None)
         kc_before = GLOBAL_KERNEL_CACHE.counters()
+        led_before = ledger.snapshot() if ledger is not None else None
         try:
             return self._run(plan)
         finally:
             # per-run kernel dispatch/cache deltas into the query metrics
             # (satellite of SQLMetrics: dispatch-count regressions surface
             # in listener snapshots and BENCH output)
-            for k, v in GLOBAL_KERNEL_CACHE.counters().items():
-                d = round(v - kc_before.get(k, 0))
+            deltas = {k: v - kc_before.get(k, 0)
+                      for k, v in GLOBAL_KERNEL_CACHE.counters().items()}
+            if ledger is not None:
+                # the process's counters also move with every other
+                # query in flight (a server's other tenants): what the
+                # query's ledger counts is taken from it
+                led_after = ledger.snapshot()
+                for k, name in self._LEDGER_KEYS:
+                    deltas[k] = led_after[name] - led_before[name]
+            for k, v in deltas.items():
+                d = round(v)
                 if d:
                     self.ctx.metrics.add(f"kernel.{k.split('.', 1)[1]}", d)
 

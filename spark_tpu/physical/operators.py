@@ -11,6 +11,7 @@ fused kernel; XLA plays the role of WholeStageCodegen.
 
 from __future__ import annotations
 
+import threading
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -235,6 +236,11 @@ class ScanExec(PhysicalPlan):
 
 
 _LOCAL_TABLE_CACHE: "weakref.WeakKeyDictionary" = None
+# two sessions of one process (a server's tenants) may scan one table
+# for the first time at the same moment: the lock makes the table's
+# entry once, and the entry's own lock copies a set of columns to the
+# device once
+_LOCAL_TABLE_LOCK = threading.Lock()
 
 
 class LocalTableScanExec(PhysicalPlan):
@@ -254,8 +260,6 @@ class LocalTableScanExec(PhysicalPlan):
     def execute(self, ctx: ExecContext) -> list[Partition]:
         import weakref
 
-        from ..columnar.arrow import table_to_batches
-
         global _LOCAL_TABLE_CACHE
         if _LOCAL_TABLE_CACHE is None:
             _LOCAL_TABLE_CACHE = {}
@@ -266,33 +270,40 @@ class LocalTableScanExec(PhysicalPlan):
         # collected cycle), so a hit must prove the entry still belongs to
         # THIS table — a stale entry here once served another test's batches.
         tid = id(self.table)
-        entry = _LOCAL_TABLE_CACHE.get(tid)
-        if entry is not None:
-            ref = entry.get("ref")
-            if ref is None or ref() is not self.table:
-                entry = None
-        if entry is None:
-            try:
-                ref = weakref.ref(self.table,
-                                  lambda _r, t=tid:
-                                  _LOCAL_TABLE_CACHE.pop(t, None))
-            except TypeError:
-                ref = None
-            entry = {"ref": ref, "batches": {}}
-            _LOCAL_TABLE_CACHE[tid] = entry
+        with _LOCAL_TABLE_LOCK:
+            entry = _LOCAL_TABLE_CACHE.get(tid)
+            if entry is not None:
+                ref = entry.get("ref")
+                if ref is None or ref() is not self.table:
+                    entry = None
+            if entry is None:
+                try:
+                    ref = weakref.ref(self.table,
+                                      lambda _r, t=tid:
+                                      _LOCAL_TABLE_CACHE.pop(t, None))
+                except TypeError:
+                    ref = None
+                entry = {"ref": ref, "batches": {},
+                         "lock": threading.Lock()}
+                _LOCAL_TABLE_CACHE[tid] = entry
 
         names = tuple(a.name for a in self.attrs)
         key = (names, ctx.conf.batch_capacity)
-        hit = entry["batches"].get(key)
-        if hit is not None:
-            return [hit]
-        tbl = self.table.select(list(names)) if self.table.num_columns \
-            else self.table
-        # the table's planes go to the device here, once: host
-        # conversion, padding and the enqueue of each copy (the copies
-        # themselves are asynchronous; the first program waits for them)
+        with entry["lock"]:
+            hit = entry["batches"].get(key)
+            if hit is None:
+                hit = entry["batches"][key] = self._to_device(ctx, names)
+        return [hit]
+
+    def _to_device(self, ctx: ExecContext, names: tuple) -> list:
+        """The table's planes go to the device here, once: host
+        conversion, padding and the enqueue of each copy (the copies
+        themselves are asynchronous; the first program waits for them)."""
+        from ..columnar.arrow import table_to_batches
         from ..obs.tracing import span_here
 
+        tbl = self.table.select(list(names)) if self.table.num_columns \
+            else self.table
         with span_here("ingest.h2d", cat="operator") as sp:
             batches = list(table_to_batches(tbl, ctx.conf.batch_capacity,
                                             attrs_schema(self.attrs)))
@@ -301,8 +312,7 @@ class LocalTableScanExec(PhysicalPlan):
                 "planes": sum(1 + sum(1 + (c.validity is not None)
                                       for c in b.columns)
                               for b in batches)})
-        entry["batches"][key] = batches
-        return [batches]
+        return batches
 
 
 class RangeExec(PhysicalPlan):

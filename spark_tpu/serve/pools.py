@@ -107,7 +107,7 @@ def pool_configs(conf) -> dict[str, PoolConfig]:
 
 class _Ticket:
     __slots__ = ("pool", "hbm", "seq", "granted", "released", "enq_t",
-                 "grant_t", "query_id")
+                 "grant_t", "query_id", "running_at_grant")
 
     def __init__(self, pool: str, hbm: int, seq: int):
         self.pool = pool
@@ -118,6 +118,7 @@ class _Ticket:
         self.enq_t = time.perf_counter()
         self.grant_t = 0.0
         self.query_id = None
+        self.running_at_grant = 0   # slots held, this one included
 
 
 class _PoolState:
@@ -343,6 +344,7 @@ class FairScheduler:
             st.hbm_inflight += t.hbm
             st.hist_wait.observe((t.grant_t - t.enq_t) * 1000)
             self._running_total += 1
+            t.running_at_grant = self._running_total
             self._hbm_total += t.hbm
             self._vclock = max(self._vclock, st.served / st.cfg.weight)
             granted_any = True

@@ -28,6 +28,12 @@ One QueryService wraps one long-lived "server" TpuSession:
   * `drain()` starts graceful shutdown: new statements raise
     ServerDraining, in-flight (and already-queued) queries finish and
     flush their query profiles, then the call returns.
+
+Tracing (obs/tracing.py), on the statement's session: span
+`serve.admission` (submit to grant; `pool`) and span `serve.execute`
+(grant to release; `pool`, `query`); counters `serve.granted`,
+`serve.rejected_full`, `serve.rejected_timeout` and `serve.running_peak`
+(the most statements that held a slot at one of this session's grants).
 """
 
 from __future__ import annotations
@@ -37,7 +43,7 @@ import threading
 from ..config import (
     MEMORY_BUDGET, SERVE_DRAIN_TIMEOUT, SERVE_POOL, SERVE_SESSION_MODE,
 )
-from ..errors import ServerDraining
+from ..errors import AdmissionTimeout, PoolQueueFull, ServerDraining
 from .pools import FairScheduler, pool_configs
 
 __all__ = ["QueryService"]
@@ -49,6 +55,7 @@ class QueryService:
         self.scheduler = FairScheduler(session.conf)
         self._lock = threading.Lock()
         self.sessions_opened = 0
+        self._open: list = []       # one entry a connection, until closed
         self.drain_snapshot = None
         # service metrics plane (spark.tpu.metrics.export): wire the
         # scrape sources over this service's pools/session and start
@@ -69,11 +76,27 @@ class QueryService:
         if self.scheduler.draining:
             raise ServerDraining()
         mode = mode or str(self.session.conf.get(SERVE_SESSION_MODE))
+        opened = self.session if mode == "shared" \
+            else self.session.newSession()
         with self._lock:
             self.sessions_opened += 1
-        if mode == "shared":
-            return self.session
-        return self.session.newSession()
+            self._open.append(opened)
+        return opened
+
+    def close_session(self, session) -> None:
+        """A connection's end: its session leaves `sessions()`. Nothing
+        is stopped: a clone owns no service, and the shared session is
+        the server's."""
+        with self._lock:
+            for i, s in enumerate(self._open):
+                if s is session:
+                    del self._open[i]
+                    return
+
+    def sessions(self) -> list:
+        """The sessions opened and not closed, each once."""
+        with self._lock:
+            return list({id(s): s for s in self._open}.values())
 
     # -- execution --------------------------------------------------------
     def _predicted_hbm(self, qe, conf) -> int:
@@ -108,10 +131,17 @@ class QueryService:
         hbm = self._predicted_hbm(qe, conf)
         if pool is None:
             pool = str(conf.get(SERVE_POOL) or "default")
+        metrics, tracer = session._metrics, session.tracer
         try:
-            ticket = self.scheduler.submit(pool, hbm=hbm)
-            self.scheduler.wait(ticket, timeout=timeout)
+            with tracer.span("serve.admission", cat="serve",
+                             args={"pool": pool}):
+                ticket = self.scheduler.submit(pool, hbm=hbm)
+                self.scheduler.wait(ticket, timeout=timeout)
         except Exception as admission_err:
+            if isinstance(admission_err, PoolQueueFull):
+                metrics.add("serve.rejected_full")
+            elif isinstance(admission_err, AdmissionTimeout):
+                metrics.add("serve.rejected_timeout")
             # black box: an admission rejection (queue full / timeout)
             # bundles the serving/metrics state that explains it
             # (rate-limited; never masks the rejection itself)
@@ -124,22 +154,27 @@ class QueryService:
                 except Exception:
                     pass
             raise
-        try:
-            table = df.toArrow()
-            ctx = getattr(qe, "_last_ctx", None)
-            if ctx is not None:
-                self.scheduler.note_query(
-                    ticket, getattr(ctx, "query_id", None))
-            return table
-        finally:
-            # an SLO breach at release becomes an obs.slo finding on
-            # the query's live record — the list EXPLAIN ANALYZE and
-            # pool status already surface
-            finding = self.scheduler.release(ticket)
-            if finding is not None:
-                live = getattr(self.session, "live_obs", None)
-                if live is not None:
-                    live.add_finding(ticket.query_id, finding)
+        metrics.add("serve.granted")
+        metrics.peak("serve.running_peak", ticket.running_at_grant)
+        with tracer.span("serve.execute", cat="serve",
+                         args={"pool": pool}) as span:
+            try:
+                table = df.toArrow()
+                ctx = getattr(qe, "_last_ctx", None)
+                if ctx is not None:
+                    qid = getattr(ctx, "query_id", None)
+                    self.scheduler.note_query(ticket, qid)
+                    span.set_args({"query": qid})
+                return table
+            finally:
+                # an SLO breach at release becomes an obs.slo finding on
+                # the query's live record — the list EXPLAIN ANALYZE and
+                # pool status already surface
+                finding = self.scheduler.release(ticket)
+                if finding is not None:
+                    live = getattr(self.session, "live_obs", None)
+                    if live is not None:
+                        live.add_finding(ticket.query_id, finding)
 
     def execute_sql(self, session, sql: str):
         """One SQL statement for one session. Commands and other
