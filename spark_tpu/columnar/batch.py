@@ -209,6 +209,20 @@ class StringDict:
         return md, ra, rb
 
 
+def eq_key_dtype(dt: DataType) -> np.dtype:
+    """The dtype of a column's equality key (`Column.eq_keys`; the whole
+    tier's `eqs_of`), from its type: a string compares by its 64-bit value
+    hash (any dictionary-encoded type is given 64 bits here: the stage tier
+    compares a nested value's int32 codes, and nothing should read those
+    as values), a boolean as int32, anything else as it lies on the device.
+    What `ops/joining.key_path` asks before either side's array is there."""
+    if dict_encoded(dt):
+        return np.dtype(np.int64)
+    if isinstance(dt, BooleanType):
+        return np.dtype(np.int32)
+    return np.dtype(dt.device_dtype)
+
+
 def canon_value(v):
     """Hashable canonical form for a dictionary value. Dict items are
     SORTED: maps are unordered (two insertion orders are the same map);

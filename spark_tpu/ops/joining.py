@@ -12,6 +12,21 @@ false-positives are eliminated by gathering and comparing the actual key
 columns (so 64-bit hashing is a grouping accelerator, not a correctness
 assumption).
 
+Where the join has one key and both sides hold it as an integer of at most
+32 bits (`key_path`: every TPC-DS surrogate key, a date, a boolean), the
+hash is a detour and the index is *exact*: the build side is sorted on the
+key itself, widened to int64, with the two sentinels outside the 32-bit
+range. The two ranks then bound just the live build rows whose key is the
+probe's, in their original order (the sort is stable), so `_expand` has
+nothing to verify: it is handed no keys, and the gathers of the build key
+and its validity by the build row (and of the probe key and its validity by
+`src`, where `src` is gathered) leave the program, with the hash's three
+`mix64` on each side. The sorted keys are also what the dense join's span
+wants to know (`observe_span`: first, last, two neighbours equal), so no
+second sort of the build keys is made for it. A collision of the hash left
+a dead slot that the check cleared; the exact index has none, and is
+otherwise the hash's result slot for slot.
+
 `rank_sorted` is `jnp.searchsorted` with two bodies. The binary search is a
 loop of ceil(log2(len(a)+1)) dependent steps, each one scalar gather per
 query, and a TPU gathers scalars one at a time: 8 Mi queries into 131 072
@@ -89,6 +104,23 @@ MERGE_FIXED_S = 0.05
 SCATTER_S = 80e-9
 SCAN_S = 1e-9
 
+# What the exact index (`key_path`) takes out of a program, a run, on a v5e
+# (PERF.md §5-§6, PR 34: `explain("device")` at the cells' size). With the
+# 32 Mi-slot fact table as the build side and 8 Mi output slots (q3's and
+# q7's date join): `expand` 463 -> 176 ms (the key's gather by the build row
+# 149.6, its validity's 134.5; what stays is the permutation's gather, 175),
+# `span_observe` 118 -> 1.07 ms (a `jnp.sort` of the keys against reads of
+# the index), `build_sort` 117.6 -> 117.2 (the hash was never the cost: the
+# sort is). At 32 Mi output slots (q89's store join) the two gathers were
+# 587 and 270 ms. The search of 131 072 probe keys in the 32 Mi index took
+# 262, 328, 257 and 302 ms in power2's four programs where it took 252, 293,
+# 329 and 255 on hashes: the same scalar gathers land nearer each other on
+# sorted keys, 10-47 ms dearer in three programs and 72 cheaper in one.
+# An index in the key's own 32 bits is not built (PERF.md §7 (c): sorting
+# int32 + row number at 32 Mi takes 81.6 ms where int64 takes 123.4, the two
+# ranks of 131 072 queries 99.0 ms where 190.8, and the sort compiles in
+# 21 s where 45; its sentinels would have to be the live count's, not values).
+
 
 def rank_path(n_sorted: int, n_queries: int) -> str:
     """`"merge"` or `"search"`: the cheaper body of `rank_sorted` for
@@ -105,6 +137,23 @@ def src_path(pcap: int, out_cap: int) -> str:
     owns the slot, both lengths known at trace time."""
     fill = pcap * SCATTER_S + out_cap * SCAN_S
     return "fill" if fill < out_cap * GATHER_S else "gather"
+
+
+def key_path(build_key_cols: Sequence, probe_key_cols: Sequence) -> str:
+    """`"exact"` or `"hash"`: what the build index is sorted on, from the
+    two sides' equality keys (arrays or dtypes), known at trace time. One
+    pair of integers of at most 32 bits is its own index: widened to int64
+    no live key can equal a sentinel. Everything else keeps the hash: many
+    keys, a 64-bit integer (it could), a string (its equality key is a
+    64-bit hash already), a float. A caller asks once and hands the answer
+    to `build_index` and `probe_join` both."""
+    if len(build_key_cols) != 1 or len(probe_key_cols) != 1:
+        return "hash"
+    for col in (build_key_cols[0], probe_key_cols[0]):
+        dt = jnp.dtype(getattr(col, "dtype", col))
+        if not jnp.issubdtype(dt, jnp.integer) or dt.itemsize > 4:
+            return "hash"
+    return "exact"
 
 
 def rank_sorted(a: jnp.ndarray, v: jnp.ndarray, side: str,
@@ -153,7 +202,8 @@ def _merge_ranks(a, v, sides) -> list:
 
 
 class BuildSide(NamedTuple):
-    """Build-side index: key-hash-sorted."""
+    """Build-side index: sorted by the key's hash or, where `key_path` says
+    "exact", by the key itself."""
 
     sorted_hash: jnp.ndarray  # int64[Bcap], inactive rows pushed to +inf
     perm: jnp.ndarray         # int32[Bcap] original row index per sorted slot
@@ -164,11 +214,24 @@ class BuildSide(NamedTuple):
 # traced these bodies says which of them the device is in; inside them
 # `rank_<path>`, `src_fill` and `pack_valid` say which body ran.
 
+def _index_keys(key_cols, key_valids, key: str) -> jnp.ndarray:
+    """What one side's rows are ranked by: the keys' combined hash, or on
+    an exact index the one key, widened."""
+    if key == "hash":
+        return hash_columns(key_cols, list(key_valids))
+    if key != "exact" or key_path(key_cols, key_cols) != "exact":
+        raise ValueError(f"no {key} index over keys of "
+                         f"{[str(c.dtype) for c in key_cols]}")
+    return key_cols[0].astype(jnp.int64)
+
+
 @jax.named_scope("build_sort")
 def build_index(key_cols: Sequence[jnp.ndarray],
                 key_valids: Sequence[jnp.ndarray | None],
-                row_mask: jnp.ndarray) -> BuildSide:
-    h = hash_columns(key_cols, list(key_valids))
+                row_mask: jnp.ndarray, key: str = "hash") -> BuildSide:
+    """`key`: `key_path`'s answer for the join, the same `probe_join` is
+    given."""
+    h = _index_keys(key_cols, key_valids, key)
     # null join keys never match (SQL equi-join); drop them from the index
     usable = row_mask
     for v in key_valids:
@@ -178,6 +241,21 @@ def build_index(key_cols: Sequence[jnp.ndarray],
     cap = row_mask.shape[0]
     sh, perm = lax.sort((hh, lax.iota(jnp.int32, cap)), num_keys=1, is_stable=True)
     return BuildSide(sh, perm)
+
+
+def observe_span(build: BuildSide) -> tuple:
+    """(lo, hi, dup) of an exact index's live keys: the first, the last,
+    and whether two neighbours are equal (int32), read off the sorted keys.
+    An index with no live key gives (2**62, -2**62, 0), as a `min` and a
+    `max` over nothing live would."""
+    sh = build.sorted_hash
+    big = jnp.int64(1) << 62
+    live = sh != I64_MAX
+    n = jnp.sum(live, dtype=jnp.int32)
+    lo = jnp.where(n > 0, sh[0], big)
+    hi = jnp.where(n > 0, sh[jnp.maximum(n - 1, 0)], -big)
+    dup = jnp.any((sh[1:] == sh[:-1]) & live[1:])
+    return lo, hi, dup.astype(jnp.int32)
 
 
 class SrcRuns(NamedTuple):
@@ -270,17 +348,18 @@ def probe_join(build: BuildSide,
                probe_key_valids: Sequence[jnp.ndarray | None],
                probe_mask: jnp.ndarray,
                out_capacity: int,
-               join_type: str = "inner") -> JoinResult:
+               join_type: str = "inner", key: str = "hash") -> JoinResult:
     """join_type: inner | left_outer | left_semi | left_anti.
 
     'left' always refers to the probe side; the planner flips sides for
     right joins (as the reference's planner does for build-side selection,
-    sqlx/SparkStrategies.scala join selection)."""
+    sqlx/SparkStrategies.scala join selection). `key` is what `build` was
+    indexed on (`key_path`)."""
     pcap = probe_mask.shape[0]
     oc = out_capacity
 
     with jax.named_scope("probe"):
-        ph = hash_columns(probe_key_cols, list(probe_key_valids))
+        ph = _index_keys(probe_key_cols, probe_key_valids, key)
         usable = probe_mask
         for v in probe_key_valids:
             if v is not None:
@@ -289,6 +368,10 @@ def probe_join(build: BuildSide,
 
         lo, hi = rank_sorted(build.sorted_hash, ph, "both")
         counts = jnp.where(usable, hi - lo, 0)
+    if key == "exact":
+        # the ranges hold the probe's key and nothing else: no key to check
+        return _expand(build, (), (), (), (), probe_mask, oc, join_type,
+                       pcap, lo, counts)
     return _expand(build, build_key_cols, build_key_valids, probe_key_cols,
                    probe_key_valids, probe_mask, oc, join_type, pcap, lo,
                    counts)
@@ -318,7 +401,8 @@ def _expand(build, build_key_cols, build_key_valids, probe_key_cols,
             probe_key_valids, probe_mask, oc, join_type, pcap, lo,
             counts) -> JoinResult:
     """probe_join's second loop: the match ranges flattened into the
-    static-capacity output, each pair verified on the true keys."""
+    static-capacity output, each pair verified on the true keys. An exact
+    index (`key_path`) hands in no keys: its ranges are the matches."""
 
     # --- verify hash ranges by comparing true keys, count real matches ----
     # For semi/anti we must not rely on hash ranges alone. Verified counts

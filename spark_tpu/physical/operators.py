@@ -1419,12 +1419,18 @@ class HashJoinExec(PhysicalPlan):
             lp = self._bloom_filter_probe(lp, build, bkeys, bkey_valids,
                                           lpos, ctx)
 
+        from ..columnar.batch import eq_key_dtype
+
+        # asked once: the index and every probe of it take the same answer
+        kpath = J.key_path(bkey_eqs, [eq_key_dtype(k.dtype)
+                                      for k in self.left_keys])
         bi_key = ("join_build", build.capacity, len(bkeys),
                   tuple(str(k.dtype) for k in bkey_eqs),
-                  tuple(v is not None for v in bkey_valids))
+                  tuple(v is not None for v in bkey_valids), kpath)
 
         def build_bi():
-            return jax.jit(lambda eqs, valids, mask: J.build_index(eqs, valids, mask))
+            return jax.jit(lambda eqs, valids, mask: J.build_index(
+                eqs, valids, mask, kpath))
 
         bi_kernel = GLOBAL_KERNEL_CACHE.get_or_build(bi_key, build_bi)
         bindex = bi_kernel(bkey_eqs, bkey_valids, build.row_mask)
@@ -1433,7 +1439,7 @@ class HashJoinExec(PhysicalPlan):
         for pb in (lp or [ColumnarBatch.empty(lschema)]):
             out_batches.append(
                 self._probe_batch(pb, build, bindex, bkey_eqs, bkey_valids,
-                                  lpos, ctx, probe_pipe))
+                                  lpos, ctx, kpath, probe_pipe))
         if self.join_type == "full_outer":
             out_batches.append(
                 self._unmatched_build_rows(lp, build, lschema, ctx))
@@ -1587,7 +1593,7 @@ class HashJoinExec(PhysicalPlan):
         return out
 
     def _probe_batch(self, pb: ColumnarBatch, build: ColumnarBatch, bindex,
-                     bkey_eqs, bkey_valids, lpos, ctx,
+                     bkey_eqs, bkey_valids, lpos, ctx, kpath: str,
                      probe_pipe=None) -> ColumnarBatch:
         import jax
 
@@ -1597,7 +1603,7 @@ class HashJoinExec(PhysicalPlan):
         jt = self.join_type if self.join_type != "full_outer" else "left_outer"
         if probe_pipe is not None:
             pb, r = self._fused_probe(pb, bindex, bkey_eqs, bkey_valids,
-                                      ctx, jt)
+                                      ctx, jt, kpath)
         else:
             pkeys = [pb.columns[lpos[k.expr_id]] for k in self.left_keys]
             pkey_eqs = [c.eq_keys() for c in pkeys]
@@ -1608,14 +1614,14 @@ class HashJoinExec(PhysicalPlan):
                 key = ("join_probe", jt, pb.capacity, build.capacity, out_cap,
                        len(pkey_eqs), tuple(str(k.dtype) for k in pkey_eqs),
                        tuple(v is not None for v in pkey_valids),
-                       tuple(v is not None for v in bkey_valids))
+                       tuple(v is not None for v in bkey_valids), kpath)
 
                 def build_kernel(oc=out_cap):
                     def kernel(bidx_sorted, bidx_perm, beqs, bvalids, peqs,
                                pvalids, pmask):
                         bi = J.BuildSide(bidx_sorted, bidx_perm)
                         return J.probe_join(bi, beqs, bvalids, peqs, pvalids,
-                                            pmask, oc, jt)
+                                            pmask, oc, jt, kpath)
 
                     return jax.jit(kernel)
 
@@ -1639,7 +1645,7 @@ class HashJoinExec(PhysicalPlan):
         return ColumnarBatch(schema, cols, r.out_mask, num_rows=None)
 
     def _fused_probe(self, pb: ColumnarBatch, bindex, bkey_eqs, bkey_valids,
-                     ctx, jt):
+                     ctx, jt, kpath: str):
         """Whole-stage fused probe: the probe-side filter/project pipeline
         traces INSIDE the probe kernel — one dispatch computes the projected
         columns, derives the join keys, and probes the build index (the
@@ -1689,7 +1695,7 @@ class HashJoinExec(PhysicalPlan):
                                             for v in bkey_valids),
                     tuple(sorted(dict_pos)),
                     tuple(int(l.shape[0])  # tpulint: ignore[host-sync]
-                          for l in kluts))
+                          for l in kluts), kpath)
 
             def build_kernel(oc=out_cap):
                 def kernel(bidx_sorted, bidx_perm, beqs, bvalids, datas,
@@ -1712,7 +1718,7 @@ class HashJoinExec(PhysicalPlan):
                         pvalids.append(out_valids[i])
                     bi = J.BuildSide(bidx_sorted, bidx_perm)
                     r = J.probe_join(bi, beqs, bvalids, peqs, pvalids,
-                                     mask, oc, jt)
+                                     mask, oc, jt, kpath)
                     return r, out_datas, out_valids, mask
 
                 return jax.jit(kernel)
@@ -1934,10 +1940,11 @@ class HashJoinExec(PhysicalPlan):
         bkey_valids = [c.validity for c in bkeys]
 
         # swap: probe = build side, build = probe side; left_anti
-        pi = J.build_index(pkey_eqs, pkey_valids, probe_all.row_mask)
+        kpath = J.key_path(pkey_eqs, bkey_eqs)
+        pi = J.build_index(pkey_eqs, pkey_valids, probe_all.row_mask, kpath)
         out_cap = build.capacity
         r = J.probe_join(pi, pkey_eqs, pkey_valids, bkey_eqs, bkey_valids,
-                         build.row_mask, out_cap, "left_anti")
+                         build.row_mask, out_cap, "left_anti", kpath)
         build_rows = gather_batch(build, r.probe_idx, r.out_mask)
         schema = attrs_schema(self.output)
         nl = len(self._left_attrs)

@@ -1374,7 +1374,12 @@ class _ProgramBuilder:
         if dense is not None:
             return self._join_dense(node, probe, build, metas, lk, rk,
                                     dense, semi_anti)
-        self._note_ranks(node, probe.cap, build.cap, out_cap)
+        from ..columnar.batch import eq_key_dtype
+        from ..ops.joining import key_path
+
+        key = key_path([eq_key_dtype(build.metas[i].dtype) for i in rk],
+                       [eq_key_dtype(probe.metas[i].dtype) for i in lk])
+        self._note_ranks(node, probe.cap, build.cap, out_cap, key)
 
         def eqs_of(d, v, idx, luts, bools, args):
             eqs, valids = [], []
@@ -1399,27 +1404,31 @@ class _ProgramBuilder:
             bd, bv, bm = _build.emit(args, needed)
             beqs, bvalids = eqs_of(bd, bv, rk, rk_luts, rk_bool, args)
             peqs, pvalids = eqs_of(pd, pv, lk, lk_luts, lk_bool, args)
-            bi_ = J.build_index(beqs, bvalids, bm)
+            bi_ = J.build_index(beqs, bvalids, bm, key)
             r = J.probe_join(bi_, beqs, bvalids, peqs, pvalids, pm, _oc,
-                             jt)
+                             jt, key)
             needed.append(r.needed)
             if eligible:
                 # observe the build-key span + uniqueness so the NEXT
                 # same-fingerprint run (via the warm-start manifest)
                 # compiles the dense direct-address variant directly
                 with jax.named_scope("span_observe"):
-                    bk = beqs[0].astype(jnp.int64)
-                    blive = bm if bvalids[0] is None \
-                        else (bm & bvalids[0])
-                    big = jnp.int64(1) << 62
-                    lo_o = jnp.min(jnp.where(blive, bk, big))
-                    hi_o = jnp.max(jnp.where(blive, bk, -big))
-                    sk = jnp.sort(jnp.where(blive, bk, big))
-                    dup = jnp.any((sk[1:] == sk[:-1])
-                                  & (sk[:-1] != big)) \
-                        if sk.shape[0] > 1 else jnp.asarray(False)
-                    needed.spans.append(
-                        (lo_o, hi_o, dup.astype(jnp.int32)))
+                    if key == "exact":
+                        # the index is sorted on the keys themselves
+                        needed.spans.append(J.observe_span(bi_))
+                    else:
+                        bk = beqs[0].astype(jnp.int64)
+                        blive = bm if bvalids[0] is None \
+                            else (bm & bvalids[0])
+                        big = jnp.int64(1) << 62
+                        lo_o = jnp.min(jnp.where(blive, bk, big))
+                        hi_o = jnp.max(jnp.where(blive, bk, -big))
+                        sk = jnp.sort(jnp.where(blive, bk, big))
+                        dup = jnp.any((sk[1:] == sk[:-1])
+                                      & (sk[:-1] != big)) \
+                            if sk.shape[0] > 1 else jnp.asarray(False)
+                        needed.spans.append(
+                            (lo_o, hi_o, dup.astype(jnp.int32)))
             with jax.named_scope("gather"):
                 # probe columns by the join's `src` on the body the join
                 # took; each side's validity planes as one byte a fetch
@@ -1439,12 +1448,14 @@ class _ProgramBuilder:
 
         return _Lowered(metas, out_cap, emit)
 
-    def _note_ranks(self, node, pcap: int, bcap: int, out_cap: int) -> None:
+    def _note_ranks(self, node, pcap: int, bcap: int, out_cap: int,
+                    key: str) -> None:
         """Which body `ops/joining.rank_sorted` takes at each of the sorted
         join's three call sites (probe_join's two ranks of `pcap` hashes in
-        `bcap`, _expand's rank of `out_cap` slots in `pcap` offsets), and
-        how `_expand` has a probe row's values at the output's slots
-        (`src_path`; the fill ranks nothing): the same rules the trace
+        `bcap`, _expand's rank of `out_cap` slots in `pcap` offsets), how
+        `_expand` has a probe row's values at the output's slots
+        (`src_path`; the fill ranks nothing), and what the build side is
+        indexed on (`key_path`'s answer, `key`): the same rules the trace
         asks, counted, and shown in the join's row."""
         from ..ops.joining import rank_path, src_path
 
@@ -1455,8 +1466,9 @@ class _ProgramBuilder:
         if src != "fill":
             self.ctx.metrics.add(f"join.rank_{expand_path}")
         self.ctx.metrics.add(f"join.src_{src}")
+        self.ctx.metrics.add(f"join.key_{key}")
         self._note(node, f"rank[probe={probe_path},expand={expand_path}] "
-                         f"src={src}")
+                         f"src={src} key={key}")
 
     def _note(self, node, note: str) -> None:
         """`note` at the end of the node's members row (100 characters)."""

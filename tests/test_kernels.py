@@ -7,7 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from join_reference import expand_of_pr31
+from join_reference import (
+    build_index_of_pr33, expand_of_pr31, join_oracle, probe_join_of_pr33,
+)
 from spark_tpu.ops import (
     SortKeySpec, build_index, cross_join, group_rows, group_output_mask,
     hash_columns, hash_partition, limit_mask, mix64, partition_ids,
@@ -365,6 +367,218 @@ def test_src_path_is_a_rule_of_two_lengths(pcap, out_cap, path):
     from spark_tpu.ops.joining import src_path
 
     assert src_path(pcap, out_cap) == path
+
+
+I32 = np.iinfo(np.int32)
+
+
+@pytest.mark.parametrize("build,probe,path", [
+    ([jnp.int32], [jnp.int32], "exact"),     # a surrogate key, a date
+    ([jnp.int8], [jnp.int8], "exact"),
+    ([jnp.int16], [jnp.int16], "exact"),
+    ([jnp.int16], [jnp.int32], "exact"),     # widened, both fit
+    ([jnp.uint32], [jnp.int32], "exact"),
+    ([jnp.int64], [jnp.int64], "hash"),      # a live key could be a sentinel
+    ([jnp.int32], [jnp.int64], "hash"),      # mixed widths, either way
+    ([jnp.int64], [jnp.int32], "hash"),
+    ([jnp.uint64], [jnp.uint64], "hash"),
+    ([jnp.float32], [jnp.float32], "hash"),
+    ([jnp.float64], [jnp.float64], "hash"),
+    ([jnp.bool_], [jnp.bool_], "hash"),      # callers hand a boolean as int32
+    ([jnp.int32, jnp.int32], [jnp.int32, jnp.int32], "hash"),
+    ([jnp.int32, jnp.int64], [jnp.int32, jnp.int64], "hash"),
+    ([], [], "hash"),
+])
+def test_key_path_is_a_rule_of_the_keys(build, probe, path):
+    """One pair of integers of at most 32 bits indexes itself; the rule
+    reads dtypes, or arrays' (a string's equality key is the int64 of its
+    LUT hash, so it is the int64 case)."""
+    from spark_tpu.ops.joining import key_path
+
+    assert key_path(build, probe) == path
+    assert key_path([jnp.zeros(4, dt) for dt in build],
+                    [np.dtype(dt) for dt in probe]) == path
+
+
+def _key_case(name):
+    """(build key, its validity, build mask, probe key, its validity, probe
+    mask, out capacity), the keys integers of at most 32 bits."""
+    rng = np.random.default_rng(34)
+    bcap, pcap, oc, dt = 96, 128, 1 << 11, np.int32
+    if name == "fan_out":
+        bk, pk = rng.integers(0, 5, bcap), rng.integers(-1, 6, pcap)
+    elif name == "extremes":
+        pool = np.array([I32.min, I32.min + 1, -1, 0, 1, I32.max - 1,
+                         I32.max])
+        bk, pk = rng.choice(pool, bcap), rng.choice(pool, pcap)
+    elif name == "absent":             # no probe key is on the build side
+        bk, pk = rng.integers(0, 40, bcap) * 2, rng.integers(0, 40, pcap) * 2 + 1
+    elif name == "int8":
+        dt = np.int8
+        bk, pk = rng.integers(-128, 128, bcap), rng.integers(-128, 128, pcap)
+    elif name == "int16_into_int32":   # each side its own width
+        dt = np.int16
+        bk, pk = rng.integers(-300, 300, bcap), rng.integers(-300, 300, pcap)
+    elif name == "one_build_row":
+        bcap = 1
+        bk, pk = np.array([7]), rng.integers(5, 9, pcap)
+    else:
+        bk, pk = rng.integers(0, 30, bcap), rng.integers(-3, 33, pcap)
+    bvalid = rng.random(bcap) > 0.15
+    pvalid = rng.random(pcap) > 0.15
+    bmask = rng.random(bcap) > 0.2
+    pmask = rng.random(pcap) > 0.1
+    if name == "empty_build":
+        bmask[:] = False
+    elif name == "all_null_build":
+        bvalid[:] = False
+    elif name == "one_build_row":
+        bvalid[:], bmask[:] = True, True
+    pk = pk.astype(np.int32 if name == "int16_into_int32" else dt)
+    return tuple(jnp.asarray(x) for x in (bk.astype(dt), bvalid, bmask, pk,
+                                          pvalid, pmask)) + (oc,)
+
+
+KEY_CASES = ["as_is", "fan_out", "extremes", "absent", "empty_build",
+             "all_null_build", "one_build_row", "int8", "int16_into_int32"]
+
+
+@pytest.mark.parametrize("src", ["gather", "fill"])
+@pytest.mark.parametrize("join_type", JOIN_TYPES)
+@pytest.mark.parametrize("case", KEY_CASES)
+def test_probe_join_same_on_both_key_paths(case, join_type, src, monkeypatch):
+    """An index on the key itself and one on its hash (`key_path`, forced
+    either way) give the same join on both bodies of `src_path`: `needed`,
+    the live slots, each slot's probe row and whether it has a match are
+    equal everywhere, the build row wherever there is one (a slot with
+    none points at whatever row lies where its key would), and both are
+    what loops over the rows give."""
+    from spark_tpu.ops import joining as J
+
+    monkeypatch.setattr(J, "src_path", lambda p, o: src)
+    bk, bvalid, bmask, pk, pvalid, pmask, oc = data = _key_case(case)
+    assert J.key_path([bk], [pk]) == "exact"
+    got = {}
+    for key in ("exact", "hash"):
+        bi = build_index([bk], [bvalid], bmask, key)
+        got[key] = probe_join(bi, [bk], [bvalid], [pk], [pvalid], pmask, oc,
+                              join_type, key)
+        assert (got[key].runs is not None) == (src == "fill")
+    exact, hashed = got["exact"], got["hash"]
+    _same_arrays((exact.probe_idx, exact.matched, exact.out_mask,
+                  exact.needed, exact.runs),
+                 (hashed.probe_idx, hashed.matched, hashed.out_mask,
+                  hashed.needed, hashed.runs), (case, join_type, src))
+    paired = np.asarray(exact.matched)
+    assert np.array_equal(np.asarray(exact.build_idx)[paired],
+                          np.asarray(hashed.build_idx)[paired])
+    live = np.asarray(exact.out_mask)
+    assert not (live & ~paired).any() or join_type != "inner"
+    rows = [(int(p), int(b) if m and join_type in ("inner", "left_outer")
+             else -1)
+            for p, b, m in zip(np.asarray(exact.probe_idx)[live],
+                               np.asarray(exact.build_idx)[live],
+                               paired[live])]
+    assert rows == join_oracle(*data[:6], join_type), (case, join_type)
+    assert int(exact.needed) <= oc
+
+
+@pytest.mark.parametrize("dtype", [jnp.int64, jnp.float32, jnp.uint64])
+def test_exact_index_refuses_what_the_rule_would(dtype):
+    """A caller that hands `build_index` or `probe_join` "exact" for keys
+    the rule sends to the hash is told so at trace time."""
+    k = jnp.zeros(8, dtype)
+    mask = jnp.ones(8, bool)
+    with pytest.raises(ValueError, match="no exact index"):
+        build_index([k], [None], mask, "exact")
+    bi = build_index([k], [None], mask)
+    with pytest.raises(ValueError, match="no exact index"):
+        probe_join(bi, [k], [None], [k], [None], mask, 16, "inner", "exact")
+
+
+@pytest.mark.parametrize("keys", ["int64", "two_int32", "float64",
+                                  "int32_forced"])
+@pytest.mark.parametrize("join_type", JOIN_TYPES)
+def test_hash_keyed_join_lowers_to_the_text_of_pr33(join_type, keys):
+    """A join the rule sends to the hash (a 64-bit key, two keys, a float;
+    or a 32-bit key with "hash" handed in) is, byte for byte, the program
+    it was before the rule: index, probe and expansion."""
+    from spark_tpu.ops import joining as J
+
+    cap = 1 << 10
+    dts = {"int64": [jnp.int64], "two_int32": [jnp.int32, jnp.int32],
+           "float64": [jnp.float64], "int32_forced": [jnp.int32]}[keys]
+    n = len(dts)
+
+    def program(index, probe, *cols):
+        bk, bv, pk, pv = (list(cols[at * n:(at + 1) * n]) for at in range(4))
+        bmask, pmask = cols[4 * n:]
+        key = "hash" if keys == "int32_forced" else J.key_path(bk, pk)
+        bi = index(bk, bv, bmask, key)
+        return tuple(probe(bi, bk, bv, pk, pv, pmask, cap, join_type,
+                           key))[:5]
+
+    shapes = [jax.ShapeDtypeStruct((cap,), t)
+              for t in dts + [bool] * n + dts + [bool] * n + [bool, bool]]
+    now = jax.jit(lambda *c: program(build_index, probe_join, *c)).lower(
+        *shapes).as_text()
+    then = jax.jit(lambda *c: program(
+        build_index_of_pr33, probe_join_of_pr33, *c)).lower(*shapes).as_text()
+    assert "stablehlo.sort" in now and then == now
+
+
+def _span_case(name):
+    rng = np.random.default_rng(5)
+    cap = 64
+    keys = rng.permutation(np.arange(-20, 44))
+    valid, mask = rng.random(cap) > 0.2, rng.random(cap) > 0.2
+    if name == "repeated":
+        keys = rng.integers(0, 9, cap)
+    elif name == "repeat_is_dead":     # the only repeat is of a dead row
+        keys[5], mask[5] = keys[9], False
+        mask[9] = valid[9] = True
+    elif name == "repeat_is_null":
+        keys[5], valid[5] = keys[9], False
+        mask[9] = valid[9] = True
+    elif name == "all_dead":
+        mask[:] = False
+    elif name == "all_null":
+        valid[:] = False
+    elif name == "one_row":
+        keys, valid, mask = keys[:1], np.array([True]), np.array([True])
+    elif name == "one_live_of_many":
+        mask[:] = False
+        mask[17] = valid[17] = True
+    elif name == "extremes":
+        keys[:4] = [I32.min, I32.max, I32.min, 0]
+        valid[:4] = mask[:4] = True
+    elif name == "no_validity":
+        valid = None
+    else:
+        assert name == "unique"
+    return keys.astype(np.int32), valid, mask
+
+
+@pytest.mark.parametrize("case", [
+    "unique", "repeated", "repeat_is_dead", "repeat_is_null", "all_dead",
+    "all_null", "one_row", "one_live_of_many", "extremes", "no_validity"])
+def test_observe_span_reads_the_sorted_keys(case):
+    """First live key, last live key, any live key twice: off an exact
+    index, what a `min`, a `max` and a sort of the live keys give (and
+    2**62, -2**62, 0 where no key is live, as those would)."""
+    from spark_tpu.ops.joining import observe_span
+
+    keys, valid, mask = _span_case(case)
+    bi = build_index([jnp.asarray(keys)],
+                     [None if valid is None else jnp.asarray(valid)],
+                     jnp.asarray(mask), "exact")
+    lo, hi, dup = jax.jit(observe_span)(bi)
+    assert (lo.dtype, hi.dtype, dup.dtype) == (jnp.int64, jnp.int64,
+                                               jnp.int32)
+    live = keys[mask if valid is None else mask & valid].astype(np.int64)
+    want = (live.min(), live.max(), int(len(np.unique(live)) < len(live))) \
+        if len(live) else (1 << 62, -(1 << 62), 0)
+    assert (int(lo), int(hi), int(dup)) == want, case
 
 
 @pytest.mark.parametrize("planes", [1, 2, 7, 8, 9, 31, 33])

@@ -85,20 +85,42 @@ def test_window_by_scans_compiles_at_8mi(one_chip):
     assert not _per_slot(text, "scatter")
 
 
-def test_join_filled_by_position_compiles_at_8mi(one_chip):
+def test_exact_index_and_its_span_compile_at_8mi(one_chip):
+    """An int32 key's own index (`key_path`) and the span read off it
+    (`observe_span`), at v1's capacity: the one sort of the build side
+    (the widened key's two words and the row number), and no gather or
+    scatter of CAP elements; the observation is elementwise reads and
+    reductions of what that sort left."""
+    def body(key, key_ok, mask):
+        assert J.key_path([key], [key]) == "exact"
+        index = J.build_index([key], [key_ok], mask, "exact")
+        return index, J.observe_span(index)
+
+    text = _compiled(body, one_chip, jnp.int32, jnp.bool_, jnp.bool_)
+    assert _sorts(text) == [3], _sorts(text)
+    assert not _per_slot(text, "gather") and not _per_slot(text, "scatter")
+
+
+@pytest.mark.parametrize("key,gathers", [("hash", 5), ("exact", 3)])
+def test_join_filled_by_position_compiles_at_8mi(one_chip, key, gathers):
     """The date join of q3, q7 and v1 (131 072 probe rows into 8 Mi slots)
     past its build sort, on the body `src_path` picks there: what a slot
     has of its probe row comes by scatters of 131 072 scalars and scans, so
     the only gathers of CAP elements are by the build row (the sorted
     side's permutation, the key, its validity, one build column with its
-    validity byte), and nothing of CAP elements is sorted."""
+    validity byte), and nothing of CAP elements is sorted. On an exact
+    index (`key_path`: the keys are int32) `_expand` is handed no keys,
+    and the key and its validity are not gathered."""
     pcap = 131072
     assert J.src_path(pcap, CAP) == "fill"
 
     def body(sorted_hash, perm, bkey, bkey_ok, price, price_ok, pkey, year,
              lo, counts, pmask):
-        r = J._expand(J.BuildSide(sorted_hash, perm), [bkey], [bkey_ok],
-                      [pkey], [None], pmask, CAP, "inner", pcap, lo, counts)
+        assert J.key_path([bkey], [pkey]) == "exact"
+        keys = ([bkey], [bkey_ok], [pkey], [None]) if key == "hash" \
+            else ((), (), (), ())
+        r = J._expand(J.BuildSide(sorted_hash, perm), *keys, pmask, CAP,
+                      "inner", pcap, lo, counts)
         planes = J.take_planes([bkey_ok, price_ok],
                                lambda w: jnp.take(w, r.build_idx))
         return (J.take_probe(r, year), jnp.take(price, r.build_idx), planes,
@@ -111,7 +133,8 @@ def test_join_filled_by_position_compiles_at_8mi(one_chip):
              for dt in (jnp.int32, jnp.int32, jnp.int32, jnp.int32,
                         jnp.bool_)]
     text = jax.jit(body).lower(*build, *probe).compile().as_text()
-    assert len(_per_slot(text, "gather")) == 5, _per_slot(text, "gather")
+    assert len(_per_slot(text, "gather")) == gathers, \
+        _per_slot(text, "gather")
     assert len(_per_slot(text, "scatter")) <= 4
     assert not [ln for ln in text.splitlines()
                 if " sort(" in ln and f"[{CAP}]" in ln.split(" sort(")[0]]

@@ -240,7 +240,7 @@ def test_join_rank_paths_counted_and_shown(tiers, spark, monkeypatch):
         joins = [m for s, m in zip(rec["scopes"], rec["members"])
                  if s and s.endswith(".HashJoin")]
         assert len(joins) == 2
-        note = f"rank[probe={path},expand={path}] src=gather"
+        note = f"rank[probe={path},expand={path}] src=gather key=exact"
         assert all(m.endswith(note) for m in joins), joins
         text = rec["kernel"]._kernel.lower(*rec["args"]).as_text(
             debug_info=True)
@@ -299,7 +299,7 @@ def test_join_src_paths_counted_and_shown(tiers, spark, monkeypatch):
     out, delta, n, rec, joins = run(Q3_SORTED)
     assert delta == {"join.src_fill": 0, "join.src_gather": 2 * n,
                      "join.rank_search": 6 * n, "join.rank_merge": 0}
-    note = "rank[probe=search,expand=search] src=gather"
+    note = "rank[probe=search,expand=search] src=gather key=exact"
     assert all(m.endswith(note) for m in joins), joins
     text = lowered(rec)
     assert "expand/rank_search" in text and "src_fill" not in text
@@ -318,7 +318,7 @@ def test_join_src_paths_counted_and_shown(tiers, spark, monkeypatch):
     out, delta, n, rec, joins = run(query)
     assert delta == {"join.src_fill": 2 * n, "join.src_gather": 0,
                      "join.rank_search": 4 * n, "join.rank_merge": 0}
-    note = "rank[probe=search,expand=none] src=fill"
+    note = "rank[probe=search,expand=none] src=fill key=exact"
     assert all(m.endswith(note) for m in joins), joins
     text = lowered(rec)
     assert "expand/src_fill" in text and "gather/src_fill" in text
@@ -326,6 +326,105 @@ def test_join_src_paths_counted_and_shown(tiers, spark, monkeypatch):
     pd.testing.assert_frame_equal(ref, out, check_dtype=False)
     shown = spark.sql(query).query_execution.explain_string("device")
     assert note in shown, shown
+
+
+def test_join_key_paths_counted_and_shown(tiers, spark, monkeypatch):
+    """Each sorted join asks `ops/joining.key_path` what its build side is
+    indexed on: the lowering counts the answer and ends the join's members
+    row with it. One 32-bit integer key is its own index: against the same
+    join sent to the hash, the program has one `sort` less (the span's
+    observation reads the index) and no gather of a key or of a key's
+    validity, same rows. Two keys, or one 64-bit key, keep the hash, in
+    the very text they lowered to before the rule was there."""
+    import jax
+    import pandas as pd
+
+    from join_reference import build_index_of_pr33, probe_join_of_pr33
+    from spark_tpu.ops import joining as J
+    from spark_tpu.physical.compile import capture_programs
+
+    rng = np.random.default_rng(34)
+    n, dims = 4000, 40
+    k = rng.integers(-2, dims + 2, n)
+    spark.createDataFrame(pa.table({
+        "k": pa.array(k.astype(np.int32), mask=rng.random(n) < 0.05),
+        "k2": (k % 7).astype(np.int32), "w": k.astype(np.int64),
+        "v": rng.integers(-50, 100, n),
+    })).createOrReplaceTempView("kp_fact")
+    d = np.arange(dims)
+    spark.createDataFrame(pa.table({
+        "dk": pa.array(d.astype(np.int32), mask=d == 3),
+        "dk2": (d % 7).astype(np.int32), "dw": d.astype(np.int64),
+        "label": [f"lab{i % 5}" for i in d],
+    })).createOrReplaceTempView("kp_dim")
+    shape = ("select label, sum(v) sv, count(*) c from kp_fact join kp_dim "
+             "on {on} where v > {v} group by label order by label")
+    names = ("join.key_exact", "join.key_hash")
+
+    def counts():
+        c = spark._metrics.snapshot()["counters"]
+        return {x: c.get(x, 0) for x in names}
+
+    def run(on, v=10):
+        query = shape.format(on=on, v=v)
+        spark.conf.set("spark.tpu.compile.tier", "stage")
+        ref = spark.sql(query).toArrow().to_pandas()
+        assert len(ref) == 5
+        spark.conf.set("spark.tpu.compile.tier", "whole")
+        before = counts()
+        with capture_programs() as programs:
+            out = spark.sql(query).toArrow().to_pandas()
+        assert programs
+        pd.testing.assert_frame_equal(ref, out, check_dtype=False)
+        delta = {x: c - before[x] for x, c in counts().items()}
+        rec = programs[-1]
+        row, = [m for sc, m in zip(rec["scopes"], rec["members"])
+                if sc and sc.endswith(".HashJoin")]
+        return query, delta, len(programs), rec, row
+
+    def lowered(rec, scopes=True):
+        # a function of its own each time: nothing traced before is reused
+        fn = rec["kernel"]._kernel.__wrapped__
+        return jax.jit(lambda *a: fn(*a)).lower(*rec["args"]).as_text(
+            debug_info=scopes)
+
+    def instructions(text, op):
+        # a `jnp.take` lowers to a call of a private `_take`, one body for
+        # every call of one signature: the calls are the gathers
+        return sum(op in ln.split(" loc(")[0] for ln in text.splitlines())
+
+    # one int32 key: exact; the same join sent to the hash is the parent's
+    query, delta, progs, rec, row = run("k = dk")
+    assert delta == {"join.key_exact": progs, "join.key_hash": 0}
+    assert row.endswith(" src=gather key=exact"), row
+    exact = lowered(rec)
+    shown = spark.sql(query).query_execution.explain_string("device")
+    assert "src=gather key=exact" in shown, shown
+    with monkeypatch.context() as m:
+        m.setattr(J, "key_path", lambda build, probe: "hash")
+        _, delta, progs, rec, row = run("k = dk", v=11)   # lowered anew
+        assert delta == {"join.key_exact": 0, "join.key_hash": progs}
+        assert row.endswith(" src=gather key=hash"), row
+        hashed = lowered(rec)
+    assert "span_observe" in exact and "span_observe" in hashed
+    assert instructions(exact, "stablehlo.sort") == \
+        instructions(hashed, "stablehlo.sort") - 1
+    # the build key and its validity by the build row, the probe key and
+    # its validity by `src`
+    assert instructions(exact, "call @_take") == \
+        instructions(hashed, "call @_take") - 4
+
+    # two keys, and one 64-bit key (whose span is observed as it was)
+    for on, observed in (("k = dk and k2 = dk2", False), ("w = dw", True)):
+        _, delta, progs, rec, row = run(on)
+        assert delta == {"join.key_exact": 0, "join.key_hash": progs}
+        assert row.endswith(" src=gather key=hash"), row
+        assert ("span_observe" in lowered(rec)) == observed
+        text = lowered(rec, scopes=False)
+        with monkeypatch.context() as m:
+            m.setattr(J, "build_index", build_index_of_pr33)
+            m.setattr(J, "probe_join", probe_join_of_pr33)
+            assert lowered(rec, scopes=False) == text
 
 
 # ---------------------------------------------------------------------------
