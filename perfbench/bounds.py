@@ -14,6 +14,12 @@ as its result line gave them. For every metric but `setup_s`:
   step of 0.005, never under 0.01; a metric that would need more than 0.1
   is not steady yet, and no bound is set wider than this gives.
 
+A cell measured later is a further file, with two sets of its own
+(`python3 perfbench/bounds.py <first> <later>...`): the same rule on its
+runs, and a metric's bound is the widest that any recorded cell gives it
+(`fit`). So a cell's runs can widen a bound and never tighten another
+cell's.
+
 `setup_s` stands at 0.1: the check judges it by its median alone. Beside
 each bound the two readings the driver holds it to are printed (each set's
 interquartile spread by `statistics.quantiles(n=4)`, its farthest run left
@@ -60,6 +66,13 @@ def bound_from(spreads: list) -> float:
     return b
 
 
+def fit(judged: list) -> dict:
+    """Every metric's bound over the recorded cells (`judge_file`'s
+    results): the widest that any of them gives it."""
+    return {name: max(file[name]["bound"] for file in judged)
+            for name in judged[0]}
+
+
 def judge(name: str, first: list, second: list, better: str) -> dict:
     """One metric from its two sets of values."""
     spreads = [spread(first), spread(second)]
@@ -98,7 +111,16 @@ def judge_file(path: str) -> dict:
 
 
 def main(argv) -> int:
-    for name, j in judge_file(argv[1]).items():
+    judged = [judge_file(path) for path in argv[1:]]
+    for path, file in zip(argv[1:], judged):
+        print(path)
+        report(file)
+    print("bounds:", json.dumps(fit(judged)))
+    return 0
+
+
+def report(judged: dict) -> None:
+    for name, j in judged.items():
         print(f"{name}: bound {j['bound']}; spreads "
               f"{j['spreads'][0]:.5f} {j['spreads'][1]:.5f}; medians "
               f"{j['medians'][0]:.6g} {j['medians'][1]:.6g}, the second "
@@ -111,7 +133,6 @@ def main(argv) -> int:
               f"(at least {j['bound'] / 8:.5f})"
               f"{' TOO TIGHT' if j['too_tight'] else ''}"
               f"{' TOO LOOSE' if j['too_loose'] else ''}")
-    return 0
 
 
 if __name__ == "__main__":

@@ -1,11 +1,14 @@
 """Vectorised TPC-DS data from a seed: numpy columns, Arrow tables.
 
 A configuration's `tables` lists each table with its row count and the
-columns kept; the module under `tables/` of the table's name makes it.
-Every draw is from a stream keyed by (seed, table, name), so the data of
-one column does not depend on which others a configuration keeps. The
-program sees only the Arrow tables; the references read the numpy columns
-the Arrow tables were built from.
+columns kept; the module under `tables/` of the table's name makes it,
+and a column that module does not make is made by the one module under
+`columns/<table>/` that lists it in its `MAKES` (`more_columns`), so a
+later PR widens a table by adding a file. Every draw is from a stream
+keyed by (seed, table, name), so the data of one column does not depend
+on which others a configuration keeps, and a widened table is the old
+one plus columns. The program sees only the Arrow tables; the references
+read the numpy columns the Arrow tables were built from.
 
 Which seed a stream takes is the configuration's `seeding`: the streams it
 lists `from_the_run_seed` take `--seed`, every other stream takes its
@@ -17,6 +20,7 @@ work), and other measures (the answers).
 from __future__ import annotations
 
 import importlib
+import os
 import zlib
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
@@ -26,6 +30,8 @@ import pyarrow as pa
 
 
 ARROW_THREADS = 4
+COLUMNS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "columns")
 
 
 @dataclass
@@ -127,9 +133,44 @@ def generate(config: dict, seed: int, scale: float = 1.0) -> dict:
         cols = mod.generate(seeds, sizes[name], list(spec["columns"]), sizes)
         missing = [c for c in spec["columns"] if c not in cols]
         if missing:
-            raise KeyError(f"{name}: generator made no column {missing}")
+            cols = more_columns(name, seeds, sizes, missing, cols)
         data[name] = {c: cols[c] for c in spec["columns"]}
     return data
+
+
+def column_modules(table: str) -> dict:
+    """{name: module} of `columns/<table>/`, in name order."""
+    folder = os.path.join(COLUMNS_DIR, table)
+    if not os.path.isdir(folder):
+        return {}
+    names = sorted(f[:-3] for f in os.listdir(folder)
+                   if f.endswith(".py") and not f.startswith("__"))
+    return {n: importlib.import_module(f"perfbench.gen.columns.{table}.{n}")
+            for n in names}
+
+
+def more_columns(table, seeds, sizes, missing, made) -> dict:
+    """`made`, the columns the table's own module made, plus the
+    `missing` ones, each from the one module under `columns/<table>/`
+    whose `MAKES` lists it: `generate(seeds, rows, columns, sizes, made)`,
+    `made` being the table's columns so far, the earlier modules' (by
+    name) among them."""
+    mods = column_modules(table)
+    for c in missing:
+        by = [n for n, m in mods.items() if c in m.MAKES]
+        if len(by) != 1:
+            raise KeyError(
+                f"{table}.{c}: perfbench/gen/tables/{table}.py does not "
+                f"make it, and of the modules under "
+                f"{os.path.join(COLUMNS_DIR, table)} {by or 'none'} list "
+                "it in MAKES: exactly one has to")
+    made = dict(made)
+    for mod in mods.values():
+        ask = [c for c in missing if c in mod.MAKES]
+        if ask:
+            got = mod.generate(seeds, sizes[table], ask, sizes, made)
+            made.update({c: got[c] for c in ask})
+    return made
 
 
 def arrow_tables(data: dict) -> dict:

@@ -11,8 +11,10 @@ generator once the backend was up (`setup_seconds`): what a user of the
 engine pays, who has their tables. The window: each stream of the traffic
 file runs its list of queries in its fixed order, one after the other (a
 closed loop: the only kind there is), in whole rounds (`stream_loop` has
-the rule); the window is from the first submit to the last completion,
-and every query started counts. After the window: read the device's
+the rule), a stream's first submit as many seconds after the window
+opens as the traffic file's `start_offsets_s` says (none: all at once);
+the window is from the first submit to the last completion, and every
+query started counts. After the window: read the device's
 peak, stop the program, compute the plain numpy references and compare
 every execution's rows with them.
 
@@ -130,7 +132,8 @@ def announced_tier(session, text: str) -> str:
 # ---------------------------------------------------------------------------
 
 def stream_loop(index, client, queries, texts, seconds, gate, annotate,
-                records, at_most=None, clock=time.perf_counter):
+                records, at_most=None, clock=time.perf_counter,
+                offset=0.0, sleep=time.sleep):
     """One stream's part of the window, in whole rounds. A round is the
     stream's whole list. The first round always starts; another starts
     only if the time passed plus the length of the round just finished
@@ -138,9 +141,14 @@ def stream_loop(index, client, queries, texts, seconds, gate, annotate,
     `rounds_at_most`; a round that is started is finished. So every
     query of the list has the same count in every window, and a round a
     little shorter or longer changes the count by a whole round or not
-    at all. (`clock` is the tests' alone.)"""
+    at all. A stream with an `offset` submits its first query that many
+    seconds after the window opened (tenants do not arrive in the same
+    millisecond; the window's clock does not wait for it). (`clock` and
+    `sleep` are the tests' alone.)"""
     gate.wait()
     t0 = gate.t0
+    if offset > clock() - t0:
+        sleep(offset - (clock() - t0))
     rounds = 0
     while True:
         t_round = clock()
@@ -168,16 +176,18 @@ class Gate(threading.Event):
         self.set()
 
 
-def run_window(clients, streams, texts, seconds, at_most, annotate) -> list:
+def run_window(clients, streams, texts, seconds, at_most, annotate,
+               offsets=None) -> list:
     """The first stream runs in the caller's thread, the one that warmed
     up (a window in a new thread had a slow first round: PR 28); every
     other stream has a thread of its own."""
     records: list = []
     gate = Gate()
+    offsets = offsets or [0.0] * len(streams)
 
     def loop(i):
         stream_loop(i, clients[i], streams[i], texts, seconds, gate,
-                    annotate, records, at_most)
+                    annotate, records, at_most, offset=float(offsets[i]))
 
     threads = [threading.Thread(target=loop, name=f"pb-stream-{i}",
                                 args=(i,), daemon=True)
@@ -360,7 +370,8 @@ def run(args, break_path=None) -> dict:
                   "hidden": hidden_moved(entry.sessions())}
         mark("window opens")
         records = run_window(clients, streams, texts, args.seconds,
-                             traffic.get("rounds_at_most"), annotate)
+                             traffic.get("rounds_at_most"), annotate,
+                             traffic.get("start_offsets_s"))
         after = {"counters": engine_counters(),
                  "hidden": hidden_moved(entry.sessions())}
         if tracing:
@@ -376,7 +387,9 @@ def run(args, break_path=None) -> dict:
             entry.stop()
         session.stop()
 
-    fact_rows = len(data["store_sales"]["ss_item_sk"].values)
+    # the rate counts the rows of the configuration's first fact table
+    first_fact = data[config["fact_tables"][0]]
+    fact_rows = len(next(iter(first_fact.values())).values)
     values = window_values(records, fact_rows)
     values["setup_s"] = setup_seconds(marks)
     window_s, latencies = values["window_s"], values["latencies"]

@@ -3,6 +3,7 @@ every name and unit is of the allowed characters, every cell finds its
 configuration, traffic, queries and references, and every per-layer
 metric has a reader that declares what BENCHMARK.json says of it."""
 
+import importlib
 import json
 import os
 import re
@@ -113,18 +114,21 @@ def test_config_entry_and_file(config):
         assert not key.endswith(("_dim", "_rank"))
     assert body["guarantees"] and body["assumed"]
     # no table is made that no query of the configuration reads; a
-    # dimension has the columns read and no others; the fact table is
-    # whole, as a deployment holds it
+    # dimension has the columns read and no others; each table the file
+    # lists under `fact_tables` is whole, as a deployment holds it: every
+    # column its generator module declares, as many as are published
     from perfbench import reference
-    from perfbench.gen.tables import store_sales
     read = {}
     for q in body["query_templates"]:
         for table, cols in reference.load(q).READS.items():
             read.setdefault(table, set()).update(cols)
-    read["store_sales"] = set(store_sales.COLUMNS)
+    assert body["fact_tables"] and set(body["fact_tables"]) <= set(read)
+    for table in body["fact_tables"]:
+        whole = importlib.import_module(
+            f"perfbench.gen.tables.{table}").COLUMNS
+        read[table] = set(whole)
+        assert len(whole) == body["published"][f"{table}_columns"], table
     assert {t["name"]: set(t["columns"]) for t in body["tables"]} == read
-    assert len(store_sales.COLUMNS) \
-        == body["published"]["store_sales_columns"]
     files = [c["file"] for c in BENCH["configs"]]
     assert len(files) == len(set(files))
     sources = [c["source"] for c in BENCH["configs"]]
@@ -138,7 +142,11 @@ def test_cell_finds_everything_by_name(cell):
     assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
     assert cell["chips"] in (1, 4) and LINE.match(cell["why"])
     loaded = spec.cell(cell["name"])
-    assert set(loaded["traffic"]) - {"rounds_at_most"} == {"why", "streams"}
+    traffic = loaded["traffic"]
+    assert set(traffic) - {"rounds_at_most", "start_offsets_s"} \
+        == {"why", "streams"}
+    offsets = traffic.get("start_offsets_s", [0.0] * len(traffic["streams"]))
+    assert len(offsets) == len(traffic["streams"]) and min(offsets) == 0.0
     names = [m["name"] for m in loaded["end_to_end"]]
     assert "setup_s" in names and len(names) >= 2
     assert loaded["per_layer"]
@@ -158,8 +166,8 @@ def test_cell_finds_everything_by_name(cell):
 
 
 def test_every_file_of_the_benchmark_serves_a_cell():
-    """No entry, traffic mix, query, reference, configuration or reader
-    without a cell in BENCHMARK.json that uses it."""
+    """No entry, traffic mix, query, reference, configuration, reader or
+    column module without a cell in BENCHMARK.json that uses it."""
     cells = [spec.cell(w["name"]) for w in BENCH["workloads"]]
     used = {"entries": {c["config"]["entry"] for c in cells},
             "traffic": {w["traffic"] for w in BENCH["workloads"]},
@@ -173,6 +181,21 @@ def test_every_file_of_the_benchmark_serves_a_cell():
                 for f in os.listdir(os.path.join(REPO, "perfbench", folder))
                 if not f.startswith("__")}
         assert have == names, folder
+    # a column module makes a column some configuration keeps of its
+    # table, and no column is made by two of them
+    from perfbench import gen
+    kept = {}
+    for c in cells:
+        for t in c["config"]["tables"]:
+            kept.setdefault(t["name"], set()).update(t["columns"])
+    for table in sorted(os.listdir(gen.COLUMNS_DIR)):
+        if table.startswith("__"):
+            continue
+        made = []
+        for name, mod in gen.column_modules(table).items():
+            assert set(mod.MAKES) & kept.get(table, set()), (table, name)
+            made += mod.MAKES
+        assert len(made) == len(set(made)), (table, sorted(made))
 
 
 def test_peaks_hold_only_what_a_reader_reads():

@@ -5,6 +5,7 @@ tests/tpcds on the same tiny tables — while the float32 control does not
 pass the comparison that decides `correct`."""
 
 import copy
+import importlib
 import os
 import sys
 from decimal import Decimal
@@ -43,6 +44,49 @@ def test_same_seed_same_tables_other_seed_other_tables(data):
             assert np.array_equal(col.values, again[t][c].values), (t, c)
             differs |= not np.array_equal(col.values, other[t][c].values)
     assert differs
+
+
+def generate_of_pr35(config, seed, scale=1.0):
+    """`gen.generate` as it stood before a table's columns could come
+    from column modules (the parent of PR 36), word for word."""
+    sizes = gen.table_rows(config, scale)
+    seeding = config["seeding"]
+    seeds = gen.Seeds(int(seed), int(seeding["structure_seed"]),
+                      frozenset(seeding["from_the_run_seed"]))
+    data = {}
+    for spec in config["tables"]:
+        name = spec["name"]
+        mod = importlib.import_module(f"perfbench.gen.tables.{name}")
+        cols = mod.generate(seeds, sizes[name], list(spec["columns"]), sizes)
+        missing = [c for c in spec["columns"] if c not in cols]
+        if missing:
+            raise KeyError(f"{name}: generator made no column {missing}")
+        data[name] = {c: cols[c] for c in spec["columns"]}
+    return data
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in
+                                  spec.benchmark()["workloads"]])
+def test_the_accepted_tables_are_the_parents_value_for_value(cell):
+    """At the rehearsal's scale: every column of every table of the
+    cell's configuration, values, masks, pools and decimal types."""
+    config = spec.cell(cell)["config"]
+    scale = float(config["rehearsal"]["scale"])
+    made = gen.generate(config, BIG_SEED, scale)
+    parents = generate_of_pr35(config, BIG_SEED, scale)
+    assert {t: list(c) for t, c in made.items()} \
+        == {t: list(c) for t, c in parents.items()} \
+        == {t["name"]: t["columns"] for t in config["tables"]}
+    for t, cols in parents.items():
+        for c, want in cols.items():
+            col = made[t][c]
+            assert col.values.dtype == want.values.dtype, (t, c)
+            assert np.array_equal(col.values, want.values), (t, c)
+            assert (col.valid is None) == (want.valid is None), (t, c)
+            assert col.valid is None \
+                or np.array_equal(col.valid, want.valid), (t, c)
+            assert (col.pool, col.scale, col.precision) \
+                == (want.pool, want.scale, want.precision), (t, c)
 
 
 def test_a_column_does_not_depend_on_the_columns_kept(data):

@@ -78,19 +78,27 @@ def test_the_cell_is_two_streams_of_three_whole_rounds():
     assert LOADED["chips"] == 1 and CONFIG["entry"] == "endpoint"
     assert LOADED["traffic"]["streams"] == [["q3", "q7"], ["q3", "q7"]]
     assert LOADED["traffic"]["rounds_at_most"] == 3
+    # the second tenant arrives 1.25 s after the first (PR 36): inside
+    # the 0.8-1.9 s in which its first program queues behind the first
+    # tenant's second, clear of the tie that gave query_s.p50 two levels
+    assert LOADED["traffic"]["start_offsets_s"] == [0.0, 1.25]
     assert CONFIG["streams"] == 2
     assert CONFIG["stream_order"] == LOADED["traffic"]["streams"]
     assert CONFIG["published"]["minimum_streams"] == 4
     assert {m["name"] for m in LOADED["end_to_end"]} == {
         "fact_rows_per_s", "query_s.p50", "query_s.p95", "setup_s"}
-    # the four readers of this PR, and the four that every cell reports
+    # the four readers of PR 33, the ten that every cell reports (PR 36),
+    # and `dispatch_ms`, which lists the cell
     assert {m["name"] for m in LOADED["per_layer"]} == {
         "serve_admission_wait_ms", "serve_execute_s_per_query",
         "wire_encode_ms", "statements_shed", "compiles_in_window",
-        "hbm_roofline_pct", "device_s_per_query", "device_idle_pct"}
+        "hbm_roofline_pct", "device_s_per_query", "device_idle_pct",
+        "programs_per_query", "discarded_program_s_per_query",
+        "stage_launches_per_query", "collect_ms", "setup_h2d_s",
+        "setup_program_load_s", "dispatch_ms"}
 
 
-@pytest.mark.parametrize("key", ["tables", "foreign_domains",
+@pytest.mark.parametrize("key", ["fact_tables", "tables", "foreign_domains",
                                  "query_templates", "distributions",
                                  "session_conf", "rehearsal", "benchmark",
                                  "scale_factor"])

@@ -1,6 +1,8 @@
 """The five readers of the engine's spans (PR 25): each on a planted
-window and span list, each silent where nothing is recorded and where the
-program has no `recorded_spans`, and all five on a real query."""
+window and span list, each silent where there is nothing to read and
+where the program has no `recorded_spans`, and all five on a real query.
+`discarded_program_s_per_query` is read in every cell, so where the
+program keeps spans and none is a discarded attempt it reads 0."""
 
 import os
 import sys
@@ -73,7 +75,8 @@ def test_reader_on_a_planted_window(name, monkeypatch):
 @pytest.mark.parametrize("name", READERS)
 def test_reader_is_silent_where_nothing_is_recorded(name, monkeypatch):
     _plant(monkeypatch, [])
-    assert spec.metric_reader(name).read({"records": RECORDS}) is None
+    nothing = 0.0 if name == "discarded_program_s_per_query" else None
+    assert spec.metric_reader(name).read({"records": RECORDS}) == nothing
     _plant(monkeypatch, SPANS)
     assert spec.metric_reader(name).read({"records": []}) is None
 
@@ -103,6 +106,59 @@ def test_failed_queries_do_not_count(monkeypatch):
         == pytest.approx(200.0)
 
 
+def _discarded_holds(records, holds_first_execution):
+    """The reader against the spans it sums: the `whole_query.attempt`
+    spans of the records' window that say `discarded`, over the queries.
+    Never under 0; over 0 only where the records hold the session's
+    first execution of the text, which has no capacities to start from:
+    an engine that remembers them reads 0 afterwards."""
+    from spark_tpu.obs.tracing import recorded_spans
+
+    found = recorded_spans(min(r["t_submit"] for r in records),
+                           max(r["t_done"] for r in records))
+    thrown = sum(s["dur_ms"] for s in found
+                 if s["name"] == "whole_query.attempt"
+                 and s.get("args", {}).get("discarded")) / 1000.0
+    got = spec.metric_reader("discarded_program_s_per_query").read(
+        {"records": records})
+    assert got == pytest.approx(thrown / len(records))
+    assert got >= 0
+    if holds_first_execution:
+        assert got > 0
+    return got
+
+
+# three executions of one text in one session, as the real query below:
+# today's engine replays the capacity ladder in each; one that remembers
+# the final capacities in-process (ROADMAP S3) discards in the first only
+THREE = [{"t_submit": 100.0, "t_done": 103.0, "error": None},
+         {"t_submit": 103.0, "t_done": 105.0, "error": None},
+         {"t_submit": 105.0, "t_done": 107.0, "error": None}]
+ENGINES = {
+    "replays": [_span("whole_query.attempt", 100.1, 700.0, discarded=True),
+                _span("whole_query.attempt", 100.9, 1900.0, discarded=False),
+                _span("whole_query.attempt", 103.1, 600.0, discarded=True),
+                _span("whole_query.attempt", 103.8, 1100.0, discarded=False),
+                _span("whole_query.attempt", 105.1, 600.0, discarded=True),
+                _span("whole_query.attempt", 105.8, 1100.0, discarded=False)],
+    "remembers": [_span("whole_query.attempt", 100.1, 700.0, discarded=True),
+                  _span("whole_query.attempt", 100.9, 1900.0,
+                        discarded=False),
+                  _span("whole_query.attempt", 103.1, 1100.0,
+                        discarded=False),
+                  _span("whole_query.attempt", 105.1, 1100.0,
+                        discarded=False)]}
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_what_the_real_query_holds_the_reader_to(engine, monkeypatch):
+    _plant(monkeypatch, ENGINES[engine])
+    assert _discarded_holds(THREE, True) == pytest.approx(
+        {"replays": 1.9 / 3, "remembers": 0.7 / 3}[engine])
+    assert _discarded_holds(THREE[1:], False) == pytest.approx(
+        {"replays": 0.6, "remembers": 0.0}[engine])
+
+
 def test_the_five_readers_on_a_real_query():
     import numpy as np
     import pyarrow as pa
@@ -121,19 +177,20 @@ def test_the_five_readers_on_a_real_query():
         })).createOrReplaceTempView("pbs_d")
         text = ("select t.k, sum(v) sv, count(*) n from pbs_t t join pbs_d d "
                 "on t.k = d.k group by t.k order by t.k")
-        s.sql(text).toArrow()                       # the set-up
-        records = []
-        for _ in range(2):
+        records = []            # the first is the set-up, the rest the window
+        for _ in range(3):
             rec = {"t_submit": time.perf_counter(), "error": None}
             s.sql(text).toArrow()
             rec["t_done"] = time.perf_counter()
             records.append(rec)
-        got = {name: spec.metric_reader(name).read({"records": records})
+        _discarded_holds(records, True)
+        thrown = _discarded_holds(records[1:], False)
+        got = {name: spec.metric_reader(name).read({"records": records[1:]})
                for name in READERS}
     finally:
         s.stop()
-    window = records[-1]["t_done"] - records[0]["t_submit"]
-    assert 0 < got["discarded_program_s_per_query"] < window / 2
+    window = records[-1]["t_done"] - records[1]["t_submit"]
+    assert got["discarded_program_s_per_query"] == thrown < window / 2
     assert 0 < got["dispatch_ms"] < 1000 * window / 2
     assert 0 < got["collect_ms"] < 1000 * window / 2
     assert got["setup_h2d_s"] > 0 and got["setup_program_load_s"] > 0

@@ -63,13 +63,20 @@ class FakeClient:
         return None, {}
 
 
-def window(streams, round_s, seconds, stall=None, at_most=None):
+def window(streams, round_s, seconds, stall=None, at_most=None,
+           offsets=None):
     records = []
     for i, (queries, length) in enumerate(zip(streams, round_s)):
         clock = FakeClock()
+
+        def sleep(s, clock=clock):
+            clock.now += s
+
         pb.stream_loop(i, FakeClient(clock, queries, length, stall), queries,
                        {q: q for q in queries}, seconds, FakeGate(clock),
-                       pb.no_span, records, at_most, clock=clock)
+                       pb.no_span, records, at_most, clock=clock,
+                       offset=(offsets or [0.0] * len(streams))[i],
+                       sleep=sleep)
     return records
 
 
@@ -145,6 +152,31 @@ def test_two_streams_of_unequal_lists_each_keep_whole_rounds(round_s):
         ROWS * len(records) / values["window_s"])
 
 
+@pytest.mark.parametrize("offset,rounds", [
+    (0.0, 3), (1.25, 3), (13.9, 3), (14.1, 2), (40.0, 1), (60.0, 1)])
+def test_a_stream_that_arrives_later_starts_later_and_the_window_does_not(
+        offset, rounds):
+    """tenants2.json's `start_offsets_s`: the second tenant's first
+    statement comes that long after the window opened. The window's
+    clock is the first stream's; the late stream's rounds are whole, its
+    first always runs, and the time it arrived late counts against its
+    further rounds as any other time passed does."""
+    streams = [["q3", "q7"], ["q3", "q7"]]
+    records = window(streams, [12.3, 12.3], 51.0, at_most=3,
+                     offsets=[0.0, offset])
+    first = [r for r in records if r["stream"] == 0]
+    late = [r for r in records if r["stream"] == 1]
+    assert [r["query"] for r in first] == ["q3", "q7"] * 3
+    assert [r["query"] for r in late] == ["q3", "q7"] * rounds
+    assert first[0]["t_submit"] == 1000.0
+    assert late[0]["t_submit"] == pytest.approx(1000.0 + offset)
+    values = pb.window_values(records, ROWS)
+    assert values["window_s"] == pytest.approx(
+        max(3 * 12.3, offset + rounds * 12.3))
+    assert values["fact_rows_per_s"] == pytest.approx(
+        ROWS * len(records) / values["window_s"])
+
+
 @pytest.mark.parametrize("stalled", [0, 4, 5, 8])
 def test_one_stalled_query_is_in_the_percentile_of_its_template(stalled):
     """Five rounds; one query waits 120 ms more for the device's answer
@@ -194,6 +226,15 @@ def test_the_first_stream_runs_in_the_callers_thread_the_others_in_theirs():
         (names[r["stream"]], r["query"]) for r in records)
     assert len(records) == 5       # seconds = 0: the first round, whole
     assert [r["query"] for r in records if r["stream"] == 1] == streams[1]
+
+
+def test_run_window_hands_each_stream_its_offset_on_the_real_clock():
+    streams = [["q3"], ["q3"]]
+    records = pb.run_window([ThreadClient([]), ThreadClient([])], streams,
+                            {"q3": "q3"}, 0.0, None, pb.no_span,
+                            offsets=[0.0, 0.2])
+    by = {r["stream"]: r for r in records}
+    assert 0.2 <= by[1]["t_submit"] - by[0]["t_submit"] < 0.3
 
 
 def test_setup_s_leaves_out_the_wait_for_the_generator():

@@ -278,12 +278,19 @@ def test_float32_control_comes_out_not_correct(data):
 # the cell, rehearsed; and the timed path broken underneath
 # ---------------------------------------------------------------------------
 
-def test_cell_rehearses_correct():
-    out = rehearse(CELL, seconds=3)
+@pytest.mark.parametrize("seconds,rounds", [(0, 1), (3600, 3)],
+                         ids=["the_round_that_always_runs",
+                              "the_three_the_traffic_file_allows"])
+def test_cell_rehearses_correct(seconds, rounds):
+    """dev2.json's `rounds_at_most` is 3 since PR 36 (two queries a
+    window left the rate to one host pause): with room for more the
+    window is three whole rounds, every execution compared."""
+    assert spec.cell(CELL)["traffic"]["rounds_at_most"] == 3
+    out = rehearse(CELL, seconds=seconds)
     assert out["correct"] is True, out["compared"]
-    assert out["failed"] == 0
-    assert out["window"]["queries"] == {"q89": 1, "q47": 1}
-    assert out["window"]["rounds"] == [1]
+    assert out["failed"] == 0 and out["attempted"] == 2 * rounds
+    assert out["window"]["queries"] == {"q89": rounds, "q47": rounds}
+    assert out["window"]["rounds"] == [rounds]
     assert out["metrics"] == {}
     for c in out["compared"].values():
         assert c["value"] is not None and c["value"] <= c["limit"]
