@@ -1654,6 +1654,18 @@ class _Analyzer:
                 budget = self._join_budget(node)
                 if budget is not None and sum(bcaps) > budget:
                     grace = True
+            lcaps = [b.cap for b in lp]
+            if bknown and not grace and len(pairs) == 1 \
+                    and all(c is not None for c in lcaps) \
+                    and node.builds_the_larger_side([lcaps], [bcaps]):
+                # the roles turn around at run time and the kernels
+                # below are the twin's, a probe a tile of the planned
+                # build side
+                self._approx(
+                    "inner join builds its smaller (left) side at run "
+                    "time: the launches are the twin join's")
+                notes.append("left side a quarter of the right's slots "
+                             "or fewer: built on the left at run time")
             if grace:
                 self._approx("grace hash join fragments both sides by key "
                              "hash — fragment kernels are data-dependent")

@@ -199,6 +199,27 @@ def _build_side_stage_ids(stages: list[Stage], done: set[int]) -> set[int]:
     return out
 
 
+def _stage_args(stage: Stage) -> dict:
+    """What span `stage.run` says of a stage before it runs: its id, the
+    attempt, and the kinds of its operators from the root down (a parent
+    stage's output is a leaf and has none)."""
+    kinds = [type(n).__name__.removesuffix("Exec")
+             for n in stage.root.iter_nodes()
+             if not isinstance(n, _StageOutput)]
+    return {"stage": stage.stage_id, "attempt": stage.attempts,
+            "operators": ",".join(kinds)}
+
+
+def _result_args(result: list, launches: int) -> dict:
+    """And once it has: the kernels it launched, the tiles it left and
+    their live rows where every tile knows its count on the host (-1
+    otherwise: reading one off the device would be a sync)."""
+    tiles = [b for part in result for b in part]
+    known = all(b._num_rows is not None for b in tiles)
+    return {"launches": launches, "tiles": len(tiles),
+            "rows_out": sum(b._num_rows for b in tiles) if known else -1}
+
+
 class DAGScheduler:
     """Runs a stage graph with per-stage retry (stage = unit of recovery;
     deterministic re-execution replays the subtree, the lineage property
@@ -256,16 +277,16 @@ class DAGScheduler:
                 try:
                     self._post("stageSubmitted", stage)
                     t0 = time.perf_counter()
-                    if tracer is not None:
+                    if tracer is None:
+                        stage.result = stage.root.execute(self.ctx)
+                    else:
                         # flow=True links execution phase → stage → lane
                         # spans as Perfetto flow arrows in the export
                         with tracer.span(f"stage-{stage.stage_id}",
                                          cat="stage",
                                          args={"attempt": attempt + 1},
                                          flow=True):
-                            stage.result = stage.root.execute(self.ctx)
-                    else:
-                        stage.result = stage.root.execute(self.ctx)
+                            self._execute_stage(stage, tracer)
                     from ..columnar.validate import maybe_validate
 
                     maybe_validate(stage.result, self.ctx,
@@ -318,6 +339,32 @@ class DAGScheduler:
                 install_runtime_filters(needed, done, self.ctx)
                 maybe_readmit(result_stage, done, self.ctx)
         return result_stage.result
+
+    def _execute_stage(self, stage: Stage, tracer) -> None:
+        """`stage.root.execute` under span `stage.run`. A whole-query
+        program is no stage of the stage tier though the scheduler runs
+        it as its one stage: it has the `whole_query.*` spans, and if it
+        degrades at run time the scheduler it starts spans its stages."""
+        from ..physical.whole_query import WholeQueryExec
+
+        if isinstance(stage.root, WholeQueryExec):
+            stage.result = stage.root.execute(self.ctx)
+            return
+        with tracer.span("stage.run", cat="stage",
+                         args=_stage_args(stage)) as sp:
+            l0 = self._launches()
+            stage.result = stage.root.execute(self.ctx)
+            sp.set_args(_result_args(stage.result, self._launches() - l0))
+
+    def _launches(self) -> int:
+        """Kernel launches so far: the query's own where it has a ledger
+        (other queries in flight move the process's counter too)."""
+        ledger = getattr(self.ctx, "kernel_ledger", None)
+        if ledger is not None:
+            return ledger.launches
+        from ..physical.compile import GLOBAL_KERNEL_CACHE
+
+        return GLOBAL_KERNEL_CACHE.launches
 
     def _post(self, kind: str, stage: Stage, dur=None, error=None):
         if self.bus is None:

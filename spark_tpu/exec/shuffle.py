@@ -17,6 +17,7 @@ dictionary codes + host dictionaries; reducers merge dictionaries on rebuild.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Sequence
 
 import numpy as np
@@ -71,6 +72,7 @@ class _OutBuffer:
         self.metrics = metrics
         self._chunk_rows: list[int] = []
         self._live_bytes = 0
+        self.bytes_in = 0      # host bytes appended (codes + validity)
         # per spill: (path, [per-chunk [sdict per col]], [per-chunk rows])
         self._spills: list[tuple] = []
         integral = [
@@ -89,13 +91,14 @@ class _OutBuffer:
         self.chunks.append(cols)
         self._chunk_rows.append(n)
         self.rows += n
+        # bytes moved through the shuffle write (codes + validity
+        # planes; dictionaries ride by reference): what compressed
+        # execution saves (tests/test_encoded_exec.py compares it)
+        shipped = sum(d.nbytes + (v.nbytes if v is not None else 0)
+                      for d, v, _ in cols)
+        self.bytes_in += shipped
         if self.metrics is not None:
-            # bytes moved through the shuffle write (codes + validity
-            # planes; dictionaries ride by reference): what compressed
-            # execution saves (tests/test_encoded_exec.py compares it)
-            self.metrics.add("shuffle.bytes_shipped", sum(
-                d.nbytes + (v.nbytes if v is not None else 0)
-                for d, v, _ in cols))
+            self.metrics.add("shuffle.bytes_shipped", shipped)
         for i in self._stat_cols:
             d, v, _ = cols[i]
             live = d if v is None else d[v]
@@ -105,9 +108,7 @@ class _OutBuffer:
                 self.col_stats[i] = ((min(plo, lo), max(phi, hi), True)
                                      if seen else (lo, hi, True))
         if self.spill_bytes is not None:
-            self._live_bytes += sum(
-                d.nbytes + (v.nbytes if v is not None else 0)
-                for d, v, _ in cols)
+            self._live_bytes += shipped
             if self._live_bytes > self.spill_bytes:
                 self._spill()
 
@@ -249,7 +250,6 @@ def _merge_dict_chunks(sdicts: list, datas: list):
 
 def _pull_sorted(batch: ColumnarBatch, perm, counts) -> tuple[list, np.ndarray]:
     """Gather columns by perm on device, transfer to host once."""
-    import jax
     jnp = _jnp()
 
     gathered = []
@@ -259,6 +259,26 @@ def _pull_sorted(batch: ColumnarBatch, perm, counts) -> tuple[list, np.ndarray]:
             np.asarray(jnp.take(c.validity, perm))
         gathered.append((data, validity, c.dictionary))
     return gathered, np.asarray(counts)
+
+
+@contextlib.contextmanager
+def host_exchange(ctx: ExecContext, kind: str, num_out: int):
+    """Span `shuffle.host` around one exchange between stages. Yields
+    the dict the exchange adds what it moved to, which the span carries
+    when it closes: `bytes_d2h`, the exchanged columns as they lie on
+    the host for the reducers' buffers, and `bytes_h2d`, the partitions
+    rebuilt as device batches. An exchange that stays on the device (a
+    broadcast of a stage's batches, a gather into one partition) moves 0
+    and 0. Nothing here reads the device."""
+    moved = {"bytes_d2h": 0, "bytes_h2d": 0}
+    tracer = getattr(ctx, "tracer", None)
+    if tracer is None:
+        yield moved
+        return
+    with tracer.span("shuffle.host", cat="exchange",
+                     args={"kind": kind, "partitions": num_out}) as sp:
+        yield moved
+        sp.set_args(moved)
 
 
 def _out_buffers(num_out: int, schema: StructType, ctx: ExecContext,
@@ -276,11 +296,10 @@ def hash_partition_batch(batch: ColumnarBatch,
     pid-grouped host columns + per-partition counts (the shared
     operator-at-a-time kernels — the fused exchange write in
     physical/fusion.py produces the same shape from one fused dispatch)."""
-    import jax
 
     from ..ops.hashing import hash_columns, partition_ids
     from ..ops.partition import hash_partition
-    from ..physical.compile import GLOBAL_KERNEL_CACHE
+    from ..physical.compile import GLOBAL_KERNEL_CACHE, stage_jit
 
     try:
         from ..utils.native import radix_partition as native_radix
@@ -302,7 +321,7 @@ def hash_partition_batch(batch: ColumnarBatch,
                 tuple(str(k.dtype) for k in key_eqs),
                 tuple(v is not None for v in key_valids))
         kernel = GLOBAL_KERNEL_CACHE.get_or_build(
-            kkey, lambda: jax.jit(
+            kkey, lambda: stage_jit(
                 lambda eqs, valids, mask: jnp.where(
                     mask,
                     partition_ids(hash_columns(eqs, list(valids),
@@ -328,7 +347,7 @@ def hash_partition_batch(batch: ColumnarBatch,
             tuple(str(k.dtype) for k in key_eqs),
             tuple(v is not None for v in key_valids))
     kernel = GLOBAL_KERNEL_CACHE.get_or_build(
-        kkey, lambda: jax.jit(
+        kkey, lambda: stage_jit(
             lambda eqs, valids, mask: hash_partition(
                 eqs, valids, mask, num_out, seed=seed)))
     pr = kernel(key_eqs, key_valids, batch.row_mask)
@@ -342,14 +361,13 @@ def rr_partition_batch(batch: ColumnarBatch, num_out: int,
     one compiled kernel per (capacity, num_out) serves every batch
     position (the historical key embedded start % num_out and compiled
     once per batch — the SampleExec storm shape)."""
-    import jax
 
     from ..ops.partition import round_robin_partition
-    from ..physical.compile import GLOBAL_KERNEL_CACHE
+    from ..physical.compile import GLOBAL_KERNEL_CACHE, stage_jit
 
     kkey = ("shuffle_rr", batch.capacity, num_out)
     kernel = GLOBAL_KERNEL_CACHE.get_or_build(
-        kkey, lambda: jax.jit(
+        kkey, lambda: stage_jit(
             lambda mask, s: round_robin_partition(mask, num_out, s)))
     pr = kernel(batch.row_mask, np.int32(start % num_out))
     return _pull_sorted(batch, pr.perm, pr.counts)
@@ -359,10 +377,9 @@ def range_partition_batch(batch: ColumnarBatch, key_position: int,
                           bounds, descending: bool, num_out: int,
                           string_key: bool) -> tuple[list, np.ndarray]:
     """Range-partition one batch against sampled bounds."""
-    import jax
 
     from ..ops.partition import range_partition, _group_by_pid
-    from ..physical.compile import GLOBAL_KERNEL_CACHE
+    from ..physical.compile import GLOBAL_KERNEL_CACHE, stage_jit
 
     jnp = _jnp()
     col = batch.columns[key_position]
@@ -379,7 +396,7 @@ def range_partition_batch(batch: ColumnarBatch, key_position: int,
         pids = jnp.take(lut_d, jnp.clip(col.data, 0, len(lut) - 1))
         kkey = ("shuffle_range_str", cap, num_out)
         kernel = GLOBAL_KERNEL_CACHE.get_or_build(
-            kkey, lambda: jax.jit(
+            kkey, lambda: stage_jit(
                 lambda p, m: _group_by_pid(p, m, num_out)))
         pr = kernel(pids, batch.row_mask)
     else:
@@ -387,7 +404,7 @@ def range_partition_batch(batch: ColumnarBatch, key_position: int,
         kkey = ("shuffle_range", cap, num_out, descending,
                 str(col.data.dtype), len(bounds))
         kernel = GLOBAL_KERNEL_CACHE.get_or_build(
-            kkey, lambda: jax.jit(
+            kkey, lambda: stage_jit(
                 lambda keys, b, mask: range_partition(
                     keys, b, mask, num_out, descending)))
         pr = kernel(col.sort_keys().astype(barr.dtype), barr,
@@ -405,13 +422,14 @@ def shuffle_hash(partitions: list[Partition], key_positions: Sequence[int],
     when re-splitting already-hash-partitioned data (grace join): reusing
     the seed makes h %% nfrag constant within a partition whenever nfrag
     divides the exchange's partition count — a degenerate split."""
-    bufs = _out_buffers(num_out, schema, ctx, stat_cols)
-    for part in partitions:
-        for batch in part:
-            gathered, counts = hash_partition_batch(
-                batch, key_positions, num_out, seed)
-            _slice_into(bufs, gathered, counts)
-    return _finish(bufs, ctx, stats, col_stats)
+    with host_exchange(ctx, "hash", num_out) as moved:
+        bufs = _out_buffers(num_out, schema, ctx, stat_cols)
+        for part in partitions:
+            for batch in part:
+                gathered, counts = hash_partition_batch(
+                    batch, key_positions, num_out, seed)
+                _slice_into(bufs, gathered, counts)
+        return _finish(bufs, ctx, stats, col_stats, moved)
 
 
 def shuffle_round_robin(partitions: list[Partition], num_out: int,
@@ -419,14 +437,15 @@ def shuffle_round_robin(partitions: list[Partition], num_out: int,
                         stats: dict | None = None,
                         col_stats: dict | None = None,
                         stat_cols: list | None = None) -> list[Partition]:
-    bufs = _out_buffers(num_out, schema, ctx, stat_cols)
-    start = 0
-    for part in partitions:
-        for batch in part:
-            gathered, counts = rr_partition_batch(batch, num_out, start)
-            _slice_into(bufs, gathered, counts)
-            start += int(counts.sum())
-    return _finish(bufs, ctx, stats, col_stats)
+    with host_exchange(ctx, "round_robin", num_out) as moved:
+        bufs = _out_buffers(num_out, schema, ctx, stat_cols)
+        start = 0
+        for part in partitions:
+            for batch in part:
+                gathered, counts = rr_partition_batch(batch, num_out, start)
+                _slice_into(bufs, gathered, counts)
+                start += int(counts.sum())
+        return _finish(bufs, ctx, stats, col_stats, moved)
 
 
 def shuffle_range(partitions: list[Partition], key_position: int,
@@ -436,16 +455,17 @@ def shuffle_range(partitions: list[Partition], key_position: int,
                   stat_cols: list | None = None) -> list[Partition]:
     """Range shuffle for global sort. `bounds` is a host list of boundary
     values in the sort-key domain (numeric) or raw strings."""
-    bufs = _out_buffers(num_out, schema, ctx, stat_cols)
     f = schema.fields[key_position]
     string_key = isinstance(f.dataType, StringType)
-    for part in partitions:
-        for batch in part:
-            gathered, counts = range_partition_batch(
-                batch, key_position, bounds, descending, num_out,
-                string_key)
-            _slice_into(bufs, gathered, counts)
-    return _finish(bufs, ctx, stats, col_stats)
+    with host_exchange(ctx, "range", num_out) as moved:
+        bufs = _out_buffers(num_out, schema, ctx, stat_cols)
+        for part in partitions:
+            for batch in part:
+                gathered, counts = range_partition_batch(
+                    batch, key_position, bounds, descending, num_out,
+                    string_key)
+                _slice_into(bufs, gathered, counts)
+        return _finish(bufs, ctx, stats, col_stats, moved)
 
 
 def shuffle_fused(partitions: list[Partition], writer, num_out: int,
@@ -463,28 +483,34 @@ def shuffle_fused(partitions: list[Partition], writer, num_out: int,
     the other fused operators' size gate."""
     from ..config import FUSION_MIN_ROWS
 
-    bufs = _out_buffers(num_out, schema, ctx, stat_cols)
     min_rows = int(ctx.conf.get(FUSION_MIN_ROWS))  # tpulint: ignore[host-sync]
-    start = 0  # running live-row offset (round-robin positioning)
-    for part in partitions:
-        fused = sum(b.capacity for b in part) >= min_rows
-        for batch in part:
-            if fused:
-                gathered, counts = writer.partition_batch(batch, start)
-            else:
-                gathered, counts = writer.partition_unfused(batch, start)
-            _slice_into(bufs, gathered, counts)
-            # counts is host numpy (materialized by the map-side write)
-            start += int(counts.sum())  # tpulint: ignore[host-sync]
-    return _finish(bufs, ctx, stats, col_stats)
+    with host_exchange(ctx, "fused", num_out) as moved:
+        bufs = _out_buffers(num_out, schema, ctx, stat_cols)
+        start = 0  # running live-row offset (round-robin positioning)
+        for part in partitions:
+            fused = sum(b.capacity for b in part) >= min_rows
+            for batch in part:
+                if fused:
+                    gathered, counts = writer.partition_batch(batch, start)
+                else:
+                    gathered, counts = writer.partition_unfused(batch,
+                                                                start)
+                _slice_into(bufs, gathered, counts)
+                # counts is host numpy (materialized by the map-side
+                # write)
+                start += int(counts.sum())  # tpulint: ignore[host-sync]
+        return _finish(bufs, ctx, stats, col_stats, moved)
 
 
-def gather_single(partitions: list[Partition]) -> list[Partition]:
-    """AllTuples: concatenate every partition into one."""
-    merged: Partition = []
-    for p in partitions:
-        merged.extend(p)
-    return [merged]
+def gather_single(partitions: list[Partition],
+                  ctx: ExecContext | None = None) -> list[Partition]:
+    """AllTuples: concatenate every partition into one. The batches stay
+    where they are, so the exchange's span carries no bytes."""
+    with host_exchange(ctx, "gather", 1):
+        merged: Partition = []
+        for p in partitions:
+            merged.extend(p)
+        return [merged]
 
 
 def _slice_into(bufs: list[_OutBuffer], gathered: list, counts: np.ndarray):
@@ -503,7 +529,8 @@ def _slice_into(bufs: list[_OutBuffer], gathered: list, counts: np.ndarray):
 
 def _finish(bufs: list[_OutBuffer], ctx: ExecContext,
             stats: dict | None,
-            col_stats: dict | None = None) -> list[Partition]:
+            col_stats: dict | None = None,
+            moved: dict | None = None) -> list[Partition]:
     tile = ctx.conf.batch_capacity
     out = []
     for i, b in enumerate(bufs):
@@ -512,4 +539,8 @@ def _finish(bufs: list[_OutBuffer], ctx: ExecContext,
         if col_stats is not None:
             col_stats[i] = dict(b.col_stats)
         out.append(b.build(tile))
+    if moved is not None:
+        moved["bytes_d2h"] += sum(b.bytes_in for b in bufs)
+        moved["bytes_h2d"] += sum(t.device_nbytes()
+                                  for part in out for t in part)
     return out

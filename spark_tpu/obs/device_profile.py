@@ -10,6 +10,15 @@ instant of a program's run goes to the innermost event covering it, the
 event to the innermost `mNN.Kind` component of its op_name, and `mNN` is
 row NN of the program's `members`. Runs nowhere on the hot path.
 
+The stage tier has no such program: a query is hundreds of launches of
+small kernels (`jit_<kind>_<hash>`: physical/compile.stage_jit), each
+shared by every operator whose structure gives its key, so the operator
+is not in a kernel's text. It is in the launch: `capture_programs` notes
+the executing operator of every KernelCache launch, and the n-th run of a
+module in the trace is its n-th launch (`attribute_launches`). An operator
+row there is `mNN.Kind` with NN the operator's `_metric_id`, its place in
+the physical plan from the root down.
+
 On a TPU the events are the lines `XLA Ops` and `XLA Modules` of
 `/device:TPU:<n>`; the CPU backend has no device plane and names its HLO
 events by stats on host threads, which `_cpu_planes` brings to the same
@@ -19,13 +28,16 @@ host thread's, and the report says so.
 
 from __future__ import annotations
 
+import collections
 import glob
 import os
 import re
 import tempfile
 import time
+from typing import Sequence
 
-__all__ = ["explain", "scope_map", "operator_of", "attribute", "render"]
+__all__ = ["explain", "scope_map", "operator_of", "attribute",
+           "attribute_launches", "render", "render_launches"]
 
 DEVICE_PLANE = "/device:TPU:"
 CPU_PLANE = "/host:XLA-CPU"      # what _cpu_planes calls its one plane
@@ -100,6 +112,13 @@ def _self_times(events: list) -> dict:
     return out
 
 
+def _device_lines(planes: dict) -> dict:
+    """The lines of the first device plane, or of the CPU's stand-in."""
+    names = sorted(p for p in planes
+                   if p.startswith(DEVICE_PLANE) or p == CPU_PLANE)
+    return planes[names[0]] if names else {}
+
+
 def attribute(planes: dict, scopes: dict) -> list:
     """One record per run of a program named in `scopes`
     ({module name: {instruction: op_name}}), in time order, from the
@@ -109,11 +128,7 @@ def attribute(planes: dict, scopes: dict) -> list:
     instructions: [(name, inclusive ns, label, phase)]}. The groups and
     `unattributed` (events no operator scope names, and the instants of
     the run in which no event ran) add up to device_ns."""
-    names = sorted(p for p in planes
-                   if p.startswith(DEVICE_PLANE) or p == CPU_PLANE)
-    if not names:
-        return []
-    lines = planes[names[0]]
+    lines = _device_lines(planes)
     ops = sorted((s, s + d, _instruction(n))
                  for n, s, d in lines.get(OPS_LINE, []))
     runs = []
@@ -141,7 +156,85 @@ def attribute(planes: dict, scopes: dict) -> list:
     return runs
 
 
-def render(runs: list, programs: dict, attempts: list, head: str) -> str:
+def attribute_launches(planes: dict, launches: list, skip=()) -> dict:
+    """The device time of the kernels a query launched one at a time, by
+    the operator that launched each. `launches` are `capture_programs`'
+    (module name, kind, (row, operator name) or None) in launch order;
+    a module's runs on the first device plane are taken in time order
+    against its launches. Modules in `skip` (the whole-query programs,
+    which `attribute` reads) are left out. Returns {"rows": {(row,
+    operator name): {kind: [ns, runs]}}, "unnamed": {module: [ns,
+    runs]} for the runs no launch answers for (eager jax.numpy
+    operations between kernels, or more runs than launches),
+    "device_ns": the sum of both}."""
+    queues: dict = {}
+    for program, kind, op in launches:
+        if program is not None and program not in skip:
+            queues.setdefault(program, collections.deque()).append(
+                (kind, op))
+    rows: dict = {}
+    unnamed: dict = {}
+    total = 0
+    for name, _start, dur in sorted(
+            _device_lines(planes).get(MODULES_LINE, []),
+            key=lambda e: e[1]):
+        program = name.split("(", 1)[0]
+        if program in skip:
+            continue
+        total += dur
+        queue = queues.get(program)
+        if not queue:
+            cell = unnamed.setdefault(program, [0, 0])
+        else:
+            kind, op = queue.popleft()
+            cell = rows.setdefault(op or (None, UNATTRIBUTED), {}) \
+                .setdefault(kind, [0, 0])
+        cell[0] += dur
+        cell[1] += 1
+    return {"rows": rows, "unnamed": unnamed, "device_ns": total}
+
+
+def render_launches(found: dict, plan_rows: dict) -> list:
+    """The stage tier's part of the report, as lines. `plan_rows` is
+    {row: (label, text)} of the traced plan's operators."""
+    total = found["device_ns"] or 1
+    named = sum(ns for kinds in found["rows"].values()
+                for ns, _n in kinds.values())
+    runs = sum(n for kinds in found["rows"].values()
+               for _ns, n in kinds.values())
+    out = [f"stage tier: {runs} kernel launches, "
+           f"{found['device_ns'] / 1e6:.3f} ms on the device, "
+           f"{100.0 * named / total:.2f} % of it in kernels an operator "
+           "launched"]
+
+    def place(op):
+        row, name = op
+        return (row if isinstance(row, int) and row in plan_rows
+                else float("inf"), str(name))
+
+    for op in sorted(found["rows"], key=place):
+        kinds = found["rows"][op]
+        ns = sum(v[0] for v in kinds.values())
+        label, text = plan_rows.get(op[0], (
+            op[1].removesuffix("Exec") if op[1] else UNATTRIBUTED, ""))
+        out.append(f"  {label:<24} {ns / 1e6:>12.3f} ms "
+                   f"{100.0 * ns / total:>6.2f} %  {text}")
+        for kind, (kns, n) in sorted(kinds.items(),
+                                     key=lambda kv: -kv[1][0]):
+            out.append(f"    {kind:<22} {kns / 1e6:>12.3f} ms "
+                       f"{100.0 * kns / total:>6.2f} %  x{n}")
+    rest = sum(v[0] for v in found["unnamed"].values())
+    out.append(f"  {UNATTRIBUTED:<24} {rest / 1e6:>12.3f} ms "
+               f"{100.0 * rest / total:>6.2f} %  modules no kernel launch "
+               "answers for")
+    for module, (ns, n) in sorted(found["unnamed"].items(),
+                                  key=lambda kv: -kv[1][0])[:TOP_INSTRUCTIONS]:
+        out.append(f"    {module:<38} {ns / 1e6:>12.3f} ms  x{n}")
+    return out
+
+
+def render(runs: list, programs: dict, attempts: list, head: str,
+           stage_lines: Sequence = ()) -> str:
     """The report. `programs` is {module name: the capture_programs
     record}; `attempts` the traced query's `whole_query.attempt` spans
     in time order, one per run, which say which runs were discarded."""
@@ -177,10 +270,11 @@ def render(runs: list, programs: dict, attempts: list, head: str) -> str:
             place = UNATTRIBUTED if label is None \
                 else label + ("/" + phase if phase else "")
             out.append(f"    {name:<28} {ns / 1e6:>12.3f} ms  {place}")
-    if not runs:
-        out.append("the traced run launched no named program on a device "
-                   "plane of the trace (answered from the result cache, or "
-                   "ran on another tier than whole / mesh-whole)")
+    out.extend(stage_lines)
+    if not runs and not stage_lines:
+        out.append("the traced run launched no named program or kernel on "
+                   "a device plane of the trace (answered from the result "
+                   "cache)")
     return "\n".join(out)
 
 
@@ -226,7 +320,8 @@ def _cpu_planes(events: list) -> dict:
 
 def explain(qe) -> str:
     """Run `qe`'s query warm once and traced once, and render where its
-    whole-query programs spent their device time, operator by operator.
+    whole-query programs, and the kernels it launched one at a time on
+    the stage tier, spent their device time, operator by operator.
     The programs' texts come from compiling their lowerings again, which
     the persistent compile cache serves where it is on."""
     import jax
@@ -236,7 +331,9 @@ def explain(qe) -> str:
     from .tracing import recorded_spans
 
     def run():
-        return QueryExecution(qe.session, qe.logical).to_arrow()
+        traced = QueryExecution(qe.session, qe.logical)
+        traced.to_arrow()
+        return traced
 
     run()
     options = jax.profiler.ProfileOptions()
@@ -247,7 +344,7 @@ def explain(qe) -> str:
             jax.profiler.start_trace(tmp, profiler_options=options)
             t0 = time.perf_counter()
             try:
-                run()
+                traced = run()
             finally:
                 t1 = time.perf_counter()
                 jax.profiler.stop_trace()
@@ -268,4 +365,23 @@ def explain(qe) -> str:
     if CPU_PLANE in planes:
         head += ("\nno device plane: these are the CPU backend's host "
                  "threads, not a device's times")
-    return render(attribute(planes, scopes), programs, attempts, head)
+    found = attribute_launches(planes, captured.launches, skip=scopes)
+    stage_lines = render_launches(found, _plan_rows(traced.physical)) \
+        if found["rows"] else ()
+    return render(attribute(planes, scopes), programs, attempts, head,
+                  stage_lines)
+
+
+def _plan_rows(physical) -> dict:
+    """{row: (`mNN.Kind`, the operator's line)} of a physical plan, rows
+    as `QueryExecution` numbers them (`_metric_id`)."""
+    from .metrics import iter_metric_nodes
+
+    rows = {}
+    for n in iter_metric_nodes(physical):
+        row = getattr(n, "_metric_id", None)
+        if row is not None:
+            kind = type(n).__name__.removesuffix("Exec")
+            rows[row] = (f"m{row:02d}.{kind}",
+                         n.simple_string().split("\n", 1)[0][:100])
+    return rows

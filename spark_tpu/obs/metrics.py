@@ -39,7 +39,7 @@ import numpy as np
 from ..utils import lockwatch
 
 __all__ = ["AnalyzedReport", "QueryKernelLedger", "batch_cost_scope",
-           "current_op_name", "current_query_ledger",
+           "current_op_name", "current_op_row", "current_query_ledger",
            "export_op_records", "export_op_records_partial",
            "finalize_plan_metrics", "fused_members",
            "get_or_create_op_record", "iter_metric_nodes",
@@ -237,9 +237,11 @@ def get_or_create_op_record(rec: dict, key) -> dict:
     return ent
 
 
-def push_op(record: dict | None, name: str):
-    """Enter an operator's attribution scope; returns the reset token."""
-    return _SCOPE.set((record, name))
+def push_op(record: dict | None, name: str, row=None):
+    """Enter an operator's attribution scope; returns the reset token.
+    `row` is the operator's key in the query's plan_metrics (its
+    `_metric_id`: the node's place in `iter_metric_nodes`)."""
+    return _SCOPE.set((record, name, row))
 
 
 def pop_op(token) -> None:
@@ -249,6 +251,12 @@ def pop_op(token) -> None:
 def current_op_name() -> str | None:
     scope = _SCOPE.get()
     return scope[1] if scope is not None else None
+
+
+def current_op_row() -> tuple | None:
+    """(row, name) of the executing operator, or None outside one."""
+    scope = _SCOPE.get()
+    return None if scope is None else (scope[2], scope[1])
 
 
 def record_kernel_launch(kind, cost: dict | None = None) -> None:

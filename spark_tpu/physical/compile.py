@@ -25,6 +25,7 @@ from ..columnar.batch import Column, ColumnarBatch
 from ..expr.eval import HostCtx, TraceCtx, Val
 from ..obs.metrics import (
     batch_cost_scope,
+    current_op_row as _obs_op_row,
     record_kernel_compile as _obs_compile,
     record_kernel_disk_hit as _obs_disk_hit,
     record_kernel_launch as _obs_launch,
@@ -40,7 +41,7 @@ from ..utils import faults as _faults
 __all__ = ["canonical_key", "KernelCache", "ExprPipeline", "bind_inputs",
             "broadcast_to_cap", "trace_pipeline", "pipeline_host_pass",
             "pipeline_signature", "pipeline_columns", "named_jit",
-            "capture_programs", "note_program", "module_name"]
+            "stage_jit", "capture_programs", "note_program", "module_name"]
 
 
 # ---------------------------------------------------------------------------
@@ -73,6 +74,28 @@ def named_jit(kind: str, key, fn, labels: Sequence = (), **jit_kwargs):
     return jax.jit(fn, **jit_kwargs)  # tpulint: ignore[raw-jit]
 
 
+# the key `KernelCache.get_or_build` is building under, for stage_jit
+_BUILDING: "contextvars.ContextVar" = contextvars.ContextVar(
+    "spark_tpu_kernel_building", default=None)
+
+
+def stage_jit(fn):
+    """`named_jit` for a kernel of the tiers under the whole tier, called
+    inside the builder that `KernelCache.get_or_build` runs: the kind is
+    the key's first word (`pipeline`, `join_probe`, `gagg`, ...: what
+    `launches_by_kind` and `kernel.first_launch` call it) and the key the
+    one it is cached under, so the XLA module is `jit_<kind>_<hash>` and a
+    trace says which kernel held the chip. A kernel is shared by every
+    operator whose structure gives its key, so an operator's row is not
+    part of the name: `capture_programs` notes the launching operator of
+    each launch instead."""
+    key = _BUILDING.get()
+    if key is None:
+        raise RuntimeError("stage_jit outside a KernelCache.get_or_build "
+                           "builder: there is no key to name the kernel by")
+    return named_jit(str(key[0]), key, fn)
+
+
 def module_name(kernel) -> str | None:
     """The XLA module a KernelCache kernel runs as (`jit_<name>`), which
     is how a profiler trace names it."""
@@ -86,13 +109,27 @@ _CAPTURE: "contextvars.ContextVar" = contextvars.ContextVar(
     "spark_tpu_program_capture", default=None)
 
 
+class _Captured(list):
+    """What `capture_programs` yields: the `note_program` records, and in
+    `launches` one (module name, kind, operator row) for every launch of
+    a KernelCache kernel, in launch order."""
+
+    def __init__(self):
+        super().__init__()
+        self.launches: list = []
+
+
 @contextlib.contextmanager
 def capture_programs():
     """Collect a record of every named program launched in this context:
     {program, members, scopes, kernel, args} with `args` reduced to
     shapes, so that `kernel._kernel.lower(*args)` gives the program's
-    text again without holding a plane."""
-    got: list = []
+    text again without holding a plane. Its `launches` lists every
+    KernelCache launch with the operator that made it
+    (obs/metrics.current_op_row): the stage tier's kernels are shared
+    between operators, so the launch and not the program says whose
+    device time a run is."""
+    got = _Captured()
     token = _CAPTURE.set(got)
     try:
         yield got
@@ -351,6 +388,10 @@ class KernelCache:
             # per-operator attribution (obs/metrics contextvar scope):
             # host bookkeeping only — no dispatch, no sync
             _obs_launch(kind, cost)
+            got = _CAPTURE.get()
+            if got is not None:
+                got.launches.append((module_name(f), str(kind),
+                                     _obs_op_row()))
             if first:
                 import time as _time
 
@@ -408,7 +449,11 @@ class KernelCache:
         import time as _time
 
         t0 = _time.perf_counter()
-        f = self._wrap(key, builder())
+        token = _BUILDING.set(key)
+        try:
+            f = self._wrap(key, builder())
+        finally:
+            _BUILDING.reset(token)
         dt = (_time.perf_counter() - t0) * 1000
         with self._lock:
             self.compile_ms += dt
@@ -588,7 +633,11 @@ class ExprPipeline:
         cols = pipeline_columns(self.out_schema.fields, host_outs, out_datas,
                                 out_valids)
         cols = self._propagate_runs(batch, cols)
-        return ColumnarBatch(self.out_schema, cols, new_mask, num_rows=None)
+        # a projection keeps its input's rows: a count the host already
+        # has stays known (a one-row aggregate's, through to a cross join)
+        return ColumnarBatch(self.out_schema, cols, new_mask,
+                             num_rows=None if self.filters
+                             else batch._num_rows)
 
     def _propagate_runs(self, batch: ColumnarBatch, cols: list) -> list:
         """Pass-through outputs inherit the input column's ingest RunInfo:
@@ -618,8 +667,6 @@ class ExprPipeline:
         return out
 
     def _build_kernel(self, cap: int):
-        import jax
-
         input_attrs = self.input_attrs
         filters = self.filters
         outputs = self.outputs
@@ -628,4 +675,4 @@ class ExprPipeline:
             return trace_pipeline(input_attrs, filters, outputs,
                                   datas, valids, row_mask, aux, cap)
 
-        return jax.jit(kernel)
+        return stage_jit(kernel)

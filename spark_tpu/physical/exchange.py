@@ -98,7 +98,7 @@ class ShuffleExchangeExec(PhysicalPlan):
         with ctx.metrics.time("shuffle"):
             if isinstance(p, SinglePartition):
                 with self._span(ctx, "exchange.gather", p):
-                    return S.gather_single(parts)
+                    return S.gather_single(parts, ctx)
             if isinstance(p, HashPartitioning):
                 pos = {a.expr_id: i for i, a in enumerate(self.output)}
                 key_positions = []
@@ -274,7 +274,7 @@ class ShuffleExchangeExec(PhysicalPlan):
             if bounds is None or len(bounds) == 0:
                 return S.gather_single(
                     [[fusion.run_pipeline(b) for b in part]
-                     for part in parts])
+                     for part in parts], ctx)
             return S.shuffle_fused(
                 parts,
                 fusion.bind_range(kpos, bounds, not order.ascending,
@@ -283,7 +283,7 @@ class ShuffleExchangeExec(PhysicalPlan):
                 self.last_col_stats, self.stat_cols)
         bounds = _sample_bounds(parts, kpos, schema, p.num_partitions)
         if bounds is None or len(bounds) == 0:
-            return S.gather_single(parts)
+            return S.gather_single(parts, ctx)
         return S.shuffle_range(parts, kpos, bounds, not order.ascending,
                                p.num_partitions, schema, ctx,
                                self.last_stats,
@@ -438,8 +438,13 @@ class BroadcastExchangeExec(PhysicalPlan):
         schema = attrs_schema(self.output)
         if not merged:
             return [[ColumnarBatch.empty(schema)]]
-        batch = concat_batches(merged, schema)
-        ctx.metrics.add("broadcast.rows", batch.num_rows())
+        with S.host_exchange(ctx, "broadcast", 1):
+            batch = concat_batches(merged, schema)
+        if batch._num_rows is not None:
+            # counted where the host has the count: nothing reads this
+            # metric, and reading a count off the device waits for every
+            # kernel queued before it
+            ctx.metrics.add("broadcast.rows", batch._num_rows)
         return [[batch]]
 
     def simple_string(self):

@@ -105,10 +105,9 @@ def external_sort(part, orders, schema, child_output, ctx,
     Returns an ordered list of sorted ColumnarBatches (bucket order).
     ``sort_single(list_of_batches) -> ColumnarBatch`` is the in-budget
     single-tile sort (SortExec's kernel)."""
-    import jax
 
     from ..ops.partition import _group_by_pid
-    from .compile import GLOBAL_KERNEL_CACHE
+    from .compile import GLOBAL_KERNEL_CACHE, stage_jit
 
     jnp = _jnp()
     total_cap = sum(b.capacity for b in part)
@@ -155,7 +154,7 @@ def external_sort(part, orders, schema, child_output, ctx,
                         pids = jnp.where(valid, pids, null_pid)
                     return _group_by_pid(pids, mask, B)
 
-                return jax.jit(kernel)
+                return stage_jit(kernel)
 
             kernel = GLOBAL_KERNEL_CACHE.get_or_build(kkey, build_str)
             pr = kernel(lut_d, col.data,
@@ -176,7 +175,7 @@ def external_sort(part, orders, schema, child_output, ctx,
                         pids = jnp.where(valid, pids, null_pid)
                     return _group_by_pid(pids, mask, B)
 
-                return jax.jit(kernel)
+                return stage_jit(kernel)
 
             kernel = GLOBAL_KERNEL_CACHE.get_or_build(kkey, build_num)
             pr = kernel(jnp.asarray(bounds), keys,

@@ -44,7 +44,7 @@ from ..obs.metrics import batch_cost_scope
 from .aggregates import FUSABLE_OPS
 from .compile import (
     GLOBAL_KERNEL_CACHE, bind_inputs, canonical_key, pipeline_columns,
-    pipeline_host_pass, pipeline_signature, trace_pipeline,
+    pipeline_host_pass, pipeline_signature, stage_jit, trace_pipeline,
 )
 from .operators import (
     ComputeExec, HashAggregateExec, HashJoinExec, LimitExec, PhysicalPlan,
@@ -285,7 +285,7 @@ class FusedAggregateExec(HashAggregateExec):
                     m = jnp.zeros((out_cap,), dtype=bool).at[0].set(True)
                     return bufs_d, bufs_v, m
 
-                return jax.jit(kernel)
+                return stage_jit(kernel)
 
             kernel = GLOBAL_KERNEL_CACHE.get_or_build(
                 ("fused_agg", "u") + base_key, build_ungrouped)
@@ -361,7 +361,7 @@ class FusedAggregateExec(HashAggregateExec):
                         .at[out_cap - 1].set(False)
                     return out_keys, key_validity, bufs, out_mask
 
-                return jax.jit(kernel)
+                return stage_jit(kernel)
 
             kernel = GLOBAL_KERNEL_CACHE.get_or_build(
                 ("fused_agg", "d", out_cap) + base_key, build_dense)
@@ -403,7 +403,7 @@ class FusedAggregateExec(HashAggregateExec):
                     mask, ops, vd, vv)
                 return out_keys, rank_to_code(bufs, iluts), out_mask
 
-            return jax.jit(kernel)
+            return stage_jit(kernel)
 
         from ..ops.grouping import segment_path
 
@@ -538,8 +538,6 @@ class FusedLimitExec(LimitExec):
         return self._unfused_cache
 
     def _fused_partition(self, part, ctx) -> list:
-        import jax
-
         from ..columnar.ops import concat_batches
 
         jnp = _jnp()
@@ -568,7 +566,7 @@ class FusedLimitExec(LimitExec):
                     (rank <= self.offset + self.n)
                 return out_datas, out_valids, keep
 
-            return jax.jit(kernel)
+            return stage_jit(kernel)
 
         kernel = GLOBAL_KERNEL_CACHE.get_or_build(key, build)
         with batch_cost_scope(batch):
@@ -722,7 +720,6 @@ class ExchangeFusion:
     # -- the fused kernel --------------------------------------------------
     def partition_batch(self, batch: ColumnarBatch, start: int):
         """One dispatch: (grouped host columns, per-partition counts)."""
-        import jax
 
         jnp = _jnp()
         cap = batch.capacity
@@ -845,7 +842,7 @@ class ExchangeFusion:
                         [counts, rf_drop.astype(counts.dtype)[None]])
                 return g_datas, g_valids, counts
 
-            return jax.jit(kernel)
+            return stage_jit(kernel)
 
         kernel = GLOBAL_KERNEL_CACHE.get_or_build(key, build)
         with batch_cost_scope(batch):
@@ -884,7 +881,6 @@ def runtime_filter_batch(rf: dict, rf_dev, b: ColumnarBatch,
     unfused path AND the mesh pre-pass, where the filter cannot ride a
     fused map kernel). Null keys are kept conservatively — the join
     drops them. Returns (filtered batch, pruned-row count)."""
-    import jax
 
     jnp = _jnp()
     col = b.columns[pos]
@@ -924,7 +920,7 @@ def runtime_filter_batch(rf: dict, rf_dev, b: ColumnarBatch,
             new_mask = mask & ok
             return new_mask, jnp.sum(mask & ~ok)
 
-        return jax.jit(kernel)
+        return stage_jit(kernel)
 
     kernel = GLOBAL_KERNEL_CACHE.get_or_build(key, build)
     new_mask, drop = kernel(col.data, col.validity, b.row_mask, op)

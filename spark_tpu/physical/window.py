@@ -22,7 +22,7 @@ from ..expr.window import (
     PercentRank, Rank, RowNumber, WindowExpression,
 )
 from ..types import StringType, float64, int32, int64
-from .compile import GLOBAL_KERNEL_CACHE
+from .compile import GLOBAL_KERNEL_CACHE, stage_jit
 from .operators import PhysicalPlan, attrs_schema
 from .partitioning import AllTuples, ClusteredDistribution, UnspecifiedDistribution
 
@@ -236,8 +236,6 @@ class WindowExec(PhysicalPlan):
         return [[self._run_partition(p)] if p else [] for p in parts]
 
     def _run_partition(self, part) -> ColumnarBatch:
-        import jax
-
         from ..ops.sorting import SortKeySpec
 
         jnp = _jnp()
@@ -317,7 +315,7 @@ class WindowExec(PhysicalPlan):
                                     okeys, ovalids, vdatas, vvalids,
                                     row_mask, kmin, band)
 
-            return jax.jit(kernel)
+            return stage_jit(kernel)
 
         kernel = GLOBAL_KERNEL_CACHE.get_or_build(key, build)
         ones = jnp.ones((cap,), jnp.int32)
