@@ -471,23 +471,25 @@ class _Analyzer:
 
     # -- persistent-cache mirrors (exec/persist_cache.py) -------------------
     def _persist_seed_record(self):
-        """The warm-start manifest record for the analyzed plan's full
-        fingerprint (None when spark.tpu.cache.dir is unset or no prior
-        same-fingerprint run recorded outcomes) — the SAME lookup
-        QueryExecution performs, so the capacity mirrors below predict a
-        seeded first attempt exactly. Memoized per analysis."""
+        """What the analyzed plan's first attempt starts from, by its
+        full fingerprint: the warm-start manifest's record, or without
+        one the join capacities this process remembers from the plan's
+        last execution (None when neither holds outcomes) — the SAME
+        lookup QueryExecution performs (persist_cache.plan_seed), so the
+        capacity mirrors below predict a seeded first attempt exactly.
+        Memoized per analysis."""
         if self._persist_seed_done:
             return self._persist_seed
         self._persist_seed_done = True
         try:
-            from ..exec.persist_cache import cache_root, manifest_seed
+            from ..exec.persist_cache import plan_seed
 
-            if self._plan_root is not None and cache_root(self.conf):
+            if self._plan_root is not None:
                 from ..obs.history import plan_fingerprint
 
                 fp = plan_fingerprint(self._plan_root, self.conf)
-                self._persist_seed = manifest_seed(self.conf,
-                                                   fp["fingerprint"])
+                self._persist_seed = plan_seed(self.conf,
+                                               fp["fingerprint"])
         except Exception:
             self._persist_seed = None
         return self._persist_seed
@@ -496,7 +498,9 @@ class _Analyzer:
                          num_out: int):
         """Mirror of the mesh exchanges' warm-start quota lookup: the
         same mesh_quota_key the execution layer computes from the
-        staging geometry, resolved against the same manifest record."""
+        staging geometry, resolved against the same manifest record
+        (quotas are the manifest's alone: what the process remembers
+        without a cache dir holds none)."""
         seed = self._persist_seed_record()
         quotas = (seed or {}).get("mesh_quotas") or {}
         if not quotas:

@@ -116,12 +116,13 @@ class ExecContext:
     # QueryExecution.execute from the tracing contextvar) — keys the
     # live store and EXPLAIN ANALYZE's straggler-finding lookup
     query_id: str | None = field(default=None, repr=False)
-    # persistent-cache warm start (exec/persist_cache.py): the newest
-    # manifest record for this query's plan fingerprint (join/mesh
-    # capacity outcomes of a prior same-fingerprint run) set by
-    # QueryExecution when spark.tpu.cache.dir is configured; executors
-    # of capacity-retry loops read their seed from it and stash this
-    # run's outcomes below for the close-time manifest write
+    # warm start (exec/persist_cache.plan_seed): what a prior run of
+    # this query's plan fingerprint learned — the newest manifest record
+    # (join/mesh capacity outcomes) when spark.tpu.cache.dir is
+    # configured, else the join capacities the process remembers — set
+    # by QueryExecution; executors of capacity-retry loops read their
+    # seed from it and stash this run's outcomes below for the
+    # close-time write to the manifest and the process's memory
     persist_seed: dict | None = field(default=None, repr=False)
     persist_join_caps: list | None = field(default=None, repr=False)
     persist_mesh_quotas: dict | None = field(default=None, repr=False)

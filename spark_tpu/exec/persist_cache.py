@@ -36,7 +36,10 @@ exact-count tests assume no persistent cache unless a test asks):
     record, so the first dispatch compiles the FINAL program of the
     cold run (one engine compile, served from the XLA disk cache)
     instead of replaying the capacity-retry ladder. The plan analyzer
-    mirrors the same lookup (analysis/plan_lint.py).
+    mirrors the same lookup (analysis/plan_lint.py). Without a cache
+    dir the process still remembers each plan's final join capacities
+    (PlanMemory, behind the same lookup, plan_seed): the ladder is
+    climbed once a process, not once an execution.
 
   * **Result cache** (`spark.tpu.cache.result.enabled`, `<dir>/result`)
     — full `plan_fingerprint` + a data-version component (warehouse /
@@ -53,6 +56,7 @@ exact-count tests assume no persistent cache unless a test asks):
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import json
 import os
@@ -68,6 +72,7 @@ __all__ = ["configure", "cache_root", "xla_cache_dir",
            "ResultCache",
            "result_cache_for", "result_key", "result_probe",
            "invalidate_path", "record_manifest", "manifest_seed",
+           "plan_seed", "PlanMemory", "PLAN_MEMORY",
            "mesh_quota_key", "mesh_quota_key_plain", "mesh_quota_key_fused"]
 
 _MANIFEST_RING = 2048
@@ -961,10 +966,9 @@ def record_manifest(conf, fingerprint: dict, tier: dict | None,
 
 
 def manifest_seed(conf, fingerprint_hash: str) -> dict | None:
-    """The newest manifest record for this full fingerprint, or None.
-    Shared by the execution layer (QueryExecution stashes it on the
-    ExecContext) and the plan analyzer's capacity mirrors. Never
-    raises."""
+    """The newest manifest record for this full fingerprint, or None
+    (always None without a cache dir). plan_seed is the door the
+    execution layer and the plan analyzer go through. Never raises."""
     m = _manifest(conf)
     if m is None:
         return None
@@ -976,3 +980,67 @@ def manifest_seed(conf, fingerprint_hash: str) -> dict | None:
         return hit
     except Exception:
         return None
+
+
+# ---------------------------------------------------------------------------
+# what a plan learned, remembered by the process
+# ---------------------------------------------------------------------------
+
+class PlanMemory:
+    """Per full plan fingerprint, the join output capacities the plan's
+    last whole-program execution in THIS process ended with. Process-wide
+    like GLOBAL_KERNEL_CACHE: a cloned session (a tenant of the SQL
+    server) starts from what any other session's execution learned.
+    Capacities only: a remembered key span would make the next execution
+    lower the dense probe variant, another program key and so a compile
+    the first execution did not pay; spans and mesh quotas stay the
+    manifest's, for restarts. Bounded: the oldest fingerprint goes first
+    (a long-running server sees unboundedly many literals)."""
+
+    def __init__(self, max_size: int = 4096):
+        self._lock = threading.Lock()
+        self._caps: "collections.OrderedDict[str, tuple]" = \
+            collections.OrderedDict()        # oldest outcome first
+        self.max_size = max_size
+
+    def get(self, fingerprint_hash: str) -> tuple | None:
+        with self._lock:
+            return self._caps.get(fingerprint_hash)
+
+    def put(self, fingerprint_hash: str, join_caps) -> None:
+        caps = tuple(int(c) for c in join_caps)
+        with self._lock:
+            self._caps[fingerprint_hash] = caps
+            self._caps.move_to_end(fingerprint_hash)
+            while len(self._caps) > self.max_size:
+                self._caps.popitem(last=False)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._caps.clear()
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._caps)
+
+
+PLAN_MEMORY = PlanMemory()
+lockwatch.register("exec.persist_cache.PlanMemory._lock", PLAN_MEMORY,
+                   "_lock")
+
+
+def plan_seed(conf, fingerprint_hash: str) -> dict | None:
+    """What the first attempt of this plan starts from, or None: the
+    manifest's newest record where a cache dir holds one (capacities,
+    spans, quotas: a restart's memory, and with a cache dir every
+    execution's), else the capacities this process remembers, marked
+    `remembered`. The ONE lookup of the execution layer
+    (QueryExecution -> ctx.persist_seed) and of the plan analyzer's
+    capacity mirrors, so the two cannot disagree. Never raises."""
+    rec = manifest_seed(conf, fingerprint_hash)
+    if rec is not None:
+        return rec
+    caps = PLAN_MEMORY.get(fingerprint_hash)
+    if not caps:
+        return None
+    return {"join_caps": list(caps), "remembered": True}

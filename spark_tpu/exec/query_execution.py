@@ -30,6 +30,16 @@ def _unconvert(value, dt):
     return value
 
 
+def _whole_program_joins(plan) -> bool:
+    """A whole-program plan (one chip's or the mesh's) that lowers a
+    join: its first attempt has capacities to start from."""
+    from ..physical.operators import HashJoinExec
+    from ..physical.whole_query import WholeQueryExec
+
+    return isinstance(plan, WholeQueryExec) and any(
+        isinstance(n, HashJoinExec) for n in plan.plan.iter_nodes())
+
+
 class QueryPlanningTracker:
     """Per-query rule/phase timing (reference:
     sqlcat/QueryPlanningTracker.scala — phases via measurePhase, rules
@@ -314,23 +324,26 @@ class QueryExecution:
                 "counters": dict(
                     self.session._metrics.snapshot()["counters"]),
                 "t0": time.perf_counter()}
-        # persistent-cache warm start (exec/persist_cache.py): with a
-        # cache dir configured, seed this query's capacity-retry state
-        # from the newest same-fingerprint manifest record, and snapshot
-        # the XLA disk-cache traffic so the per-query compile.disk_*
-        # metric deltas below attribute disk-served vs true cold
-        # compiles. Pure host work, skipped entirely on the default
-        # (cache dir empty) path.
+        # warm start (exec/persist_cache.py): seed this query's
+        # capacity-retry state from what its plan learned before — the
+        # newest same-fingerprint manifest record with a cache dir
+        # configured, else the join capacities this process remembers
+        # (so a whole-program plan with a join is fingerprinted, cache
+        # dir or not: under a millisecond against the attempt it saves)
+        # — and, with a cache dir, snapshot the XLA disk-cache traffic
+        # so the per-query compile.disk_* metric deltas below attribute
+        # disk-served vs true cold compiles. Pure host work.
         from ..exec import persist_cache as _persist
 
         persist_on = bool(  # tpulint: ignore[host-sync]
             _persist.cache_root(self.session.conf))
         disk_before = _persist.disk_counters() if persist_on else None
-        if persist_on:
+        plan_fp = None
+        if persist_on or _whole_program_joins(plan):
             try:
-                ctx.persist_seed = _persist.manifest_seed(
-                    self.session.conf,
-                    self.plan_fingerprint()["fingerprint"])
+                plan_fp = self.plan_fingerprint()["fingerprint"]
+                ctx.persist_seed = _persist.plan_seed(self.session.conf,
+                                                      plan_fp)
             except Exception:
                 ctx.persist_seed = None
         if getattr(self, "_rc_miss_pending", False):
@@ -408,6 +421,10 @@ class QueryExecution:
         # (one memoized host read per distinct mask identity — the only
         # device read the metrics layer performs, after the last dispatch)
         finalize_plan_metrics(ctx.plan_metrics)
+        if plan_fp and ctx.persist_join_caps:
+            # the process's own memory of this plan's final capacities:
+            # its next execution, from any session, starts from them
+            _persist.PLAN_MEMORY.put(plan_fp, ctx.persist_join_caps)
         if persist_on:
             # per-query XLA disk-cache traffic + the warm-start manifest
             # write (capacity outcomes of this run, keyed by the full

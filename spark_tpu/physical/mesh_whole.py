@@ -45,7 +45,7 @@ from .compile import (
 from .operators import attrs_schema
 from .whole_query import (
     _MAX_PROGRAM_RETRIES, _Collect, _Lowered, _MCol, _ProgramBuilder,
-    WholeQueryExec, _jnp, _record_spans, is_runtime_fault,
+    WholeQueryExec, _jnp, _record_spans, _seeded_caps, is_runtime_fault,
 )
 
 __all__ = ["MeshWholeQueryExec"]
@@ -704,10 +704,7 @@ class MeshWholeQueryExec(WholeQueryExec):
         mesh = _get_mesh(P, axis)
         span, sub = self._program_span(ctx, "mesh-whole")
         seed_rec = getattr(ctx, "persist_seed", None) or {}
-        join_caps: list[int] = [int(c) for c in
-                                (seed_rec.get("join_caps") or ())]
-        if join_caps:
-            ctx.metrics.add("cache.capacity_seeded")
+        join_caps = _seeded_caps(ctx, seed_rec)
         spans_seed = seed_rec.get("join_spans") or None
         mesh_seed = seed_rec.get("mesh_quotas") or {}
         dense_off: set[int] = set()

@@ -1604,6 +1604,20 @@ class _ProgramBuilder:
         return _Lowered(metas, cap, emit)
 
 
+def _seeded_caps(ctx, seed_rec: dict) -> list[int]:
+    """The first attempt's join capacities out of the seed record (empty
+    without one), counted: `cache.capacity_seeded` a seeded first
+    attempt, `cache.capacity_remembered` one whose seed is the
+    process's own memory and not the manifest's."""
+    join_caps = [int(c)  # tpulint: ignore[host-sync]
+                 for c in (seed_rec.get("join_caps") or ())]
+    if join_caps:
+        ctx.metrics.add("cache.capacity_seeded")
+        if seed_rec.get("remembered"):
+            ctx.metrics.add("cache.capacity_remembered")
+    return join_caps
+
+
 def _record_spans(ctx, b: _ProgramBuilder, spans, n_joins: int) -> None:
     """Stash the observed build-side key spans on the context (aligned
     by join id with persist_join_caps) so the close-time manifest write
@@ -1775,19 +1789,18 @@ class WholeQueryExec(PhysicalPlan):
 
     def _execute_whole(self, ctx) -> list:
         span, sub = self._program_span(ctx, "whole")
-        # warm-start seeding (exec/persist_cache.py): a prior same-
-        # fingerprint run's FINAL join output capacities ride the
-        # persistent manifest back onto this process's first attempt, so
-        # a restarted server compiles the final program directly (one
-        # engine compile, served by the XLA disk cache) instead of
-        # replaying the capacity-retry ladder. Absent/short seeds fall
-        # back to the normal per-join defaults; an under-sized seed just
-        # re-enters the ordinary retry loop.
+        # warm-start seeding (exec/persist_cache.plan_seed): a prior
+        # same-fingerprint run's FINAL join output capacities come back
+        # onto this execution's first attempt — from the process's own
+        # memory, so a plan it has run before launches the ladder's
+        # final program at once (already in the KernelCache), or from
+        # the persistent manifest, so a restarted server compiles that
+        # program directly (one engine compile, served by the XLA disk
+        # cache) — instead of replaying the capacity-retry ladder.
+        # Absent/short seeds fall back to the normal per-join defaults;
+        # an under-sized seed just re-enters the ordinary retry loop.
         seed_rec = getattr(ctx, "persist_seed", None) or {}
-        seed = seed_rec.get("join_caps")
-        join_caps: list[int] = [int(c) for c in (seed or ())]
-        if join_caps:
-            ctx.metrics.add("cache.capacity_seeded")
+        join_caps = _seeded_caps(ctx, seed_rec)
         spans_seed = seed_rec.get("join_spans") or None
         dense_off: set[int] = set()
         with span:

@@ -40,6 +40,17 @@ def thread_audit():
         warnings.warn(f"possible thread leak: {[t.name for t in after]}")
 
 
+@pytest.fixture(autouse=True)
+def fresh_plan_memory():
+    """The process remembers each plan's final join capacities
+    (exec/persist_cache.PLAN_MEMORY) and the `spark` fixture lives as
+    long as the process: emptied before every test, so a test that
+    counts a capacity ladder counts the same one in any order."""
+    from spark_tpu.exec.persist_cache import PLAN_MEMORY
+
+    PLAN_MEMORY.clear()
+
+
 @pytest.fixture(scope="session")
 def spark():
     from spark_tpu import TpuSession

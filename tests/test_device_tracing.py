@@ -260,8 +260,14 @@ def test_operator_of_takes_the_innermost_row():
     assert operator_of("x") == (None, None) == operator_of(None)
 
 
-def test_explain_device_renders_every_program_of_the_run(session, capsys):
-    session.conf.set("spark.tpu.cache.dir", "")     # replay the ladder
+def test_explain_device_renders_every_program_of_the_run(session, capsys,
+                                                         monkeypatch):
+    from spark_tpu.exec.persist_cache import PLAN_MEMORY
+
+    # replay the ladder in the traced run: no manifest, and a process
+    # that forgets what the warm run learned
+    session.conf.set("spark.tpu.cache.dir", "")
+    monkeypatch.setattr(PLAN_MEMORY, "put", lambda *_a: None)
     try:
         session.sql(QUERY).explain(mode="device")
     finally:

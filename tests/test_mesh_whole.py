@@ -267,14 +267,34 @@ def test_mesh_join_cap_retry_exact(tiers, spark):
     spark.conf.set("spark.tpu.compile.tier", "stage")
     ref = _rows(q(spark), ["fk"])
     spark.conf.set("spark.tpu.compile.tier", "mesh-whole")
-    pd.testing.assert_frame_equal(ref, _rows(q(spark), ["fk"]),
-                                  check_dtype=False)
-    report = q(spark).query_execution.analysis_report()
-    assert report.predicted_launches.get("mesh_whole", 0) >= 2, \
-        report.predicted_launches
-    measured = _measured(lambda: q(spark))
-    assert report.predicted_launches == measured, (
-        report.predicted_launches, measured, report.render())
+    def executed():
+        """One execution, its rows checked: (launches by kind, the
+        session's counters it moved)."""
+        before, before_k = _counters(spark), dict(KC.launches_by_kind)
+        pd.testing.assert_frame_equal(ref, _rows(q(spark), ["fk"]),
+                                      check_dtype=False)
+        after = _counters(spark)
+        return ({k: v - before_k.get(k, 0)
+                 for k, v in KC.launches_by_kind.items()
+                 if v != before_k.get(k, 0)},
+                {k: v - before.get(k, 0) for k, v in after.items()
+                 if v != before.get(k, 0)})
+
+    # a process that has not run the plan: the mirror predicts the ladder
+    # and the first execution climbs it
+    cold = q(spark).query_execution.analysis_report()
+    ladder = cold.predicted_launches.get("mesh_whole", 0)
+    assert ladder >= 2, cold.predicted_launches
+    kinds, moved = executed()
+    assert kinds.get("mesh_whole") == ladder, (kinds, cold.render())
+    assert moved.get("mesh_whole.dispatches") == ladder
+    # the process remembers the capacities the ladder ended with: a
+    # report taken now predicts one launch, the next execution measures it
+    warm = q(spark).query_execution.analysis_report()
+    assert warm.predicted_launches == {"mesh_whole": 1}, warm.render()
+    kinds, moved = executed()
+    assert kinds == warm.predicted_launches
+    assert moved.get("cache.capacity_remembered") == 1
 
 
 # ---------------------------------------------------------------------------

@@ -454,9 +454,11 @@ def test_whole_query_capacity_seed_collapses_retries(tmp_path):
         fp = s.sql(jq).query_execution.plan_fingerprint()["fingerprint"]
         rec = pc.manifest_seed(s.conf, fp)
         assert rec and rec.get("join_caps"), rec
-        # "warm restart" semantics: every execute re-derives join_caps
-        # from scratch, so even in-process the seed is what collapses
-        # the ladder — one dispatch, zero retries, identical answer
+        # "warm restart" semantics: with a cache dir the manifest's
+        # record is what the first attempt starts from, in this process
+        # as in a restarted one (the process's own memory of the
+        # capacities stands behind it: tests/test_plan_memory.py) —
+        # one dispatch, zero retries, identical answer
         warm_out, warm = run()
         assert warm["whole_query.capacity_retries"] == 0, warm
         assert warm["whole_query.dispatches"] == 1, warm

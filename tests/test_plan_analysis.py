@@ -83,6 +83,23 @@ def _predicted_vs_measured(spark, sql):
     return _predicted_vs_measured_df(lambda: spark.sql(sql))
 
 
+def _whole_tier_cold_then_warm(build):
+    """The whole tier's two predictions, each beside the execution it is
+    of: (report, measured launches) in a process that has not run the
+    plan (tests/conftest.py empties the process's memory before every
+    test), where the capacity ladder is climbed, and again after that
+    execution, which starts from the capacities the first ended with."""
+    out = []
+    for _ in range(2):
+        report = build().query_execution.analysis_report()
+        before = dict(KC.launches_by_kind)
+        build().toArrow()
+        out.append((report, {
+            k: v - before.get(k, 0) for k, v in KC.launches_by_kind.items()
+            if v != before.get(k, 0)}))
+    return out
+
+
 def _assert_exact_df(build):
     report, measured = _predicted_vs_measured_df(build)
     assert report.exact, report.inexact_reasons
@@ -518,6 +535,33 @@ def test_string_minmax_fused_prediction_exact(fusion_conf, data, enabled):
     data.conf.set("spark.tpu.fusion.enabled", enabled)
     _assert_exact(data, "select k, min(s) mn, max(s) mx, count(*) c "
                         "from an_t where v > 0 group by k")
+
+
+@pytest.mark.parametrize("query,ladder", [(Q7, True), (Q_JOIN_AGG, False)],
+                         ids=["overflowing", "fitting"])
+def test_whole_tier_prediction_exact_cold_then_remembered(fusion_conf, data,
+                                                          query, ladder):
+    """On the whole tier the mirror reads what the executor reads
+    (persist_cache.plan_seed): in a process that has not run the plan it
+    predicts the capacity ladder and the first execution climbs it; after
+    that execution it predicts the one program the second one launches.
+    A plan whose joins fit their first capacities is one program both
+    times."""
+    from tpcds_mini import register_tpcds
+
+    register_tpcds(data)
+    data.conf.set("spark.tpu.compile.tier", "whole")
+    try:
+        (cold, first), (warm, second) = _whole_tier_cold_then_warm(
+            lambda: data.sql(query))
+        assert cold.exact and warm.exact, (cold.inexact_reasons,
+                                           warm.inexact_reasons)
+        assert cold.predicted_launches == first, cold.render()
+        assert (first["whole_query"] >= 2) == ladder, first
+        assert warm.predicted_launches == second == {"whole_query": 1}, \
+            warm.render()
+    finally:
+        data.conf.unset("spark.tpu.compile.tier")
 
 
 Q_WINDOW = ("select label, k, sv, avg(sv) over (partition by label) a, "

@@ -177,11 +177,28 @@ def test_q3_prediction_exact_all_tiers(tiers, spark, tier):
         tier, report.predicted_launches, measured)
 
 
+def _executed(session, build):
+    """One execution: its launches by kind, and the session's counters it
+    moved."""
+    before_k = dict(KC.launches_by_kind)
+    before_c = dict(session._metrics.snapshot()["counters"])
+    build().toArrow()
+    after_c = session._metrics.snapshot()["counters"]
+    return ({k: v - before_k.get(k, 0)
+             for k, v in KC.launches_by_kind.items()
+             if v != before_k.get(k, 0)},
+            {k: v - before_c.get(k, 0) for k, v in after_c.items()
+             if v != before_c.get(k, 0)})
+
+
 def test_whole_tier_join_retry_predicted(tiers, spark):
-    """q7's fact-probe joins overflow the initial output buckets: the
-    program re-dispatches with bumped capacities and the analyzer's
-    round-by-round mirror (truncated upstream traces included) predicts
-    the retry dispatches EXACTLY."""
+    """q7's fact-probe joins overflow the initial output buckets. In a
+    process that has not run the plan, the program re-dispatches with
+    bumped capacities and the analyzer's round-by-round mirror (truncated
+    upstream traces included) predicts the FIRST execution's dispatches
+    EXACTLY; the process then remembers the capacities the ladder ended
+    with, so a report taken after that execution predicts one launch and
+    the second execution measures one."""
     from tpcds_mini import register_tpcds
 
     register_tpcds(spark)
@@ -190,12 +207,23 @@ def test_whole_tier_join_retry_predicted(tiers, spark):
         FROM store_sales ss JOIN item i ON ss.ss_item_sk = i.i_item_sk
         JOIN date_dim d ON ss.ss_sold_date_sk = d.d_date_sk
         WHERE d.d_year = 1999 GROUP BY i.i_category"""
-    report = spark.sql(q7).query_execution.analysis_report()
-    assert report.exact, report.inexact_reasons
-    assert report.predicted_launches.get("whole_query", 0) >= 2, \
-        report.predicted_launches
-    measured = _measured(lambda: spark.sql(q7))
-    assert report.predicted_launches == measured
+    cold = spark.sql(q7).query_execution.analysis_report()
+    assert cold.exact, cold.inexact_reasons
+    ladder = cold.predicted_launches.get("whole_query", 0)
+    assert ladder >= 2, cold.predicted_launches
+    kinds, counters = _executed(spark, lambda: spark.sql(q7))
+    assert kinds.get("whole_query") == ladder, (kinds, ladder)
+    assert counters.get("whole_query.dispatches") == ladder
+    assert counters.get("whole_query.capacity_retries") == ladder - 1
+    assert "cache.capacity_seeded" not in counters
+    warm = spark.sql(q7).query_execution.analysis_report()
+    assert warm.exact, warm.inexact_reasons
+    assert warm.predicted_launches == {"whole_query": 1}
+    kinds, counters = _executed(spark, lambda: spark.sql(q7))
+    assert kinds == warm.predicted_launches
+    assert counters.get("whole_query.dispatches") == 1
+    assert "whole_query.capacity_retries" not in counters
+    assert counters.get("cache.capacity_remembered") == 1
 
 
 def test_join_rank_paths_counted_and_shown(tiers, spark, monkeypatch):
