@@ -9,7 +9,9 @@ execution model, where the expensive mistakes are different:
     ``np.asarray(...)`` on device arrays, ``block_until_ready`` outside
     bench code. One sync stalls the async dispatch pipeline
     (utils/device_memo.memo_device_scalars exists precisely to kill
-    these); on transfer-bound transports each is a permanent tax.
+    these, and utils/device_memo.device_read is the one door of those
+    that remain: what it returns is host data); on transfer-bound
+    transports each is a permanent tax.
   * ``row-loop`` — Python-level per-row loops inside ops/ and physical/:
     a ``for`` over ``range(num_rows/capacity)`` is the antithesis of the
     one-dispatch-per-batch contract.
@@ -197,6 +199,48 @@ def _memoized_context(node: ast.AST, memo_names: set,
     return inner.name in memo_names
 
 
+_READ_NAMES = ("device_read",)
+
+
+def _call_name(node: ast.Call) -> str:
+    tgt = node.func
+    return tgt.attr if isinstance(tgt, ast.Attribute) else (
+        tgt.id if isinstance(tgt, ast.Name) else "")
+
+
+def _host_read_names(tree: ast.AST) -> set[tuple]:
+    """(enclosing function, name) of every name bound to what a
+    utils/device_memo.device_read call returned: host copies, read in
+    the one sanctioned transfer of their site."""
+    out: set[tuple] = set()
+    for n in ast.walk(tree):
+        if isinstance(n, ast.Assign) and isinstance(n.value, ast.Call) \
+                and _call_name(n.value) in _READ_NAMES:
+            encl = _enclosing_functions(n, lambdas=True)
+            scope = id(encl[0]) if encl else None
+            for t in n.targets:
+                for sub in ast.walk(t):
+                    if isinstance(sub, ast.Name):
+                        out.add((scope, sub.id))
+    return out
+
+
+def _reads_host_copy(node: ast.AST, host_names: set) -> bool:
+    """True when `node` is (an element, attribute or method result of)
+    a device_read call or a name bound to one: a host value."""
+    while isinstance(node, (ast.Subscript, ast.Attribute, ast.Call)):
+        if isinstance(node, ast.Call):
+            if _call_name(node) in _READ_NAMES:
+                return True
+            node = node.func
+        else:
+            node = node.value
+    if not isinstance(node, ast.Name):
+        return False
+    encl = _enclosing_functions(node, lambdas=True)
+    return (id(encl[0]) if encl else None, node.id) in host_names
+
+
 def _names_used_in_cache_builders(tree: ast.AST) -> set[str]:
     """Function names referenced inside any get_or_build(...) call's
     arguments — module-level kernel builders wrapped at the call site
@@ -276,6 +320,7 @@ def lint_source(source: str, relpath: str,
     pragmas = _pragmas(lines)
     builder_names = _names_used_in_cache_builders(tree)
     memo_names, memo_lambdas = _memo_protected(tree)
+    host_names = _host_read_names(tree)
     entry_lines = _config_entry_arg_lines(tree)
     hot = _in_dirs(relpath, _HOT_DIRS)
     loopable = _in_dirs(relpath, _LOOP_DIRS)
@@ -299,9 +344,11 @@ def lint_source(source: str, relpath: str,
             target = _dotted(node.func)
             memoized = hot and _memoized_context(node, memo_names,
                                                  memo_lambdas)
-            if memoized:
-                # inside the closure handed to memo_device_scalars: the
-                # pull runs once per array identity — sanctioned
+            if memoized or (hot and node.args and _reads_host_copy(
+                    node.args[0], host_names)):
+                # inside the closure handed to memo_device_scalars (the
+                # pull runs once per array identity), or over what a
+                # device_read brought home — sanctioned
                 pass
             elif isinstance(node.func, ast.Attribute) \
                     and node.func.attr == "item" and not node.args and hot:

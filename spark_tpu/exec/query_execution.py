@@ -18,6 +18,7 @@ from ..config import MAX_RESULT_ROWS
 from ..exec.context import ExecContext
 from ..plan.logical import LogicalPlan
 from ..physical.operators import PhysicalPlan, attrs_schema
+from ..utils.device_memo import device_read
 
 
 def _unconvert(value, dt):
@@ -61,20 +62,17 @@ class QueryPlanningTracker:
 
 
 def _planes_to_host(batches) -> dict:
-    """Copy every plane of the result to the host, in the order
-    `ColumnarBatch.to_arrow` reads them. A jax array keeps its host copy,
-    so the Arrow assembly that follows finds it there: the device→host
-    time and the assembly time come apart, and nothing is copied twice."""
-    import numpy as np
-
-    planes = nbytes = 0
-    for b in batches:
-        for a in [b.row_mask] + [x for c in b.columns
-                                 for x in (c.data, c.validity)]:
-            if a is not None:
-                nbytes += np.asarray(a).nbytes  # tpulint: ignore[host-sync]
-                planes += 1
-    return {"planes": planes, "bytes": nbytes}
+    """Copy every plane of the result to the host in one transfer (the
+    sync `collect.d2h`), in the order `ColumnarBatch.to_arrow` reads
+    them; returns {id(plane): host copy}. A jax array keeps its host
+    copy, so the Arrow assembly that follows finds it there: the
+    device→host time and the assembly time come apart, and nothing is
+    copied twice."""
+    planes = [a for b in batches
+              for a in [b.row_mask] + [x for c in b.columns
+                                       for x in (c.data, c.validity)]
+              if a is not None]
+    return dict(zip(map(id, planes), device_read("collect.d2h", *planes)))
 
 
 class QueryExecution:
@@ -209,7 +207,11 @@ class QueryExecution:
         dec.details["history_replanned"] = True
         return WholeQueryExec(plan, dec)
 
-    def execute(self) -> list:
+    def execute(self, finalize_rows: bool = True) -> list:
+        """Run the plan; the partitions of its result batches. With
+        `finalize_rows` False the operators' parked row masks stay
+        unread unless the flight recorder needs them: a collect counts
+        them from its own read of the result planes."""
         from ..config import (KERNEL_ATTRIBUTION, PROGRESS_CONSOLE,
                               PROGRESS_UPDATE_INTERVAL,
                               UI_OPERATOR_METRICS)
@@ -418,9 +420,10 @@ class QueryExecution:
             if eph_token is not None:
                 pop_query(eph_token)
         # query end: resolve row counts parked during sync-free collection
-        # (one memoized host read per distinct mask identity — the only
-        # device read the metrics layer performs, after the last dispatch)
-        finalize_plan_metrics(ctx.plan_metrics)
+        # (one host read of the distinct masks — the only device read the
+        # metrics layer performs, after the last dispatch)
+        if finalize_rows or recorder is not None:
+            finalize_plan_metrics(ctx.plan_metrics)
         if plan_fp and ctx.persist_join_caps:
             # the process's own memory of this plan's final capacities:
             # its next execution, from any session, starts from them
@@ -596,7 +599,7 @@ class QueryExecution:
         try:
             from ..obs.tracing import span_here
 
-            parts = self.execute()
+            parts = self.execute(finalize_rows=False)
             with span_here("collect", cat="phase"):
                 batches = [b for p in parts for b in p]
                 schema = attrs_schema(self.physical.output)
@@ -604,8 +607,7 @@ class QueryExecution:
                     from ..columnar.batch import ColumnarBatch
 
                     batches = [ColumnarBatch.empty(schema)]
-                with span_here("collect.d2h", cat="phase") as d2h:
-                    d2h.set_args(_planes_to_host(batches))
+                host = _planes_to_host(batches)
                 with span_here("collect.arrow", cat="phase"):
                     tables = [b.to_arrow() for b in batches]
                     try:
@@ -617,6 +619,11 @@ class QueryExecution:
                     except pa.lib.ArrowInvalid:
                         out = pa.concat_tables(
                             tables, promote_options="permissive")
+            # the operators' parked row masks, counted from the result's
+            # host copies where they are result planes
+            from ..obs.metrics import finalize_plan_metrics
+
+            finalize_plan_metrics(self._last_ctx.plan_metrics, host)
             limit = int(self.session.conf.get(MAX_RESULT_ROWS))
             if out.num_rows > limit:
                 raise RuntimeError(

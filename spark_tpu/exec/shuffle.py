@@ -26,6 +26,7 @@ from ..columnar.batch import (Column, ColumnarBatch, EMPTY_DICT,
                               StringDict, bucket_capacity)
 from ..exec.context import ExecContext
 from ..types import StringType, StructType, dict_encoded
+from ..utils.device_memo import device_read
 
 Partition = list
 
@@ -252,13 +253,14 @@ def _pull_sorted(batch: ColumnarBatch, perm, counts) -> tuple[list, np.ndarray]:
     """Gather columns by perm on device, transfer to host once."""
     jnp = _jnp()
 
-    gathered = []
-    for c in batch.columns:
-        data = np.asarray(jnp.take(c.data, perm))
-        validity = None if c.validity is None else \
-            np.asarray(jnp.take(c.validity, perm))
-        gathered.append((data, validity, c.dictionary))
-    return gathered, np.asarray(counts)
+    planes, counts = device_read(
+        "shuffle.pull",
+        [(jnp.take(c.data, perm),
+          None if c.validity is None else jnp.take(c.validity, perm))
+         for c in batch.columns], counts)
+    return ([(d, v, c.dictionary) for (d, v), c in zip(planes,
+                                                        batch.columns)],
+            counts)
 
 
 @contextlib.contextmanager
@@ -328,7 +330,10 @@ def hash_partition_batch(batch: ColumnarBatch,
                                                seed=seed),
                                   num_out),
                     num_out)))
-        pids = np.asarray(kernel(key_eqs, key_valids, batch.row_mask))
+        # the pids and the columns they group, in one transfer
+        pids, planes = device_read(
+            "shuffle.pull", kernel(key_eqs, key_valids, batch.row_mask),
+            [(c.data, c.validity) for c in batch.columns])
         try:
             order, counts = native_radix(pids, num_out)
         except Exception:
@@ -336,12 +341,9 @@ def hash_partition_batch(batch: ColumnarBatch,
             counts = np.bincount(
                 pids[pids < num_out], minlength=num_out)
         order = order[: int(counts.sum())]
-        gathered = []
-        for c in batch.columns:
-            data = np.asarray(c.data)[order]
-            validity = None if c.validity is None else \
-                np.asarray(c.validity)[order]
-            gathered.append((data, validity, c.dictionary))
+        gathered = [(d[order], None if v is None else v[order],
+                     c.dictionary)
+                    for (d, v), c in zip(planes, batch.columns)]
         return gathered, counts.astype(np.int64)
     kkey = ("shuffle_hash", cap, num_out, len(keys), seed,
             tuple(str(k.dtype) for k in key_eqs),

@@ -10,6 +10,13 @@ instant of a program's run goes to the innermost event covering it, the
 event to the innermost `mNN.Kind` component of its op_name, and `mNN` is
 row NN of the program's `members`. Runs nowhere on the hot path.
 
+The report ends with the device's gaps in the traced run as the engine
+saw them (`device.gap` spans, obs/tracing.DEVICE): each instant of a gap
+goes to the innermost engine span covering it, summed by span name,
+"outside the engine" where none does. Beside it, the trace's own idle
+over the same run, laid on the engine's clock by the `pc` arg of the
+`st:` annotations.
+
 The stage tier has no such program: a query is hundreds of launches of
 small kernels (`jit_<kind>_<hash>`: physical/compile.stage_jit), each
 shared by every operator whose structure gives its key, so the operator
@@ -37,12 +44,13 @@ import time
 from typing import Sequence
 
 __all__ = ["explain", "scope_map", "operator_of", "attribute",
-           "attribute_launches", "render", "render_launches"]
+           "attribute_launches", "gap_table", "render", "render_launches"]
 
 DEVICE_PLANE = "/device:TPU:"
 CPU_PLANE = "/host:XLA-CPU"      # what _cpu_planes calls its one plane
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 UNATTRIBUTED = "unattributed"
+OUTSIDE = "outside the engine"
 TOP_INSTRUCTIONS = 10
 
 _INSTRUCTION = re.compile(
@@ -234,7 +242,7 @@ def render_launches(found: dict, plan_rows: dict) -> list:
 
 
 def render(runs: list, programs: dict, attempts: list, head: str,
-           stage_lines: Sequence = ()) -> str:
+           stage_lines: Sequence = (), tail: Sequence = ()) -> str:
     """The report. `programs` is {module name: the capture_programs
     record}; `attempts` the traced query's `whole_query.attempt` spans
     in time order, one per run, which say which runs were discarded."""
@@ -275,12 +283,86 @@ def render(runs: list, programs: dict, attempts: list, head: str,
         out.append("the traced run launched no named program or kernel on "
                    "a device plane of the trace (answered from the result "
                    "cache)")
+    out.extend(tail)
     return "\n".join(out)
+
+
+def gap_table(spans: list, t0: float, t1: float) -> dict:
+    """{span name: seconds} of the `device.gap` spans among `spans`
+    (`recorded_spans` dicts), clipped to [t0, t1): each instant of a gap
+    to the innermost (shortest) other span covering it, OUTSIDE where
+    none does."""
+    from .tracing import GAP_SPAN
+
+    iv = [(s["name"], s["ts"], s["ts"] + s["dur_ms"] / 1000.0)
+          for s in spans]
+    out: dict = {}
+    for name, a, b in iv:
+        if name != GAP_SPAN:
+            continue
+        a, b = max(a, t0), min(b, t1)
+        if b <= a:
+            continue
+        over = [(n, lo, hi) for n, lo, hi in iv
+                if n != GAP_SPAN and lo < b and hi > a]
+        cuts = sorted({a, b} | {x for _n, lo, hi in over
+                                for x in (lo, hi) if a < x < b})
+        for x, y in zip(cuts, cuts[1:]):
+            mid = (x + y) / 2
+            under = [(hi - lo, n) for n, lo, hi in over if lo <= mid < hi]
+            where = min(under)[1] if under else OUTSIDE
+            out[where] = out.get(where, 0.0) + (y - x)
+    return out
+
+
+def _busy_ns(intervals: list, lo: int, hi: int) -> int:
+    """Length of the union of (start, duration) intervals within
+    [lo, hi)."""
+    busy, end = 0, lo
+    for start, dur in sorted(intervals):
+        a, b = max(start, end), min(start + dur, hi)
+        if b > a:
+            busy += b - a
+        end = max(end, min(start + dur, hi))
+    return busy
+
+
+def _render_gaps(table: dict, t0: float, t1: float,
+                trace_idle_s: float | None) -> list:
+    gap_s = sum(table.values())
+    idle = "not in the trace" if trace_idle_s is None \
+        else f"{trace_idle_s * 1000:.3f} ms"
+    out = [f"device gaps the engine saw in the traced run: "
+           f"{gap_s * 1000:.3f} ms of {(t1 - t0) * 1000:.3f} ms; the "
+           f"trace's idle over the same run: {idle}"]
+    for name, sec in sorted(table.items(), key=lambda kv: -kv[1]):
+        out.append(f"  {name:<32} {sec * 1000:>12.3f} ms "
+                   f"{100.0 * sec / (gap_s or 1):>6.2f} %")
+    return out
 
 
 # ---------------------------------------------------------------------------
 # from the profiler's file to planes
 # ---------------------------------------------------------------------------
+
+def _clock_offset_ns(xplane_path: str) -> float | None:
+    """The profiler's clock less the engine's (perf_counter, in ns), from
+    the `pc` arg the `st:` annotations carry: the median over them."""
+    from jax.profiler import ProfileData
+
+    from .tracing import ANNOTATION_PREFIX
+
+    offs = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(ANNOTATION_PREFIX):
+                    pc = dict(e.stats).get("pc")
+                    if pc is not None:
+                        offs.append(e.start_ns - float(pc) * 1e9)
+    offs.sort()
+    return offs[len(offs) // 2] if offs else None
+
 
 def _load_planes(xplane_path: str) -> dict:
     from jax.profiler import ProfileData
@@ -351,6 +433,7 @@ def explain(qe) -> str:
         found = sorted(glob.glob(os.path.join(
             tmp, "plugins", "profile", "*", "*.xplane.pb")))
         planes = _load_planes(found[-1]) if found else {}
+        offset = _clock_offset_ns(found[-1]) if found else None
     programs = {rec["program"]: rec for rec in captured}
     scopes = {name: scope_map(rec["kernel"]._kernel.lower(*rec["args"])
                               .compile().as_text())
@@ -368,8 +451,16 @@ def explain(qe) -> str:
     found = attribute_launches(planes, captured.launches, skip=scopes)
     stage_lines = render_launches(found, _plan_rows(traced.physical)) \
         if found["rows"] else ()
+    idle = None
+    if offset is not None and planes:
+        lo, hi = int(t0 * 1e9 + offset), int(t1 * 1e9 + offset)
+        modules = next(iter(planes.values())).get(MODULES_LINE, ())
+        idle = (hi - lo - _busy_ns([(st, d) for _m, st, d in modules],
+                                   lo, hi)) / 1e9
+    gaps = _render_gaps(
+        gap_table(recorded_spans(float("-inf"), t1), t0, t1), t0, t1, idle)
     return render(attribute(planes, scopes), programs, attempts, head,
-                  stage_lines)
+                  stage_lines, gaps)
 
 
 def _plan_rows(physical) -> dict:

@@ -458,31 +458,42 @@ def op_metric_fields(ent: dict | None) -> dict:
             if by and ms > 0 else None}
 
 
-def finalize_plan_metrics(rec: dict | None) -> None:
-    """Resolve parked row masks at query end: one host pull per DISTINCT
+def finalize_plan_metrics(rec: dict | None, host: dict | None = None) -> None:
+    """Resolve parked row masks at query end: one host copy per DISTINCT
     mask identity, deduped QUERY-LOCALLY so masks shared across operators
-    (reorder projections, rewrapped union batches) sync once. A local
-    dict — not the bounded utils/device_memo LRU — because parked masks
-    are per-query temporaries: pushing them through the shared memo could
-    evict the dense-range seeds and cause real kernel re-launches. This
-    is the only device read the metrics layer performs, and it happens
-    after the query's last dispatch."""
+    (reorder projections, rewrapped union batches) count once. `host`
+    maps id(mask) to host copies already made (a collect's read of the
+    result planes); the rest come home in ONE read, the sync
+    `metrics.rows`. A local dict — not the bounded utils/device_memo
+    LRU — because parked masks are per-query temporaries: pushing them
+    through the shared memo could evict the dense-range seeds and cause
+    real kernel re-launches. This is the only device read the metrics
+    layer performs, and it happens after the query's last dispatch."""
     if not rec:
         return
+    host = dict(host or ())
+    pending = {id(m): m for ent in _op_records(rec)
+               for m in ent.get("pending") or () if id(m) not in host}
+    if pending:
+        from ..utils.device_memo import device_read
+
+        try:
+            host.update(zip(pending, device_read("metrics.rows",
+                                                 *pending.values())))
+        except Exception:
+            pass  # the masks stay unread: their rows are a lower bound
     counts: dict[int, int] = {}  # id(mask) -> live rows, this query only
     for ent in _op_records(rec):
-        pending = ent.get("pending")
-        if not pending:
-            continue
-        ent["pending"] = []
-        for mask in pending:
-            try:
-                n = counts.get(id(mask))
-                if n is None:
-                    n = counts[id(mask)] = int(np.asarray(mask).sum())
-                ent["rows"] += n
-            except Exception:
+        masks, ent["pending"] = ent.get("pending"), []
+        for mask in masks or ():
+            got = host.get(id(mask))
+            if got is None:
                 ent["rows_exact"] = False
+                continue
+            n = counts.get(id(mask))
+            if n is None:
+                n = counts[id(mask)] = int(np.asarray(got).sum())
+            ent["rows"] += n
     with _ATTR_LOCK:  # size-changing pop vs the live flush's iteration
         rec.pop(_PARKED_KEY, None)
 

@@ -40,6 +40,23 @@ Three cross-cutting mechanisms ride every span:
     beside the device's timeline, so an idle gap of the device can be
     read off against what the engine was doing.
 
+  * why the device waits — three kinds of span say what the host does
+    while the chip has nothing to run. Every blocking device→host read
+    goes through `utils/device_memo.device_read` and is a span of
+    category `sync` named by its site. `DEVICE` counts the
+    programs `KernelCache` launches and the syncs that drain them: a
+    sync that returns with nothing launched since it began opens a gap,
+    and the next launch closes it as a `device.gap` span (category
+    `gap`, args `after` the sync's site and `before` the launch's
+    kind). A `gc.callbacks` hook makes every collection of the oldest
+    generation, and any of 1 ms or more, a `py.gc` span (category `gc`).
+    The account sees only what `KernelCache` launches: an eager `jnp`
+    call outside it runs on a device the account thinks idle, and the
+    launch's own dispatch and the device's wake-up are not in a gap.
+    Each `st:` annotation carries its span's start on this clock as the
+    arg `pc`, which lays the spans no annotation carries (the gaps) on
+    the profiler's clock.
+
 `recorded_spans(t_from, t_to)` reads the spans of every live tracer of
 the process on the `time.perf_counter()` clock — what a benchmark's
 readers and an in-process debug endpoint call.
@@ -51,7 +68,9 @@ chrome://tracing.
 
 from __future__ import annotations
 
+import collections
 import contextvars
+import gc
 import json
 import threading
 import time
@@ -59,11 +78,15 @@ import uuid
 import weakref
 from typing import Optional
 
-__all__ = ["Tracer", "current_flow", "current_query", "pop_query",
-           "push_query", "recorded_spans", "span_here", "to_chrome_trace"]
+__all__ = ["DEVICE", "DeviceAccount", "Tracer", "current_flow",
+           "current_query", "pop_query", "push_query", "recorded_spans",
+           "span_here", "to_chrome_trace"]
 
 # prefix of the engine's spans in a profiler trace (`/host:CPU`)
 ANNOTATION_PREFIX = "st:"
+GAP_SPAN, GC_SPAN = "device.gap", "py.gc"
+GAP_TRACK = "device"     # the gaps' own track: they cross threads' spans
+GC_MIN_S = 1e-3     # a young collection shorter than this is not a span
 
 
 # ---------------------------------------------------------------------------
@@ -89,9 +112,16 @@ _TRACER: "contextvars.ContextVar" = contextvars.ContextVar(
     "spark_tpu_tracer_scope", default=None)
 
 
+# the tracer of the last query scope entered with one: where a gap or a
+# collection that no query's scope covers is recorded
+_LAST_TRACER: list = [None]
+
+
 def push_query(query_id: str, tracer: "Tracer | None" = None):
     """Enter a query scope (and, when given, its tracer's); returns the
     reset token for pop_query."""
+    if tracer is not None:
+        _LAST_TRACER[0] = weakref.ref(tracer)
     return (_QUERY.set(query_id),
             None if tracer is None else _TRACER.set(tracer))
 
@@ -140,13 +170,14 @@ _NULL_SPAN = _NullSpan()
 _TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first use
 
 
-def _annotation(name: str, qid, args):
-    """The span as the profiler sees it: `st:<name>` with the query id
-    and the scalar args. Entered here, exited by the span."""
+def _annotation(name: str, qid, args, pc: float):
+    """The span as the profiler sees it: `st:<name>` with the query id,
+    the scalar args and `pc`, the span's start on the perf_counter
+    clock. Entered here, exited by the span."""
     global _TraceAnnotation
     if _TraceAnnotation is None:
         from jax.profiler import TraceAnnotation as _TraceAnnotation
-    kw = {} if qid is None else {"query": qid}
+    kw = {"pc": pc} if qid is None else {"query": qid, "pc": pc}
     if args:
         kw.update((k, v) for k, v in args.items()
                   if isinstance(v, (str, int, float)))
@@ -191,8 +222,8 @@ class _Span:
             self.set_args(args)
             self._ftoken = _FLOW.set(fid)
         self._qid = _QUERY.get()
-        self._ann = _annotation(self.name, self._qid, self.args)
         self.t0 = time.perf_counter()
+        self._ann = _annotation(self.name, self._qid, self.args, self.t0)
         # live telemetry reads in-flight spans: register open, drop on
         # close (two dict ops per span — still pure host bookkeeping)
         self.tracer._open_add(self)
@@ -251,6 +282,8 @@ class Tracer:
         self._open: dict[int, "_Span"] = {}
         with _TRACERS_LOCK:
             _TRACERS.add(self)
+            if _gc_callback not in gc.callbacks:
+                gc.callbacks.append(_gc_callback)
 
     @property
     def enabled(self) -> bool:
@@ -363,6 +396,7 @@ class Tracer:
     def since(self, mark: int) -> list[dict]:
         """Spans recorded after mark(), as JSON-friendly dicts (spans the
         ring already evicted are gone — only the tail can be lost)."""
+        _drain_gc()
         with self._lock:
             first = self._seq - len(self._spans)  # seq of oldest buffered
             spans = list(self._spans)[max(0, mark - first):]
@@ -374,12 +408,14 @@ class Tracer:
         The lock covers only the ring snapshot (same profile as
         since()); the tag filter runs outside it so a full 100k-span
         ring never stalls concurrent span recording."""
+        _drain_gc()
         with self._lock:
             spans = list(self._spans)
         return [self._span_dict(s) for s in spans
                 if len(s) > 7 and s[7] == query_id]
 
     def spans(self) -> list:
+        _drain_gc()
         with self._lock:
             return list(self._spans)
 
@@ -413,8 +449,131 @@ def recorded_spans(t_from: float = float("-inf"),
     with _TRACERS_LOCK:
         tracers = list(_TRACERS)
     spans = [s for t in tracers for s in t.spans() if t_from <= s[2] < t_to]
+    gap = DEVICE.open_gap()
+    if gap is not None and t_from <= gap[2] < t_to:
+        spans.append(gap)
     spans.sort(key=lambda s: s[2])
     return [Tracer._span_dict(s) for s in spans]
+
+
+# ---------------------------------------------------------------------------
+# Why the device waits: the launch/sync account and the collector's pauses
+# ---------------------------------------------------------------------------
+
+def _recording_tracer(scoped):
+    """`scoped` (the tracer of a query scope), else the last one a query
+    scope was entered with; None where there is none or it is off."""
+    t = scoped
+    if t is None:
+        ref = _LAST_TRACER[0]
+        t = None if ref is None else ref()
+    return t if t is not None and t.enabled else None
+
+
+class DeviceAccount:
+    """When the device has work, as the engine knows it.
+
+    `launched` is the sequence number of the last program `KernelCache`
+    launched, `by_query` the last one each query scope launched, and
+    `drained` the highest launch a returned sync has waited for: the
+    device runs programs in launch order, so a sync of a query whose last
+    launch was s has, when it returns, drained every program launched up
+    to s — and not another query's launched since (a tenant's read while
+    the other tenant's program runs). When a sync returns with `drained
+    == launched` a gap opens; the next launch, from any thread, closes
+    it and records a `device.gap` span, on a track of its own, on the
+    tracer in scope there, else the last one a query ran with. Cost: a
+    lock and a clock read per sync, a lock per launch and a clock read
+    per launch that closes a gap. Blind to device work outside
+    `KernelCache` (eager `jnp` calls), to the launch's own dispatch and
+    to the device's wake-up: they are idle here and busy in a device
+    trace, or the other way round."""
+
+    def __init__(self, clock=time.perf_counter):
+        self.clock = clock
+        self.lock = threading.Lock()
+        self.launched = 0
+        self.by_query: dict = {}     # query id -> its last launch
+        self.drained = 0
+        self.gap = None          # (start, the site of the sync) while open
+
+    def launch(self, kind) -> None:
+        qid = _QUERY.get()
+        with self.lock:
+            self.launched += 1
+            self.by_query[qid] = self.launched
+            if len(self.by_query) > _MAX_QUERIES:
+                del self.by_query[next(iter(self.by_query))]
+            gap, self.gap = self.gap, None
+        if gap is not None:
+            tracer = _recording_tracer(_TRACER.get())
+            if tracer is not None:
+                tracer._record(GAP_SPAN, "gap", gap[0],
+                               self.clock() - gap[0], 0, GAP_TRACK,
+                               {"after": gap[1], "before": str(kind)},
+                               qid)
+
+    def sync_begin(self) -> int:
+        """The last launch of the query in scope: what the sync drains."""
+        return self.by_query.get(_QUERY.get(), 0)
+
+    def sync_end(self, begun: int, site: str) -> None:
+        now = self.clock()
+        with self.lock:
+            if begun > self.drained:
+                self.drained = begun
+            if self.gap is None and self.drained == self.launched:
+                self.gap = (now, site)
+
+    def open_gap(self):
+        """The gap open now, up to now, as a raw span of the last
+        tracer a query ran with; None if none is open or tracing is
+        off there."""
+        gap = self.gap
+        if gap is None or _recording_tracer(None) is None:
+            return None
+        return (GAP_SPAN, "gap", gap[0], self.clock() - gap[0], 0,
+                GAP_TRACK, {"after": gap[1], "before": ""}, None)
+
+
+_MAX_QUERIES = 1024     # query scopes the account remembers a launch of
+DEVICE = DeviceAccount()
+
+# collections waiting to become spans: the collector may run inside any
+# lock a tracer or the conf holds, so its callback takes none and the
+# next read of spans records them
+_GC_PENDING: "collections.deque" = collections.deque(maxlen=4096)
+# race-lint: ignore[worker-reinit] — the start of the collection now
+# running in this process: each process times its own collector
+_GC_START = [0.0]
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC_START[0] = time.perf_counter()
+        return
+    t0 = _GC_START[0]
+    dur = time.perf_counter() - t0
+    gen = info.get("generation", 0)
+    if gen < 2 and dur < GC_MIN_S:
+        return
+    t = threading.current_thread()
+    _GC_PENDING.append((t0, dur, t.ident, t.name,
+                        {"generation": gen,
+                         "collected": info.get("collected", 0)},
+                        _TRACER.get(), _QUERY.get()))
+
+
+def _drain_gc() -> None:
+    while _GC_PENDING:
+        try:
+            t0, dur, ident, tname, args, scoped, qid = \
+                _GC_PENDING.popleft()
+        except IndexError:
+            return
+        tracer = _recording_tracer(scoped)
+        if tracer is not None:
+            tracer._record(GC_SPAN, "gc", t0, dur, ident, tname, args, qid)
 
 
 def _flow_events(complete: list) -> list:

@@ -31,7 +31,7 @@ from ..obs.metrics import (
     record_kernel_launch as _obs_launch,
     record_kernel_miss as _obs_miss,
 )
-from ..obs.tracing import span_here as _span_here
+from ..obs.tracing import DEVICE as _DEVICE, span_here as _span_here
 from ..expr.expressions import (
     Alias, AttributeReference, Expression, Literal, SortOrder,
 )
@@ -392,6 +392,8 @@ class KernelCache:
             if got is not None:
                 got.launches.append((module_name(f), str(kind),
                                      _obs_op_row()))
+            # the device has work from here: closes a gap a sync opened
+            _DEVICE.launch(kind)
             if first:
                 import time as _time
 

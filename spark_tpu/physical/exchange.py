@@ -312,19 +312,20 @@ def _batch_key_samples(batch: ColumnarBatch, kpos: int, f,
     mask) identity (utils/device_memo.memo_device_scalars): repeated
     range exchanges over device-cached scan batches sync once, not once
     per batch per query."""
-    from ..utils.device_memo import memo_device_scalars
+    from ..utils.device_memo import device_read, memo_device_scalars
 
     col = batch.columns[kpos]
 
     def compute():
-        mask = np.asarray(batch.row_mask)
+        mask, data, valid = device_read("exchange.sample", batch.row_mask,
+                                        col.data, col.validity)
+        mask = np.asarray(mask, dtype=bool)
         if isinstance(f.dataType, StringType):
             vals = col.to_numpy(np.nonzero(mask)[0][:per_part_sample])
             return tuple(v for v in vals if v is not None)
-        data = np.asarray(col.data)[mask][:per_part_sample]
-        if col.validity is not None:
-            vmask = np.asarray(col.validity)[mask][:per_part_sample]
-            data = data[vmask[: len(data)]]
+        data = data[mask][:per_part_sample]
+        if valid is not None:
+            data = data[valid[mask][:per_part_sample][: len(data)]]
         return tuple(data.tolist())
 
     return memo_device_scalars(

@@ -41,15 +41,17 @@ def _batch_numeric_samples(b: ColumnarBatch, kpos: int) -> np.ndarray:
     identity (utils/device_memo.memo_device_scalars): repeated external
     sorts over device-cached batches pull samples to host once, not once
     per batch per pass. Treat the returned array as immutable."""
-    from ..utils.device_memo import memo_device_scalars
+    from ..utils.device_memo import device_read, memo_device_scalars
 
     col = b.columns[kpos]
 
     def compute():
-        mask = np.asarray(b.row_mask)
-        keys = np.asarray(col.sort_keys())[mask]
-        if col.validity is not None:
-            keys = keys[np.asarray(col.validity)[mask]]
+        mask, keys, valid = device_read("sort.sample", b.row_mask,
+                                        col.sort_keys(), col.validity)
+        mask = np.asarray(mask, dtype=bool)
+        keys = keys[mask]
+        if valid is not None:
+            keys = keys[valid[mask]]
         if keys.dtype.kind == "f":
             keys = keys[~np.isnan(keys)]
         return keys[:_SAMPLE_PER_BATCH]
@@ -73,11 +75,13 @@ def _sample_numeric_bounds(part, kpos: int, num_buckets: int):
 def _batch_string_samples(b: ColumnarBatch, kpos: int) -> tuple:
     """Live non-null string samples for one batch, memoized like the
     numeric path (selection_indices syncs the mask otherwise)."""
-    from ..utils.device_memo import memo_device_scalars
+    from ..utils.device_memo import device_read, memo_device_scalars
 
     col = b.columns[kpos]
 
     def compute():
+        # one transfer; the host copies are what the two reads below see
+        device_read("sort.sample", b.row_mask, col.data, col.validity)
         sel = b.selection_indices()[:_SAMPLE_PER_BATCH]
         vals = col.to_numpy(sel)
         return tuple(v for v in vals if v is not None)

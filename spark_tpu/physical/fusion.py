@@ -41,6 +41,7 @@ from ..types import (
 )
 from ..columnar.batch import Column, ColumnarBatch, bucket_capacity
 from ..obs.metrics import batch_cost_scope
+from ..utils.device_memo import device_read
 from .aggregates import FUSABLE_OPS
 from .compile import (
     GLOBAL_KERNEL_CACHE, bind_inputs, canonical_key, pipeline_columns,
@@ -852,20 +853,16 @@ class ExchangeFusion:
                 np.int32(start % num_out), self._bounds_dev, kluts,
                 rf_arg if rf_kind is not None else None)
         fields = attrs_schema(self.pipe_attrs).fields
-        gathered = []
-        for i, f in enumerate(fields):
-            sdict = host_outs[i].sdict if dict_encoded(f.dataType) else None
-            # the shuffle write's ONE intended sync point: map output
-            # lands in host buffers for IPC/reduce-buffer slicing
-            gathered.append((
-                np.asarray(g_datas[i]),  # tpulint: ignore[host-sync]
-                None if g_valids[i] is None
-                else np.asarray(g_valids[i]),  # tpulint: ignore[host-sync]
-                sdict))
-        counts = np.asarray(counts)  # tpulint: ignore[host-sync]
+        # the shuffle write's ONE intended sync point: map output lands
+        # in host buffers for IPC/reduce-buffer slicing
+        g_datas, g_valids, counts = device_read(
+            "shuffle.pull", g_datas, g_valids, counts)
+        gathered = [(g_datas[i], g_valids[i],
+                     host_outs[i].sdict if dict_encoded(f.dataType)
+                     else None)
+                    for i, f in enumerate(fields)]
         if rf_kind is not None:
-            # counts is already host-side numpy here — no extra sync
-            self.rf_pruned += int(counts[-1])  # tpulint: ignore[host-sync]
+            self.rf_pruned += int(counts[-1])
             counts = counts[:-1]
         return gathered, counts
 
@@ -924,8 +921,8 @@ def runtime_filter_batch(rf: dict, rf_dev, b: ColumnarBatch,
 
     kernel = GLOBAL_KERNEL_CACHE.get_or_build(key, build)
     new_mask, drop = kernel(col.data, col.validity, b.row_mask, op)
-    return (ColumnarBatch(b.schema, b.columns, new_mask),
-            int(drop))  # tpulint: ignore[host-sync]
+    drop, = device_read("rf.pruned", drop)
+    return ColumnarBatch(b.schema, b.columns, new_mask), int(drop)
 
 
 def _aggregate_fusable(agg: HashAggregateExec, compute: ComputeExec) -> bool:

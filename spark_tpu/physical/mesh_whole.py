@@ -39,6 +39,7 @@ from __future__ import annotations
 from ..columnar.batch import Column, ColumnarBatch, bucket_capacity
 from ..errors import ExecutionError
 from ..types import BooleanType, dict_encoded
+from ..utils.device_memo import device_read
 from .compile import (
     GLOBAL_KERNEL_CACHE, module_name, named_jit, note_program,
 )
@@ -768,26 +769,29 @@ class MeshWholeQueryExec(WholeQueryExec):
                                 continue
                             staged.release_consumed()
                         # the round's ONE verdict: every capacity scalar
-                        # of the single dispatch, applied together
-                        with sub("whole_query.verdict"):
-                            bumped = False
-                            for i, nd in enumerate(needed):
-                                n_i = int(nd)  # tpulint: ignore[host-sync]
-                                if n_i > join_caps[i]:
-                                    join_caps[i] = bucket_capacity(n_i)
-                                    bumped = True
-                            for xid, o in zip(b.x_ids, ovfs):
-                                if int(o) > 0:  # tpulint: ignore[host-sync]
-                                    quotas[xid] = quotas[xid] * 2
-                                    ctx.metrics.add(
-                                        "mesh_whole.quota_retries")
-                                    bumped = True
-                            for jid, g in zip(b.guard_jids, guards):
-                                if int(g):  # tpulint: ignore[host-sync]
-                                    dense_off.add(jid)
-                                    ctx.metrics.add(
-                                        "whole_query.dense_guard_retries")
-                                    bumped = True
+                        # of the single dispatch, read in one transfer
+                        # and applied together
+                        needed, ovfs, guards, spans = device_read(
+                            "whole_query.verdict", needed, ovfs, guards,
+                            spans)
+                        bumped = False
+                        for i, nd in enumerate(needed):
+                            n_i = int(nd)
+                            if n_i > join_caps[i]:
+                                join_caps[i] = bucket_capacity(n_i)
+                                bumped = True
+                        for xid, o in zip(b.x_ids, ovfs):
+                            if int(o) > 0:
+                                quotas[xid] = quotas[xid] * 2
+                                ctx.metrics.add(
+                                    "mesh_whole.quota_retries")
+                                bumped = True
+                        for jid, g in zip(b.guard_jids, guards):
+                            if int(g):
+                                dense_off.add(jid)
+                                ctx.metrics.add(
+                                    "whole_query.dense_guard_retries")
+                                bumped = True
                         att.set_args({"program": module_name(kernel),
                                       "discarded": bumped})
                     if bumped:

@@ -461,7 +461,8 @@ def _batch_stats_cache(batch: ColumnarBatch) -> dict:
 # Implementation lives in utils/device_memo (also used by exchange/sort
 # sampling and columnar ingest seeding).
 from ..utils.device_memo import (
-    DENSE_RANGE_KIND, memo_device_scalars as _memo_device_scalars,
+    DENSE_RANGE_KIND, device_read,
+    memo_device_scalars as _memo_device_scalars,
 )
 
 
@@ -488,9 +489,10 @@ def dense_range_stats(kc: Column, row_mask, cap: int):
                         jnp.any(m))
             return stage_jit(kr)
 
-        kmin_d, kmax_d, any_d = GLOBAL_KERNEL_CACHE.get_or_build(
-            rkey, build_range)(kc.data, kc.validity, row_mask)
-        return (int(kmin_d), int(kmax_d), bool(any_d))
+        kmin, kmax, anyl = device_read(
+            "dense.range", *GLOBAL_KERNEL_CACHE.get_or_build(
+                rkey, build_range)(kc.data, kc.validity, row_mask))
+        return (int(kmin), int(kmax), bool(anyl))
 
     return _memo_device_scalars(DENSE_RANGE_KIND,
                                 (kc.data, kc.validity, row_mask), compute)
@@ -1669,7 +1671,8 @@ class HashJoinExec(PhysicalPlan):
                 kernel = GLOBAL_KERNEL_CACHE.get_or_build(key, build_kernel)
                 r = kernel(bindex.sorted_hash, bindex.perm, bkey_eqs,
                            bkey_valids, pkey_eqs, pkey_valids, pb.row_mask)
-                needed = int(r.needed)
+                needed, = device_read("join.needed", r.needed)
+                needed = int(needed)
                 if needed <= out_cap:
                     break
                 out_cap = bucket_capacity(needed)
@@ -1862,7 +1865,7 @@ class HashJoinExec(PhysicalPlan):
         # syncs once, not once per partition
         maxc = _memo_device_scalars(
             ("djoin_maxc", tcap), (kc.data, kc.validity, build.row_mask),
-            lambda: int(maxc_d))
+            lambda: int(device_read("dense.dup", maxc_d)[0]))
         if maxc > 1:
             return None  # duplicate build keys → sorted-probe path
         ctx.metrics.add("join.dense_fast_path")

@@ -66,6 +66,7 @@ from ..columnar.batch import (
 from ..errors import ExecutionError
 from ..expr.expressions import Alias, AttributeReference
 from ..types import BooleanType, StringType, dict_encoded
+from ..utils.device_memo import device_read
 from .aggregates import FUSABLE_OPS
 from .compile import (
     GLOBAL_KERNEL_CACHE, bind_inputs, canonical_key, module_name, named_jit,
@@ -1619,7 +1620,8 @@ def _seeded_caps(ctx, seed_rec: dict) -> list[int]:
 
 
 def _record_spans(ctx, b: _ProgramBuilder, spans, n_joins: int) -> None:
-    """Stash the observed build-side key spans on the context (aligned
+    """Stash the observed build-side key spans (host values, read with
+    the verdict) on the context (aligned
     by join id with persist_join_caps) so the close-time manifest write
     carries them — the NEXT same-fingerprint run seeds the dense
     direct-address probe variant from them (sp[2]=1 means unique)."""
@@ -1627,11 +1629,10 @@ def _record_spans(ctx, b: _ProgramBuilder, spans, n_joins: int) -> None:
         return
     out: list = [None] * n_joins
     for jid, (lo, hi, dup) in zip(b.span_jids, spans):
-        lo_i = int(lo)  # tpulint: ignore[host-sync]
-        hi_i = int(hi)  # tpulint: ignore[host-sync]
+        lo_i, hi_i = int(lo), int(hi)
         if hi_i < lo_i:
             continue  # empty build side: nothing worth seeding
-        uniq = 0 if int(dup) else 1  # tpulint: ignore[host-sync]
+        uniq = 0 if int(dup) else 1
         out[jid] = [lo_i, hi_i, uniq]
     if any(s is not None for s in out):
         ctx.persist_join_spans = out
@@ -1834,27 +1835,30 @@ class WholeQueryExec(PhysicalPlan):
                                      b.scopes)
                         datas, valids, mask, needed, spans, guards = \
                             kernel(b.args)
-                    # the program's ONE capacity verdict: join `needed`
-                    # scalars sync after the single dispatch (the query's
-                    # last device interaction before collect), so this
-                    # span is the host's view of the program's device time
-                    with sub("whole_query.verdict"):
-                        bumped = False
-                        for i, nd in enumerate(needed):
-                            n_i = int(nd)  # tpulint: ignore[host-sync]
-                            if n_i > join_caps[i]:
-                                join_caps[i] = bucket_capacity(n_i)
-                                bumped = True
-                        # dense-probe guards: the seeded span no longer
-                        # covers the build rows (data drifted under the
-                        # fingerprint) — drop the dense variant for that
-                        # join and re-lower
-                        for jid, g in zip(b.guard_jids, guards):
-                            if int(g):  # tpulint: ignore[host-sync]
-                                dense_off.add(jid)
-                                ctx.metrics.add(
-                                    "whole_query.dense_guard_retries")
-                                bumped = True
+                    # the program's ONE capacity verdict: the joins'
+                    # `needed` scalars, the dense guards and the build
+                    # spans come home in one read after the single
+                    # dispatch (the query's last device interaction before
+                    # collect), so this sync span is the host's view of
+                    # the program's device time
+                    needed, guards, spans = device_read(
+                        "whole_query.verdict", needed, guards, spans)
+                    bumped = False
+                    for i, nd in enumerate(needed):
+                        n_i = int(nd)
+                        if n_i > join_caps[i]:
+                            join_caps[i] = bucket_capacity(n_i)
+                            bumped = True
+                    # dense-probe guards: the seeded span no longer
+                    # covers the build rows (data drifted under the
+                    # fingerprint) — drop the dense variant for that
+                    # join and re-lower
+                    for jid, g in zip(b.guard_jids, guards):
+                        if int(g):
+                            dense_off.add(jid)
+                            ctx.metrics.add(
+                                "whole_query.dense_guard_retries")
+                            bumped = True
                     att.set_args({"program": module_name(kernel),
                                   "discarded": bumped,
                                   "window_members": sum(

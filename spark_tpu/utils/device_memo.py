@@ -13,6 +13,13 @@ Users: the dense-range aggregate/join fast-path decision
 (physical/operators.dense_range_stats), the dense-join duplicate-key
 verdict, range-exchange and external-sort key sampling. dev/tpulint.py's
 host-sync rule sanctions reads wrapped in this helper.
+
+`device_read` is the one door of every blocking
+device→host read on the engine's paths, the memo's computes included:
+one transfer a site, one span of category `sync` named by the site
+(obs/tracing), and the launch/sync account that finds the device's
+gaps (`obs/tracing.DEVICE`). The lint sanctions it as it does the
+memo.
 """
 
 from __future__ import annotations
@@ -23,8 +30,8 @@ import threading
 
 from . import lockwatch
 
-__all__ = ["memo_device_scalars", "seed_dense_range_memo",
-           "peek_dense_range", "DENSE_RANGE_KIND"]
+__all__ = ["device_read", "memo_device_scalars",
+           "seed_dense_range_memo", "peek_dense_range", "DENSE_RANGE_KIND"]
 
 _MEMO: "collections.OrderedDict" = collections.OrderedDict()
 _LOCK = threading.Lock()
@@ -34,6 +41,24 @@ _MAX = 4096
 
 # cache-key kind shared by dense_range_stats and the arrow-ingest seeding
 DENSE_RANGE_KIND = ("dense_range",)
+
+
+def device_read(site: str, *arrays) -> tuple:
+    """The host copies of `arrays` (pytrees of arrays; None entries
+    allowed), in one explicit transfer: the sync span `site` (args `site`, `bytes`) on
+    the tracer in scope, and the device account's drain. A jax array
+    keeps its host copy, so a later `np.asarray` of it reads no device."""
+    import jax
+
+    from ..obs.tracing import DEVICE, span_here
+
+    begun = DEVICE.sync_begin()
+    nbytes = sum(int(getattr(a, "nbytes", 0) or 0)
+                 for a in jax.tree_util.tree_leaves(arrays))
+    with span_here(site, "sync", {"site": site, "bytes": nbytes}):
+        host = jax.device_get(arrays)
+    DEVICE.sync_end(begun, site)
+    return host
 
 
 def memo_device_scalars(kind: tuple, arrays: tuple, compute):
