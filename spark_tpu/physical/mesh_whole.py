@@ -46,7 +46,8 @@ from .compile import (
 from .operators import attrs_schema
 from .whole_query import (
     _MAX_PROGRAM_RETRIES, _Collect, _Lowered, _MCol, _ProgramBuilder,
-    WholeQueryExec, _jnp, _record_spans, _seeded_caps, is_runtime_fault,
+    WholeQueryExec, _jnp, _late_take, _record_spans, _seeded_caps,
+    is_runtime_fault,
 )
 
 __all__ = ["MeshWholeQueryExec"]
@@ -167,11 +168,16 @@ class _MeshProgramBuilder(_ProgramBuilder):
         axis = self.axis
         cap = low.cap * self.P
         self.key.append(("torep",))
+        # a deferred column's row numbers are the shard's own: gathered
+        # before the rows leave it
+        cols = range(len(low.metas))
+        self._late_read(low, cols)
 
         def emit(args, needed, _low=low):
             from jax import lax
 
             d, v, m = _low.emit(args, needed)
+            d, v = _late_take(d, v, cols)
 
             def g(x):
                 return None if x is None else lax.all_gather(
@@ -456,12 +462,15 @@ class _MeshProgramBuilder(_ProgramBuilder):
         self._member(
             node, "Exchange[HashPartitioning] -> in-program all_to_all")
         self.x_ids.append(xid)
+        cols = range(len(low.metas))
+        self._late_read(low, cols)
 
         def emit(args, needed, _low=low):
             from ..ops.hashing import hash_columns, partition_ids
             from ..parallel.mesh_fusion import _exchange_tail
 
             d, v, m = _low.emit(args, needed)
+            d, v = _late_take(d, v, cols)
             eqs, kvs = [], []
             for j, i in enumerate(kidx):
                 kd = d[i]
@@ -497,6 +506,8 @@ class _MeshProgramBuilder(_ProgramBuilder):
                          tuple(x[1] for x in luts)))
         self._member(
             node, "Exchange[HashPartitioning] -> in-program pid filter")
+        cols = range(len(low.metas))
+        self._late_read(low, cols)
 
         def emit(args, needed, _low=low):
             from jax import lax
@@ -504,6 +515,7 @@ class _MeshProgramBuilder(_ProgramBuilder):
             from ..ops.hashing import hash_columns, partition_ids
 
             d, v, m = _low.emit(args, needed)
+            d, v = _late_take(d, v, cols)
             eqs, kvs = [], []
             for j, i in enumerate(kidx):
                 kd = d[i]
@@ -729,7 +741,8 @@ class MeshWholeQueryExec(WholeQueryExec):
                                 mesh_seed=mesh_seed, leaf_cache=leaf_cache,
                                 use_base=use_base, gang=gang)
                             gang = False
-                            root = b._to_rep(b.lower(self.plan))
+                            root = b.finish(
+                                b._to_rep(b.lower(self.plan)), self.plan)
                             key = ("mesh_whole", axis, P,
                                    "base" if use_base else "don",
                                    tuple(b.key))

@@ -25,6 +25,11 @@ input signatures, capacities):
     dispatch (the same capacity-bucket retry contract as the per-batch
     kernels — a retry recompiles with the bumped bucket and re-dispatches
     the whole program);
+  * a join hands its build side's columns on as its build row numbers
+    (`_Late`): a later join or sort moves one index plane for all of them,
+    and each column is gathered once, by the first operator that reads
+    its values, at that operator's capacity — before a join whose output
+    is larger than its input, so no column is gathered at a larger one;
   * intermediate stage outputs never materialize as ColumnarBatches —
     they are XLA values inside one program, resident in HBM only for the
     program's lifetime.
@@ -64,7 +69,7 @@ from ..columnar.batch import (
     merge_string_dicts,
 )
 from ..errors import ExecutionError
-from ..expr.expressions import Alias, AttributeReference
+from ..expr.expressions import Alias, AttributeReference, IsNotNull, IsNull
 from ..types import BooleanType, StringType, dict_encoded
 from ..utils.device_memo import device_read
 from .aggregates import FUSABLE_OPS
@@ -692,6 +697,156 @@ class _Lowered(NamedTuple):
     metas: list            # list[_MCol] per output column
     cap: int               # static tile capacity of this flow
     emit: Callable         # emit(args, needed) -> (datas, valids, mask)
+    late: tuple = ()       # per column: the _LateTag of a column the emit
+    #                        hands on as a `_Late`, or None; () for none
+
+
+# ---------------------------------------------------------------------------
+# late materialisation: a join hands its build side's columns on as row
+# numbers, and each column is gathered once, by the first reader of its
+# values, at that reader's capacity
+# ---------------------------------------------------------------------------
+
+class _Rows:
+    """One row-index plane that deferred columns share: `idx` (int32, at
+    the flow's capacity) into their source arrays, and `live` (bool, or
+    None) an outer join's null extension, ANDed into each validity when
+    the column is gathered. Shared by identity: a carry moves it once."""
+
+    __slots__ = ("idx", "live")
+
+    def __init__(self, idx, live=None):
+        self.idx = idx
+        self.live = live
+
+
+class _Late(NamedTuple):
+    """A column a join handed on instead of gathering it: in a flow's data
+    list its values are `src[rows.idx]`; in its validity list `src` is a
+    bool plane or None (every row valid), and `rows.live` is ANDed in. A
+    column's data and validity are handed on and gathered each on its
+    own: IS NULL gathers the validity alone. Exists at trace time only."""
+
+    src: object
+    rows: _Rows
+
+
+class _LateTag(NamedTuple):
+    """What the lowering knows of a deferred column, for the counters."""
+
+    join: int     # the join's place in `_ProgramBuilder.late_joins`
+    col: int      # the column's place on the join's build side
+    fresh: bool   # at the join's own output: nothing has carried it yet
+
+
+def _late_take(datas: list, valids: list, cols, valid_cols=()) -> tuple:
+    """(datas, valids) with the deferred columns among `cols` gathered,
+    and of those among `valid_cols` the validity alone (what IS NULL
+    reads), under `late_gather`; the validity planes of one index plane
+    ride one byte a fetch (`take_planes`)."""
+    flags = [i for i in dict.fromkeys((*cols, *valid_cols))
+             if isinstance(valids[i], _Late)]
+    cols = [i for i in cols if isinstance(datas[i], _Late)]
+    if not cols and not flags:
+        return datas, valids
+    import jax
+
+    from ..ops.joining import take_planes
+
+    jnp = _jnp()
+    datas, valids = list(datas), list(valids)
+    groups: dict = {}
+    for i in flags:
+        groups.setdefault(id(valids[i].rows), []).append(i)
+    with jax.named_scope("late_gather"):
+        for i in cols:
+            datas[i] = jnp.take(datas[i].src, datas[i].rows.idx)
+        for group in groups.values():
+            rows = valids[group[0]].rows
+            planes = take_planes([valids[i].src for i in group],
+                                 lambda w, _r=rows: jnp.take(w, _r.idx))
+            for i, plane in zip(group, planes):
+                if plane is None:
+                    plane = rows.live if rows.live is not None else \
+                        jnp.ones(rows.idx.shape[0], dtype=bool)
+                elif rows.live is not None:
+                    plane = plane & rows.live
+                valids[i] = plane
+    return datas, valids
+
+
+def _late_carry(datas: list, valids: list, fetch, live=None) -> tuple:
+    """(datas, valids) with every deferred column's index plane, and its
+    `live` plane, moved to the next flow's slots by `fetch`: one fetch a
+    distinct plane, however many columns ride on it. `live`, where given,
+    is ANDed into the moved one."""
+    moved: dict = {}
+
+    def move(x):
+        if not isinstance(x, _Late):
+            return x
+        rows = moved.get(id(x.rows))
+        if rows is None:
+            lv = None if x.rows.live is None else fetch(x.rows.live)
+            if live is not None:
+                lv = live if lv is None else lv & live
+            rows = moved[id(x.rows)] = _Rows(fetch(x.rows.idx), lv)
+        return _Late(x.src, rows)
+
+    return [move(x) for x in datas], [move(x) for x in valids]
+
+
+def _take_side(datas: list, valids: list, fetch) -> tuple:
+    """One side of a join at the output's slots: each column by `fetch`,
+    its validity planes as one byte a fetch (`take_planes`); a deferred
+    column by its index plane alone."""
+    from ..ops.joining import take_planes
+
+    datas, valids = _late_carry(datas, valids, fetch)
+    out_d = [x if isinstance(x, _Late) else fetch(x) for x in datas]
+    planes = take_planes([None if isinstance(x, _Late) else x
+                          for x in valids], fetch)
+    return out_d, [x if isinstance(x, _Late) else p
+                   for p, x in zip(planes, valids)]
+
+
+def _hand_on(datas: list, valids: list, idx, live) -> tuple:
+    """A join's build side handed on by the join's build row numbers
+    `idx` instead of gathered: every column a `_Late` on one shared index
+    plane, with `live` (an outer join's matches, else None) ANDed into its
+    validity when it is gathered; a column the side already defers has
+    its own plane moved by `idx`."""
+    jnp = _jnp()
+    datas, valids = _late_carry(datas, valids, lambda x: jnp.take(x, idx),
+                                live)
+    rows = _Rows(idx, live)
+    return ([x if isinstance(x, _Late) else _Late(x, rows) for x in datas],
+            [x if isinstance(x, _Late) else _Late(x, rows) for x in valids])
+
+
+def _late_tag(low: _Lowered, i: int) -> Optional[_LateTag]:
+    return low.late[i] if low.late else None
+
+
+def _bare(expr) -> Optional[AttributeReference]:
+    """The attribute an output expression hands on unchanged, or None."""
+    while isinstance(expr, Alias):
+        expr = expr.child
+    return expr if isinstance(expr, AttributeReference) else None
+
+
+def _refs(expr, values: set, flags: set) -> None:
+    """Add to `values` the attributes whose values `expr` reads, and to
+    `flags` those it reads as the operand of IS [NOT] NULL, which reads
+    the validity alone."""
+    if isinstance(expr, (IsNull, IsNotNull)) \
+            and isinstance(expr.child, AttributeReference):
+        flags.add(expr.child.expr_id)
+    elif isinstance(expr, AttributeReference):
+        values.add(expr.expr_id)
+    else:
+        for c in expr.children:
+            _refs(c, values, flags)
 
 
 class _Collect(list):
@@ -745,6 +900,10 @@ class _ProgramBuilder:
         # subtree lowers AND emits before build subtree before self)
         self.guard_jids: list[int] = []  # dense joins, = guards order
         self.dense_joins: list[int] = [] # joins on the dense fast path
+        # per join, lowering order: [node, build columns it hands on,
+        # those its own consumer gathered (a set of build positions),
+        # the rank note] — `finish` counts them and writes the notes
+        self.late_joins: list = []
 
     # -- plumbing ----------------------------------------------------------
     def arg(self, arr) -> int:
@@ -782,7 +941,47 @@ class _ProgramBuilder:
             with jax.named_scope(label):
                 return _emit(args, needed)
 
-        return _Lowered(low.metas, low.cap, emit)
+        return low._replace(emit=emit)
+
+    def _late_read(self, low: _Lowered, cols) -> None:
+        """A reader gathers the deferred columns among `cols` of `low`: a
+        fresh one is its join's own consumer's, at the join's capacity."""
+        for i in cols:
+            tag = _late_tag(low, i)
+            if tag is not None and tag.fresh:
+                self.late_joins[tag.join][2].add(tag.col)
+
+    def finish(self, root: _Lowered, node) -> _Lowered:
+        """The program's root, with every column it still defers gathered
+        (under the root operator's scope, where it has one); then each
+        join's `late=<handed on>/<build columns>` note, before its rank
+        note, and the counters `join.build_deferred` and
+        `join.build_gathered`, once a program built."""
+        import jax
+
+        cols = range(len(root.metas))
+        self._late_read(root, cols)
+        for jnode, n, gathered, note in self.late_joins:
+            if n:
+                self.ctx.metrics.add("join.build_deferred", n - len(gathered))
+                self.ctx.metrics.add("join.build_gathered", len(gathered))
+                note = f"late={n - len(gathered)}/{n} {note}".rstrip()
+            if note:
+                self._note(jnode, note)
+        if not root.late:
+            return root
+        row = self._member_of.get(id(node))
+        label = None if row is None else self.scopes[row]
+
+        def emit(args, needed, _emit=root.emit):
+            from contextlib import nullcontext
+
+            d, v, m = _emit(args, needed)
+            with jax.named_scope(label) if label else nullcontext():
+                d, v = _late_take(d, v, cols)
+            return d, v, m
+
+        return _Lowered(root.metas, root.cap, emit)
 
     def _lower_node(self, node) -> _Lowered:
         from ..exec.scheduler import _StageOutput
@@ -968,7 +1167,8 @@ class _ProgramBuilder:
                 d, v, m = _low.emit(args, needed)
                 return [d[i] for i in _sel], [v[i] for i in _sel], m
 
-            return _Lowered(metas, low.cap, emit)
+            late = tuple(low.late[i] for i in sel) if low.late else ()
+            return _Lowered(metas, low.cap, emit, late)
         hctx, host_outs, aux = pipeline_host_pass(
             input_attrs, filters, outputs, _MetaView(low.metas))
         aux_idx = [self.arg(a) for a in aux]
@@ -985,14 +1185,39 @@ class _ProgramBuilder:
         in_attrs = list(input_attrs)
         flt = list(filters)
         outs = list(outputs)
+        # the columns whose values the filters and the computed outputs
+        # read are gathered here (of one only IS NULL reads, the validity);
+        # a deferred column an output only hands on stays deferred
+        bare = {j: id_to_pos[a.expr_id] for j, o in enumerate(outs)
+                if (a := _bare(o)) is not None and a.expr_id in id_to_pos}
+        values, flags = set(), set()
+        for e in flt + [o for j, o in enumerate(outs) if j not in bare]:
+            _refs(e, values, flags)
+        reads = sorted(id_to_pos[r] for r in values if r in id_to_pos)
+        nulls = sorted(id_to_pos[r] for r in flags - values
+                       if r in id_to_pos)
+        self._late_read(low, reads)
+        handed = {j: i for j, i in bare.items()
+                  if _late_tag(low, i) is not None and i not in reads}
+        traced = [o for j, o in enumerate(outs) if j not in handed]
 
         def emit(args, needed, _low=low):
             d, v, m = _low.emit(args, needed)
+            d, v = _late_take(d, v, reads, nulls)
             aux_arrs = [args[i] for i in aux_idx]
-            return trace_pipeline(in_attrs, flt, outs, d, v, m, aux_arrs,
-                                  cap)
+            od, ov, mask = trace_pipeline(in_attrs, flt, traced, d, v, m,
+                                          aux_arrs, cap)
+            if not handed:
+                return od, ov, mask
+            od, ov = iter(od), iter(ov)
+            return ([d[handed[j]] if j in handed else next(od)
+                     for j in range(len(outs))],
+                    [v[handed[j]] if j in handed else next(ov)
+                     for j in range(len(outs))], mask)
 
-        return _Lowered(metas, cap, emit)
+        late = tuple(low.late[handed[j]] if j in handed else None
+                     for j in range(len(outs))) if handed else ()
+        return _Lowered(metas, cap, emit, late)
 
     # -- aggregation -------------------------------------------------------
     def _lower_agg(self, node, in_attrs, low: _Lowered) -> _Lowered:
@@ -1042,6 +1267,8 @@ class _ProgramBuilder:
             self._note(node, f"segments[{path}]")
             if path != "scatter":
                 self.key.append(("segments", path))
+        reads = sorted(set(key_idx) | {i for i in val_idx if i >= 0})
+        self._late_read(low, reads)
 
         def pipe_vals(d, v, m):
             vd, vv = [], []
@@ -1087,6 +1314,7 @@ class _ProgramBuilder:
 
                 args_box[0] = args
                 d, v, m = _low.emit(args, needed)
+                d, v = _late_take(d, v, reads)
                 vd, vv = pipe_vals(d, v, m)
                 outs = G.apply_global_ops(ops, vd, vv, m)
                 outs = rank_back(outs)
@@ -1114,6 +1342,7 @@ class _ProgramBuilder:
 
             args_box[0] = args
             d, v, m = _low.emit(args, needed)
+            d, v = _late_take(d, v, reads)
             key_eqs = []
             for i, is_bool in zip(key_idx, key_bool):
                 kd = d[i]
@@ -1193,9 +1422,18 @@ class _ProgramBuilder:
         self._note(node, f"segments[{note}]")
         if "scan" in frames or back == "sort":
             self.key.append(("segments", note))
+        # the keys and values are gathered here; the flow keeps its rows
+        # and order, so every other deferred column stays deferred
+        reads = sorted(set(pk + ok) | {i for i in vi
+                                       if i is not None and i >= 0})
+        self._late_read(low, reads)
+        late = tuple(None if i in reads else t
+                     for i, t in enumerate(low.late)) + (None,) * len(plans) \
+            if low.late else ()
 
         def emit(args, needed, _low=low):
             d, v, m = _low.emit(args, needed)
+            d, v = _late_take(d, v, reads)
 
             def key(i):
                 return d[i].astype(jnp.int32) if is_bool[i] else d[i]
@@ -1211,7 +1449,7 @@ class _ProgramBuilder:
             return (list(d) + [od for od, _ov in outs],
                     list(v) + [ov for _od, ov in outs], m)
 
-        return _Lowered(metas, cap, emit)
+        return _Lowered(metas, cap, emit, late)
 
     # -- limit / sort ------------------------------------------------------
     def _lower_limit(self, node, low: _Lowered) -> _Lowered:
@@ -1225,7 +1463,8 @@ class _ProgramBuilder:
             keep = m & (rank > offset) & (rank <= offset + n)
             return d, v, keep
 
-        return _Lowered(low.metas, low.cap, emit)
+        # reads no column: a deferred one stays deferred
+        return _Lowered(low.metas, low.cap, emit, low.late)
 
     def _lower_sort(self, node, low: _Lowered) -> _Lowered:
         jnp = _jnp()
@@ -1251,6 +1490,12 @@ class _ProgramBuilder:
         kidx_t, specs_t, ranks_t = tuple(kidx), list(specs), list(rank_idx)
         is_bool = tuple(isinstance(low.metas[i].dtype, BooleanType)
                         for i in kidx)
+        # the keys are gathered here; every other deferred column has its
+        # index plane permuted, once a plane
+        self._late_read(low, kidx_t)
+        late = tuple(None if i in kidx_t or t is None else t._replace(
+            fresh=False) for i, t in enumerate(low.late)) \
+            if low.late else ()
 
         def emit(args, needed, _low=low):
             import jax
@@ -1258,6 +1503,7 @@ class _ProgramBuilder:
             from ..ops.sorting import sort_permutation
 
             d, v, m = _low.emit(args, needed)
+            d, v = _late_take(d, v, kidx_t)
             keys, kvalids = [], []
             for j, i in enumerate(kidx_t):
                 kd = d[i]
@@ -1270,12 +1516,14 @@ class _ProgramBuilder:
                 kvalids.append(v[i])
             perm = sort_permutation(keys, kvalids, specs_t, m)
             with jax.named_scope("gather"):
-                out_d = [jnp.take(x, perm) for x in d]
-                out_v = [None if x is None else jnp.take(x, perm)
-                         for x in v]
+                d, v = _late_carry(d, v, lambda x: jnp.take(x, perm))
+                out_d = [x if isinstance(x, _Late) else jnp.take(x, perm)
+                         for x in d]
+                out_v = [x if x is None or isinstance(x, _Late)
+                         else jnp.take(x, perm) for x in v]
                 return out_d, out_v, jnp.take(m, perm)
 
-        return _Lowered(low.metas, low.cap, emit)
+        return _Lowered(low.metas, low.cap, emit, late)
 
     # -- joins -------------------------------------------------------------
     def _eq_lut(self, mc: _MCol):
@@ -1372,15 +1620,35 @@ class _ProgramBuilder:
         else:
             metas = list(probe.metas) + [
                 _MCol(m.dtype, True, m.sdict) for m in build.metas]
+        # what each side gathers before the join (its keys; and every
+        # column it defers where the join's output is larger than the side,
+        # which would otherwise be gathered at that larger capacity), and
+        # what the output defers: the probe side's deferred columns carried
+        # on, and every build column, by the join's own row numbers
+        rec = len(self.late_joins)
+        self.late_joins.append([node, 0 if semi_anti else len(rattrs),
+                                set(), ""])
+        p_reads = self._side_reads(probe, lk, out_cap)
+        b_reads = self._side_reads(build, rk, out_cap)
+        late = tuple(None if i in p_reads or t is None
+                     else t if dense is not None else t._replace(fresh=False)
+                     for i, t in enumerate(probe.late or
+                                           (None,) * len(probe.metas)))
+        if not semi_anti:
+            late += tuple(_LateTag(rec, j, True) for j in range(len(rattrs)))
+        if not any(late):
+            late = ()
         if dense is not None:
             return self._join_dense(node, probe, build, metas, lk, rk,
-                                    dense, semi_anti)
+                                    dense, semi_anti, p_reads, b_reads,
+                                    late)
         from ..columnar.batch import eq_key_dtype
         from ..ops.joining import key_path
 
         key = key_path([eq_key_dtype(build.metas[i].dtype) for i in rk],
                        [eq_key_dtype(probe.metas[i].dtype) for i in lk])
-        self._note_ranks(node, probe.cap, build.cap, out_cap, key)
+        self.late_joins[rec][3] = self._note_ranks(
+            probe.cap, build.cap, out_cap, key)
 
         def eqs_of(d, v, idx, luts, bools, args):
             eqs, valids = [], []
@@ -1403,6 +1671,8 @@ class _ProgramBuilder:
 
             pd, pv, pm = _probe.emit(args, needed)
             bd, bv, bm = _build.emit(args, needed)
+            pd, pv = _late_take(pd, pv, p_reads)
+            bd, bv = _late_take(bd, bv, b_reads)
             beqs, bvalids = eqs_of(bd, bv, rk, rk_luts, rk_bool, args)
             peqs, pvalids = eqs_of(pd, pv, lk, lk_luts, lk_bool, args)
             bi_ = J.build_index(beqs, bvalids, bm, key)
@@ -1432,32 +1702,39 @@ class _ProgramBuilder:
                             (lo_o, hi_o, dup.astype(jnp.int32)))
             with jax.named_scope("gather"):
                 # probe columns by the join's `src` on the body the join
-                # took; each side's validity planes as one byte a fetch
-                datas = [J.take_probe(r, x) for x in pd]
-                valids = J.take_planes(pv, lambda w: J.take_probe(r, w))
+                # took, their validity planes as one byte a fetch; a
+                # deferred one's index plane the same way
+                datas, valids = _take_side(
+                    pd, pv, lambda x: J.take_probe(r, x))
                 if semi_anti:
                     return datas, valids, r.out_mask
-                null_build = ~r.matched
-                planes = J.take_planes(
-                    bv, lambda w: jnp.take(w, r.build_idx))
-                for x, plane in zip(bd, planes):
-                    datas.append(jnp.take(x, r.build_idx))
-                    base = plane if plane is not None \
-                        else jnp.ones(_oc, dtype=bool)
-                    valids.append(base & ~null_build)
-                return datas, valids, r.out_mask
+                bd, bv = _hand_on(bd, bv, r.build_idx,
+                                  r.matched if jt == "left_outer" else None)
+                return datas + bd, valids + bv, r.out_mask
 
-        return _Lowered(metas, out_cap, emit)
+        return _Lowered(metas, out_cap, emit, late)
 
-    def _note_ranks(self, node, pcap: int, bcap: int, out_cap: int,
-                    key: str) -> None:
+    def _side_reads(self, low: _Lowered, keys: tuple, out_cap: int) -> tuple:
+        """The columns one side of a join gathers before the join: its
+        keys and, where the join's output is larger than the side (a
+        fan-out), every column the side defers: gathered there they cost
+        no more than at the join that deferred them."""
+        reads = set(keys)
+        if out_cap > low.cap:
+            reads |= {i for i, t in enumerate(low.late) if t is not None}
+        reads = tuple(sorted(reads))
+        self._late_read(low, reads)
+        return reads
+
+    def _note_ranks(self, pcap: int, bcap: int, out_cap: int,
+                    key: str) -> str:
         """Which body `ops/joining.rank_sorted` takes at each of the sorted
         join's three call sites (probe_join's two ranks of `pcap` hashes in
         `bcap`, _expand's rank of `out_cap` slots in `pcap` offsets), how
         `_expand` has a probe row's values at the output's slots
         (`src_path`; the fill ranks nothing), and what the build side is
         indexed on (`key_path`'s answer, `key`): the same rules the trace
-        asks, counted, and shown in the join's row."""
+        asks, counted; returns the note `finish` ends the join's row with."""
         from ..ops.joining import rank_path, src_path
 
         probe_path = rank_path(bcap, pcap)
@@ -1468,8 +1745,8 @@ class _ProgramBuilder:
             self.ctx.metrics.add(f"join.rank_{expand_path}")
         self.ctx.metrics.add(f"join.src_{src}")
         self.ctx.metrics.add(f"join.key_{key}")
-        self._note(node, f"rank[probe={probe_path},expand={expand_path}] "
-                         f"src={src} key={key}")
+        return (f"rank[probe={probe_path},expand={expand_path}] "
+                f"src={src} key={key}")
 
     def _note(self, node, note: str) -> None:
         """`note` at the end of the node's members row (100 characters)."""
@@ -1477,14 +1754,16 @@ class _ProgramBuilder:
         self.members[row] = f"{self.members[row][:99 - len(note)]} {note}"
 
     def _join_dense(self, node, probe: _Lowered, build: _Lowered, metas,
-                    lk, rk, dense, semi_anti) -> _Lowered:
+                    lk, rk, dense, semi_anti, p_reads, b_reads,
+                    late) -> _Lowered:
         """Dense direct-address probe inside the whole program: the same
         scatter/take body as the per-stage fast path (operators.py), but
         compiled up front from the warm-start manifest's build-key span
         instead of a host-synced value inspection. A guard scalar rides
         the dispatch: if the data drifted off the seeded span (or grew a
         duplicate) the host disables dense for this join and re-lowers —
-        one extra round, never a wrong result."""
+        one extra round, never a wrong result. The probe side keeps its
+        rows and slots; the build side is handed on by `bidx`."""
         jnp = _jnp()
         lo, hi = dense
         tcap = bucket_capacity(hi - lo + 1)
@@ -1498,6 +1777,8 @@ class _ProgramBuilder:
 
             pd, pv, pm = _probe.emit(args, needed)
             bd, bv, bm = _build.emit(args, needed)
+            pd, pv = _late_take(pd, pv, p_reads)
+            bd, bv = _late_take(bd, bv, b_reads)
             bk = bd[rk[0]].astype(jnp.int64)
             bvd = bv[rk[0]]
             blive = bm if bvd is None else (bm & bvd)
@@ -1533,16 +1814,11 @@ class _ProgramBuilder:
                 out_mask = pm & ~matched
             if semi_anti:
                 return list(pd), list(pv), out_mask
-            datas = list(pd)
-            valids = list(pv)
-            for x, xv in zip(bd, bv):
-                datas.append(jnp.take(x, bidx))
-                base = jnp.take(xv, bidx) if xv is not None \
-                    else jnp.ones(pcap, dtype=bool)
-                valids.append(base & matched)
-            return datas, valids, out_mask
+            bd, bv = _hand_on(bd, bv, bidx,
+                              matched if jt == "left_outer" else None)
+            return list(pd) + bd, list(pv) + bv, out_mask
 
-        return _Lowered(metas, pcap, emit)
+        return _Lowered(metas, pcap, emit, late)
 
     # -- union -------------------------------------------------------------
     def _lower_union(self, node, lows: list) -> _Lowered:
@@ -1568,9 +1844,12 @@ class _ProgramBuilder:
                                any(lw.metas[ci].valid for lw in lows),
                                merged))
         self.key.append(("union", tuple(lw.cap for lw in lows)))
+        for lw in lows:
+            self._late_read(lw, range(ncols))
 
         def emit(args, needed):
-            outs = [lw.emit(args, needed) for lw in lows]
+            outs = [(*_late_take(d, v, range(ncols)), m)
+                    for d, v, m in (lw.emit(args, needed) for lw in lows)]
 
             def pad(a, fill):
                 n = sum(lw.cap for lw in lows)
@@ -1813,7 +2092,7 @@ class WholeQueryExec(PhysicalPlan):
                         b = _ProgramBuilder(ctx, join_caps,
                                             spans_seed=spans_seed,
                                             dense_off=dense_off)
-                        root = b.lower(self.plan)
+                        root = b.finish(b.lower(self.plan), self.plan)
                         key = ("whole_query", tuple(b.key))
 
                     def build(_root=root, _key=key, _scopes=b.scopes):

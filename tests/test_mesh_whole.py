@@ -543,6 +543,44 @@ def test_mesh_gang_retry_reuses_base_planes(tiers, spark):
         "device ledger unbalanced after the gang retry"
 
 
+def test_deferred_column_is_gathered_before_the_exchange(tiers, data,
+                                                         monkeypatch):
+    """A join hands its build side on as row numbers; those number the
+    rows of the shard's own source, so a deferred column that meets an
+    in-program all_to_all is gathered before it and crosses as values,
+    same rows as the stage tier's."""
+    import pandas as pd
+
+    from spark_tpu.physical import mesh_whole as MW
+    from spark_tpu.physical.whole_query import _Late
+
+    _need_devices(4)
+    gathered = []
+    take = MW._late_take
+
+    def spy(datas, valids, cols):
+        gathered.extend(i for i in cols if isinstance(datas[i], _Late))
+        return take(datas, valids, cols)
+
+    monkeypatch.setattr(MW, "_late_take", spy)
+
+    def q(s):   # a filter no other test uses: the program is traced here
+        return (s.sql("select mw_t.k k, v, label from mw_t "
+                      "join mw_dim on k = dk where v > 17")
+                .repartition(4, "k").groupBy("label").count())
+
+    data.conf.set("spark.tpu.compile.tier", "stage")
+    ref = _rows(q(data), ["label"])
+    data.conf.set("spark.tpu.compile.tier", "mesh-whole")
+    before = _counters(data).get("join.build_deferred", 0)
+    pd.testing.assert_frame_equal(ref, _rows(q(data), ["label"]),
+                                  check_dtype=False)
+    assert isinstance(q(data).query_execution.physical,
+                      MW.MeshWholeQueryExec)
+    assert _counters(data).get("join.build_deferred", 0) > before
+    assert gathered, "no deferred column reached the exchange"
+
+
 # ---------------------------------------------------------------------------
 # per-stage carry-over: dict-encoded keys fuse into the stage collective
 # ---------------------------------------------------------------------------

@@ -350,7 +350,10 @@ def test_join_src_paths_counted_and_shown(tiers, spark, monkeypatch):
     assert all(m.endswith(note) for m in joins), joins
     text = lowered(rec)
     assert "expand/src_fill" in text and "gather/src_fill" in text
-    assert "gather/pack_valid" in text and "expand/rank_" not in text
+    assert "expand/rank_" not in text
+    # the build side's validity byte left the joins: each build column is
+    # gathered by its reader, one plane of an index plane each here
+    assert "gather/pack_valid" not in text and "/late_gather/" in text
     pd.testing.assert_frame_equal(ref, out, check_dtype=False)
     shown = spark.sql(query).query_execution.explain_string("device")
     assert note in shown, shown
@@ -453,6 +456,240 @@ def test_join_key_paths_counted_and_shown(tiers, spark, monkeypatch):
             m.setattr(J, "build_index", build_index_of_pr33)
             m.setattr(J, "probe_join", probe_join_of_pr33)
             assert lowered(rec, scopes=False) == text
+
+
+# ---------------------------------------------------------------------------
+# late materialisation: a join hands its build side's columns on as row
+# numbers, and each is gathered by the first reader of its values
+# ---------------------------------------------------------------------------
+
+_DATE_FACT = ("from date_dim d join store_sales ss "
+              "on d.d_date_sk = ss.ss_sold_date_sk ")
+# name -> (query, the join kinds its program holds, where deferred columns
+# are gathered: `mNN.<Kind>/late_gather` of these kinds)
+LATE_CASES = {
+    # a fact payload read only after the last of three joins
+    "star": ("select i.i_category, d.d_year, sum(ss.ss_ext_sales_price) s, "
+             "avg(ss.ss_quantity) q, count(*) c " + _DATE_FACT +
+             "join item i on ss.ss_item_sk = i.i_item_sk "
+             "join store st on ss.ss_store_sk = st.s_store_sk "
+             "where d.d_moy = 11 and st.s_state = 'CA' "
+             "group by i.i_category, d.d_year",
+             {"inner"}, {"HashJoin", "HashAggregate"}),
+    # a deferred column is a later join's key on its probe side
+    "key_probe": ("select i.i_brand, sum(ss.ss_net_profit) p " + _DATE_FACT +
+                  "join item i on ss.ss_item_sk = i.i_item_sk "
+                  "where d.d_moy = 3 group by i.i_brand",
+                  {"inner"}, {"HashJoin", "HashAggregate"}),
+    # ... and on its build side, where an outer join null-extends it
+    "key_build": ("select i.i_brand, count(*) c, sum(x.ss_net_profit) p "
+                  "from item i left outer join (select ss.ss_item_sk, "
+                  "ss.ss_net_profit " + _DATE_FACT + "where d.d_moy = 3) x "
+                  "on i.i_item_sk = x.ss_item_sk where i.i_manufact_id < 20 "
+                  "group by i.i_brand",
+                  {"inner", "left_outer"}, {"HashJoin", "HashAggregate"}),
+    # an outer join's null-extended build columns cross a further join
+    "outer": ("select i.i_brand, d.d_year, count(*) c, sum(ss.ss_quantity) q "
+              "from store_sales ss left outer join (select * from item "
+              "where i_manufact_id < 20) i on ss.ss_item_sk = i.i_item_sk "
+              "join date_dim d on ss.ss_sold_date_sk = d.d_date_sk "
+              "where d.d_moy = 5 group by i.i_brand, d.d_year",
+              {"inner", "left_outer"}, {"HashAggregate"}),
+    "semi_anti": ("select d.d_year, count(*) c, sum(ss.ss_quantity) q " +
+                  _DATE_FACT + "left semi join (select i_item_sk from item "
+                  "where i_manufact_id < 10) i on ss.ss_item_sk = i.i_item_sk "
+                  "left anti join (select s_store_sk from store where "
+                  "s_state = 'CA') st on ss.ss_store_sk = st.s_store_sk "
+                  "where d.d_moy = 4 group by d.d_year",
+                  {"inner", "left_semi", "left_anti"},
+                  {"HashJoin", "HashAggregate"}),
+    # dictionary-encoded strings deferred across a join, then grouped on
+    "strings": ("select i.i_brand, st.s_state, count(*) c " + _DATE_FACT +
+                "join item i on ss.ss_item_sk = i.i_item_sk "
+                "join store st on ss.ss_store_sk = st.s_store_sk "
+                "where d.d_moy = 2 group by i.i_brand, st.s_state",
+                {"inner"}, {"HashJoin", "HashAggregate"}),
+    # a deferred column read by a filter the next join's probe side fuses
+    "probe_filter": ("select d.d_year, count(*) c, sum(ss.ss_sales_price) p "
+                     + _DATE_FACT + "join item i on ss.ss_item_sk = "
+                     "i.i_item_sk where ss.ss_quantity > i.i_manufact_id "
+                     "and d.d_moy < 7 group by d.d_year",
+                     {"inner"}, {"HashJoin", "HashAggregate"}),
+}
+
+
+def _lowered(rec, scopes=True):
+    """A captured program's text, traced by a function of its own so
+    that nothing traced before is reused."""
+    import jax
+
+    fn = rec["kernel"]._kernel.__wrapped__
+    return jax.jit(lambda *a: fn(*a)).lower(*rec["args"]).as_text(
+        debug_info=scopes)
+
+
+@pytest.mark.parametrize("case", list(LATE_CASES))
+def test_late_columns_give_the_stage_tiers_rows(tiers, spark, case):
+    """Every shape a deferred column takes through a program gives the
+    stage tier's rows: read after the last join, a later join's key on
+    either side, null-extended by an outer join, through semi and anti
+    joins, a string, read by a filter fused into a join's probe side. The
+    program shows where the deferred columns were gathered."""
+    import re
+
+    import pandas as pd
+
+    from spark_tpu.physical.compile import capture_programs
+    from tpcds_mini import register_tpcds
+
+    register_tpcds(spark)
+    query, kinds, readers = LATE_CASES[case]
+    spark.conf.set("spark.tpu.compile.tier", "stage")
+    ref = spark.sql(query).toArrow().to_pandas()
+    assert len(ref)
+    spark.conf.set("spark.tpu.compile.tier", "whole")
+    before = spark._metrics.snapshot()["counters"].get(
+        "join.build_deferred", 0)
+    df = spark.sql(query)
+    with capture_programs() as programs:
+        out = df.toArrow().to_pandas()
+    assert spark._metrics.snapshot()["counters"].get(
+        "join.build_deferred", 0) > before
+    by = list(ref.columns)
+    pd.testing.assert_frame_equal(
+        ref.sort_values(by).reset_index(drop=True),
+        out.sort_values(by).reset_index(drop=True), check_dtype=False)
+    joins = [n for n in df.query_execution.physical.plan.iter_nodes()
+             if type(n).__name__ == "HashJoinExec"]
+    assert {j.join_type for j in joins} == kinds
+    if case == "probe_filter":
+        assert any(j.probe_fusion is not None and j.probe_fusion[0]
+                   for j in joins)
+    rec = programs[-1]
+    rows = [m for s, m in zip(rec["scopes"], rec["members"])
+            if s and s.endswith(".HashJoin")]
+    assert any(" late=" in m for m in rows), rows
+    found = set(re.findall(r"m\d+\.(\w+)/late_gather", _lowered(rec)))
+    assert found == readers, found
+
+
+def test_join_hands_build_columns_on_as_row_numbers(tiers, spark):
+    """Two joins: the first hands the fact table's columns on as its build
+    row numbers and the second carries that one plane for them, so no
+    payload is gathered at the first join's output capacity; the payload
+    is gathered once, from the fact table, at the aggregate's capacity,
+    its two validity planes as one byte. Counted per program built and
+    shown in the join's row, before its rank note, and in explain."""
+    import re
+
+    import pandas as pd
+
+    from spark_tpu.exec.persist_cache import PLAN_MEMORY
+    from spark_tpu.physical.compile import capture_programs
+
+    rng = np.random.default_rng(40)
+    n = 6000
+    spark.createDataFrame(pa.table({
+        "k1": rng.integers(0, 50, n).astype(np.int32),
+        "k2": rng.integers(0, 40, n).astype(np.int32),
+        "a": pa.array(rng.random(n), mask=rng.random(n) < 0.1),
+        "b": pa.array(rng.integers(-50, 100, n), mask=rng.random(n) < 0.1),
+    })).createOrReplaceTempView("lf_fact")
+    spark.createDataFrame(pa.table({
+        "dk1": np.arange(50, dtype=np.int32),
+        "flag": np.arange(50) % 5 == 0,
+    })).createOrReplaceTempView("lf_d1")
+    spark.createDataFrame(pa.table({
+        "dk2": np.arange(40, dtype=np.int32),
+        "y": [f"y{i % 4}" for i in range(40)],
+    })).createOrReplaceTempView("lf_d2")
+    query = ("select d2.y, sum(f.a) sa, sum(f.b) sb, count(*) c from lf_d1 d1 "
+             "join lf_fact f on d1.dk1 = f.k1 join lf_d2 d2 on f.k2 = d2.dk2 "
+             "where d1.flag and d2.dk2 < 8 group by d2.y order by d2.y")
+    spark.conf.set("spark.tpu.compile.tier", "stage")
+    ref = spark.sql(query).toArrow().to_pandas()
+    assert len(ref) == 4
+    spark.conf.set("spark.tpu.compile.tier", "whole")
+    names = ("join.build_deferred", "join.build_gathered")
+
+    def counts():
+        c = spark._metrics.snapshot()["counters"]
+        return {x: c.get(x, 0) for x in names}
+
+    before = counts()
+    df = spark.sql(query)
+    with capture_programs() as programs:
+        out = df.toArrow().to_pandas()
+    pd.testing.assert_frame_equal(ref, out, check_dtype=False)
+    progs = len(programs)
+    # the fact table's join hands on its four columns and its consumer,
+    # the next join, gathers one (its key); the next join hands on lf_d1's
+    assert {x: c - before[x] for x, c in counts().items()} == {
+        "join.build_deferred": 4 * progs, "join.build_gathered": progs}
+    rec = programs[-1]
+    rows = [m for s, m in zip(rec["scopes"], rec["members"])
+            if s and s.endswith(".HashJoin")]
+    assert len(rows) == 2
+    assert re.search(r" late=3/4 rank\[.*\] src=\w+ key=exact$", rows[1])
+    assert re.search(r" late=1/1 rank\[.*\] src=\w+ key=exact$", rows[0])
+    first, second = PLAN_MEMORY.get(
+        df.query_execution.plan_fingerprint()["fingerprint"])
+    assert first != second
+    takes = re.findall(r"call @_take\w*\(.*\) : \(tensor<(\d+)x(\w+)>, "
+                       r"tensor<\d+xi32>\) -> tensor<(\d+)x", _lowered(rec,
+                                                                 False))
+    payload = [(int(src), int(to)) for src, dt, to in takes
+               if dt in ("f64", "i64")]
+    assert payload and all(to != first for _src, to in payload), payload
+    assert any(src > first and to == second for src, to in payload)
+    assert "late_gather/pack_valid" in _lowered(rec)
+    shown = spark.sql(query).query_execution.explain_string("device")
+    assert " late=3/4 rank[" in shown, shown
+
+
+def test_is_null_gathers_the_validity_alone(tiers, spark, monkeypatch):
+    """q89's shape: the join key a later join needs is checked for NULL in
+    the probe side of the join before it (the inferred IS NOT NULL). That
+    reads the key's validity alone: the validity is gathered there, and
+    the key's values stay deferred to the join that reads them."""
+    import pandas as pd
+
+    from spark_tpu.physical import whole_query as WQ
+    from tpcds_mini import register_tpcds
+
+    register_tpcds(spark)
+    query = ("select i.i_category, st.s_state, d.d_moy, "
+             "sum(ss.ss_sales_price) p from store st join store_sales ss "
+             "on st.s_store_sk = ss.ss_store_sk join item i "
+             "on ss.ss_item_sk = i.i_item_sk join date_dim d "
+             "on ss.ss_sold_date_sk = d.d_date_sk where d.d_year = 1999 "
+             "and i.i_category in ('Books', 'Music') "
+             "group by i.i_category, st.s_state, d.d_moy")
+    spark.conf.set("spark.tpu.compile.tier", "stage")
+    ref = spark.sql(query).toArrow().to_pandas()
+    assert len(ref)
+    flags_alone = []
+    take = WQ._late_take
+
+    def spy(datas, valids, cols, valid_cols=()):
+        flags_alone.extend(i for i in valid_cols
+                           if isinstance(datas[i], WQ._Late)
+                           and isinstance(valids[i], WQ._Late))
+        return take(datas, valids, cols, valid_cols)
+
+    monkeypatch.setattr(WQ, "_late_take", spy)
+    spark.conf.set("spark.tpu.compile.tier", "whole")
+    df = spark.sql(query)
+    out = df.toArrow().to_pandas()
+    by = list(ref.columns)
+    pd.testing.assert_frame_equal(
+        ref.sort_values(by).reset_index(drop=True),
+        out.sort_values(by).reset_index(drop=True), check_dtype=False)
+    fused = [str(f) for n in df.query_execution.physical.plan.iter_nodes()
+             if type(n).__name__ == "HashJoinExec" and n.probe_fusion
+             for f in n.probe_fusion[0]]
+    assert any(f.startswith("isnotnull(ss_sold_date_sk") for f in fused)
+    assert flags_alone
 
 
 # ---------------------------------------------------------------------------
