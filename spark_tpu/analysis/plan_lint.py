@@ -2752,8 +2752,11 @@ class _Analyzer:
     def _whole_join_needed(self, node, ptr, btr):
         """Mirror of ops/joining.probe_join's `needed` scalar over the
         single gathered flow: per live probe row, the count of verified
-        build matches (semi/anti/outer reserve >= 1 slot per live row).
-        None when the keys/values are outside the traced language."""
+        build matches (an outer join reserves >= 1 slot per live row; a
+        semi join takes 1 for a row with a match, an anti join 1 for each
+        live row: `ops/joining._exists`; a hash collision's expansion is
+        not mirrored). None when the keys/values are outside the traced
+        language."""
         if len(node.left_keys) != 1 or ptr is None or btr is None:
             return None
         pent = ptr.cols.get(node.left_keys[0].expr_id)
@@ -2777,7 +2780,11 @@ class _Analyzer:
                 counts = np.where(bvals[idx] == pv, bcounts[idx],
                                   0).astype(np.int64)
         counts = np.where(usable, counts, 0)
-        if node.join_type != "inner":
+        if node.join_type == "left_semi":
+            counts = np.minimum(counts, 1)
+        elif node.join_type == "left_anti":
+            counts = live.astype(np.int64)
+        elif node.join_type != "inner":
             counts = np.maximum(counts, live.astype(np.int64))
         return int(counts.sum())
 

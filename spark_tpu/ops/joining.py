@@ -55,6 +55,22 @@ gathered (by the build row: a permutation's order) takes its validity planes
 along as the bits of one byte (`take_planes`): a gathered byte costs what a
 gathered bool costs.
 
+A semi or anti join asks only whether a probe row has a match, as the
+reference's LeftSemi/LeftAnti hash joins do, so it expands no match
+(`_exists`): a row it may keep has one output slot, and `needed` counts
+those rows. The probe side of a set operation is often a sparse flow (an
+aggregate's output keeps its input's capacity: 468 000 rows in 8 Mi slots
+in TPC-DS q38 and q87 at SF10), so the output, at the capacity those rows
+fill, is also what every later operator runs at (kept at the probe's
+slots under a mask, q38 took 7.09 s a query on a TPU v5e; in the capacity
+its rows fill, as the expansion had it, 4.37 s). On an exact index a row
+has a match
+where its range is not empty. On a hash index the range's first build row
+is compared on the true keys; a range of more than one row whose first row
+is not the probe's key (two keys of one 64-bit hash) leaves the row
+undecided, and the join counts it (`unsure`) so that the caller runs it on
+the expansion instead (`expand=True`), which verifies every pair.
+
 Output capacity overflow is reported via a scalar (`needed`) that the host
 checks to retry at the next capacity bucket (SURVEY.md §7 'Hard parts' (1)).
 """
@@ -275,6 +291,9 @@ class JoinResult(NamedTuple):
     out_mask: jnp.ndarray    # bool[OC] live output rows
     needed: jnp.ndarray      # int32 scalar: total rows the join wanted to emit
     runs: SrcRuns | None = None  # where `src_path` said "fill": take_probe's
+    unsure: jnp.ndarray | None = None  # a semi/anti join on a hash index:
+    #   int32, the probe rows `_exists` could not decide (nonzero: run the
+    #   join again with `expand=True`)
 
 
 def take_probe(r: JoinResult, x: jnp.ndarray) -> jnp.ndarray:
@@ -348,13 +367,16 @@ def probe_join(build: BuildSide,
                probe_key_valids: Sequence[jnp.ndarray | None],
                probe_mask: jnp.ndarray,
                out_capacity: int,
-               join_type: str = "inner", key: str = "hash") -> JoinResult:
+               join_type: str = "inner", key: str = "hash",
+               expand: bool = False) -> JoinResult:
     """join_type: inner | left_outer | left_semi | left_anti.
 
     'left' always refers to the probe side; the planner flips sides for
     right joins (as the reference's planner does for build-side selection,
     sqlx/SparkStrategies.scala join selection). `key` is what `build` was
-    indexed on (`key_path`)."""
+    indexed on (`key_path`). A semi or anti join decides existence, one
+    slot a row it may keep (`_exists`), unless `expand` asks for the
+    expansion, which a caller does where `_exists` left rows `unsure`."""
     pcap = probe_mask.shape[0]
     oc = out_capacity
 
@@ -368,6 +390,13 @@ def probe_join(build: BuildSide,
 
         lo, hi = rank_sorted(build.sorted_hash, ph, "both")
         counts = jnp.where(usable, hi - lo, 0)
+    if join_type in ("left_semi", "left_anti") and not expand:
+        if key == "exact":
+            return _exists(build, (), (), (), probe_mask, oc, join_type,
+                           pcap, lo, counts)
+        return _exists(build, build_key_cols, build_key_valids,
+                       probe_key_cols, probe_mask, oc, join_type, pcap, lo,
+                       counts)
     if key == "exact":
         # the ranges hold the probe's key and nothing else: no key to check
         return _expand(build, (), (), (), (), probe_mask, oc, join_type,
@@ -375,6 +404,58 @@ def probe_join(build: BuildSide,
     return _expand(build, build_key_cols, build_key_valids, probe_key_cols,
                    probe_key_valids, probe_mask, oc, join_type, pcap, lo,
                    counts)
+
+
+@jax.named_scope("exists")
+def _exists(build, build_key_cols, build_key_valids, probe_key_cols,
+            probe_mask, oc, join_type, pcap, lo, counts) -> JoinResult:
+    """probe_join's second loop for a semi or anti join: whether each probe
+    row has a match, one output slot a row that can be kept (a semi join's
+    rows whose range is not empty, an anti join's live rows), in probe
+    order, so `needed` counts those rows and the join's capacity is what
+    they fill, not the probe side's. A slot reaches its row as `_expand`'s
+    do (`src_path`). An exact index hands in no keys: a range holds the
+    probe's key alone. On a hash index the range's first build row is
+    checked on the true keys (the build's null keys are out of the index
+    already): a range of one row that fails holds no match; one of more
+    rows whose first row fails (keys of one hash, interleaved by the
+    stable sort) is `unsure`. `build_idx` is the range's first row."""
+    semi = join_type == "left_semi"
+    ecounts = ((counts > 0) if semi else probe_mask).astype(jnp.int32)
+    offsets = jnp.cumsum(ecounts)
+    total = offsets[pcap - 1] if pcap > 0 else jnp.int32(0)
+    if src_path(pcap, oc) == "fill":
+        runs, src, _ = _src_runs(offsets, ecounts, oc)
+
+        def by_src(x):
+            return _fill(runs, x, oc)
+    else:
+        runs = None
+        src = jnp.minimum(rank_sorted(offsets, lax.iota(jnp.int64, oc),
+                                      "right"), pcap - 1)
+
+        def by_src(x):
+            return jnp.take(x, src)
+    # a slot below `total` is a candidate row's, and that row is live
+    in_range = lax.iota(jnp.int32, oc) < total
+    n = by_src(counts)
+    bidx = jnp.take(build.perm, jnp.minimum(by_src(lo),
+                                            build.perm.shape[0] - 1))
+    found = n > 0
+    unsure = None
+    if build_key_cols:
+        first = found
+        for bc, bv, pc_ in zip(build_key_cols, build_key_valids,
+                               probe_key_cols):
+            eq = jnp.take(bc, bidx) == by_src(pc_)
+            if bv is not None:
+                eq = eq & jnp.take(bv, bidx)
+            first = first & eq
+        unsure = jnp.sum(in_range & (n > 1) & ~first, dtype=jnp.int32)
+        found = first
+    keep = found if semi else ~found
+    return JoinResult(src, bidx, found, in_range & keep,
+                      total.astype(jnp.int64), runs, unsure)
 
 
 @jax.named_scope("src_fill")

@@ -36,7 +36,7 @@ dispatch count exactly, fusion on and off.
 
 from __future__ import annotations
 
-from ..columnar.batch import Column, ColumnarBatch, bucket_capacity
+from ..columnar.batch import Column, ColumnarBatch
 from ..errors import ExecutionError
 from ..types import BooleanType, dict_encoded
 from ..utils.device_memo import device_read
@@ -46,8 +46,8 @@ from .compile import (
 from .operators import attrs_schema
 from .whole_query import (
     _MAX_PROGRAM_RETRIES, _Collect, _Lowered, _MCol, _ProgramBuilder,
-    WholeQueryExec, _jnp, _late_take, _record_spans, _seeded_caps,
-    is_runtime_fault,
+    WholeQueryExec, _apply_needed, _jnp, _late_take, _record_spans,
+    _seeded_caps, _setop_args, is_runtime_fault,
 )
 
 __all__ = ["MeshWholeQueryExec"]
@@ -83,10 +83,10 @@ class _MeshProgramBuilder(_ProgramBuilder):
     they see ordinary [cap] arrays."""
 
     def __init__(self, ctx, join_caps, spans_seed=None, dense_off=None,
-                 *, mesh, axis, num_shards, quotas, mesh_seed,
-                 leaf_cache, use_base, gang):
+                 expand_on=None, *, mesh, axis, num_shards, quotas,
+                 mesh_seed, leaf_cache, use_base, gang):
         super().__init__(ctx, join_caps, spans_seed=spans_seed,
-                         dense_off=dense_off)
+                         dense_off=dense_off, expand_on=expand_on)
         self.mesh = mesh
         self.axis = axis
         self.P = num_shards
@@ -640,6 +640,7 @@ def _build_mesh_program(b: _MeshProgramBuilder, root: _Lowered, key: tuple):
     nov = len(b.x_ids)
     nsp = len(b.span_jids)
     ng = len(b.guard_jids)
+    nu = len(b.unsure_jids)
 
     def local_fn(don, keep):
         from jax import lax
@@ -666,13 +667,14 @@ def _build_mesh_program(b: _MeshProgramBuilder, root: _Lowered, key: tuple):
         spans = tuple((allmin(lo), allmax(hi), allmax(dup))
                       for lo, hi, dup in needed.spans)
         guards = tuple(allmax(g) for g in needed.guards)
-        return (datas, valids, mask, needed_r, ovfs, spans, guards)
+        unsure = tuple(allmax(u) for u in needed.unsure)
+        return (datas, valids, mask, needed_r, ovfs, spans, guards, unsure)
 
     out_specs = ([rep] * len(valid_sig),
                  [rep if hv else None for hv in valid_sig],
                  rep,
                  (rep,) * njoin, (rep,) * nov,
-                 ((rep, rep, rep),) * nsp, (rep,) * ng)
+                 ((rep, rep, rep),) * nsp, (rep,) * ng, (rep,) * nu)
 
     def sharded(don, keep):
         f = shard_map(local_fn, mesh=b.mesh,
@@ -721,6 +723,7 @@ class MeshWholeQueryExec(WholeQueryExec):
         spans_seed = seed_rec.get("join_spans") or None
         mesh_seed = seed_rec.get("mesh_quotas") or {}
         dense_off: set[int] = set()
+        expand_on: set[int] = set()
         quotas: dict[int, int] = {}
         leaf_cache: dict[int, dict] = {}
         use_base = False
@@ -736,7 +739,8 @@ class MeshWholeQueryExec(WholeQueryExec):
                         with sub("whole_query.lower"):
                             b = _MeshProgramBuilder(
                                 ctx, join_caps, spans_seed=spans_seed,
-                                dense_off=dense_off, mesh=mesh, axis=axis,
+                                dense_off=dense_off, expand_on=expand_on,
+                                mesh=mesh, axis=axis,
                                 num_shards=P, quotas=quotas,
                                 mesh_seed=mesh_seed, leaf_cache=leaf_cache,
                                 use_base=use_base, gang=gang)
@@ -759,8 +763,8 @@ class MeshWholeQueryExec(WholeQueryExec):
                             try:
                                 with expected_donation_residue():
                                     (datas, valids, mask, needed, ovfs,
-                                     spans, guards) = kernel(don_args,
-                                                             keep_args)
+                                     spans, guards, unsure) = kernel(
+                                         don_args, keep_args)
                             except Exception as e:
                                 staged.release_all()
                                 if not is_runtime_fault(e) \
@@ -784,15 +788,11 @@ class MeshWholeQueryExec(WholeQueryExec):
                         # the round's ONE verdict: every capacity scalar
                         # of the single dispatch, read in one transfer
                         # and applied together
-                        needed, ovfs, guards, spans = device_read(
+                        needed, ovfs, guards, spans, unsure = device_read(
                             "whole_query.verdict", needed, ovfs, guards,
-                            spans)
-                        bumped = False
-                        for i, nd in enumerate(needed):
-                            n_i = int(nd)
-                            if n_i > join_caps[i]:
-                                join_caps[i] = bucket_capacity(n_i)
-                                bumped = True
+                            spans, unsure)
+                        bumped = _apply_needed(b, needed, unsure, join_caps,
+                                               expand_on)
                         for xid, o in zip(b.x_ids, ovfs):
                             if int(o) > 0:
                                 quotas[xid] = quotas[xid] * 2
@@ -806,7 +806,8 @@ class MeshWholeQueryExec(WholeQueryExec):
                                     "whole_query.dense_guard_retries")
                                 bumped = True
                         att.set_args({"program": module_name(kernel),
-                                      "discarded": bumped})
+                                      "discarded": bumped,
+                                      **_setop_args(b)})
                     if bumped:
                         rounds += 1
                         use_base = True

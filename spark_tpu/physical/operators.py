@@ -1645,39 +1645,48 @@ class HashJoinExec(PhysicalPlan):
         jnp = _jnp()
         jt = self.join_type if self.join_type != "full_outer" else "left_outer"
         if probe_pipe is not None:
-            pb, r = self._fused_probe(pb, bindex, bkey_eqs, bkey_valids,
-                                      ctx, jt, kpath)
+            pb, r, expand = self._fused_probe(pb, bindex, bkey_eqs,
+                                              bkey_valids, ctx, jt, kpath)
         else:
             pkeys = [pb.columns[lpos[k.expr_id]] for k in self.left_keys]
             pkey_eqs = [c.eq_keys() for c in pkeys]
             pkey_valids = [c.validity for c in pkeys]
 
             out_cap = max(pb.capacity, 1 << 10)
+            expand = False
             while True:
                 key = ("join_probe", jt, pb.capacity, build.capacity, out_cap,
                        len(pkey_eqs), tuple(str(k.dtype) for k in pkey_eqs),
                        tuple(v is not None for v in pkey_valids),
-                       tuple(v is not None for v in bkey_valids), kpath)
+                       tuple(v is not None for v in bkey_valids), kpath) + (
+                           ("expand",) if expand else ())
 
-                def build_kernel(oc=out_cap):
+                def build_kernel(oc=out_cap, ex=expand):
                     def kernel(bidx_sorted, bidx_perm, beqs, bvalids, peqs,
                                pvalids, pmask):
                         bi = J.BuildSide(bidx_sorted, bidx_perm)
                         return J.probe_join(bi, beqs, bvalids, peqs, pvalids,
-                                            pmask, oc, jt, kpath)
+                                            pmask, oc, jt, kpath, expand=ex)
 
                     return stage_jit(kernel)
 
                 kernel = GLOBAL_KERNEL_CACHE.get_or_build(key, build_kernel)
                 r = kernel(bindex.sorted_hash, bindex.perm, bkey_eqs,
                            bkey_valids, pkey_eqs, pkey_valids, pb.row_mask)
-                needed, = device_read("join.needed", r.needed)
+                # a semi/anti join's hash index may leave rows undecided:
+                # then the batch is probed again on the expansion
+                needed, unsure = device_read("join.needed", r.needed,
+                                             r.unsure)
+                if unsure is not None and int(unsure):
+                    expand = True
+                    continue
                 needed = int(needed)
                 if needed <= out_cap:
                     break
                 out_cap = bucket_capacity(needed)
                 ctx.metrics.add("join.capacity_retry")
 
+        self._count_setop(jt, expand, ctx)
         probe_out = gather_batch(pb, r.probe_idx, r.out_mask)
         if self.join_type in ("left_semi", "left_anti"):
             return probe_out
@@ -1731,6 +1740,7 @@ class HashJoinExec(PhysicalPlan):
         in_sig = pipeline_signature(pb)
 
         out_cap = max(cap, 1 << 10)
+        expand = False
         while True:
             kkey = ("fused_probe", jt, pipe._struct_key, cap,
                     bindex.perm.shape[0], out_cap, kidx, in_sig,
@@ -1738,9 +1748,10 @@ class HashJoinExec(PhysicalPlan):
                                             for v in bkey_valids),
                     tuple(sorted(dict_pos)),
                     tuple(int(l.shape[0])  # tpulint: ignore[host-sync]
-                          for l in kluts), kpath)
+                          for l in kluts), kpath) + (
+                        ("expand",) if expand else ())
 
-            def build_kernel(oc=out_cap):
+            def build_kernel(oc=out_cap, ex=expand):
                 def kernel(bidx_sorted, bidx_perm, beqs, bvalids, datas,
                            valids, pmask, aux, kluts):
                     out_datas, out_valids, mask = trace_pipeline(
@@ -1761,7 +1772,7 @@ class HashJoinExec(PhysicalPlan):
                         pvalids.append(out_valids[i])
                     bi = J.BuildSide(bidx_sorted, bidx_perm)
                     r = J.probe_join(bi, beqs, bvalids, peqs, pvalids,
-                                     mask, oc, jt, kpath)
+                                     mask, oc, jt, kpath, expand=ex)
                     return r, out_datas, out_valids, mask
 
                 return stage_jit(kernel)
@@ -1771,7 +1782,11 @@ class HashJoinExec(PhysicalPlan):
                 bindex.sorted_hash, bindex.perm, bkey_eqs, bkey_valids,
                 [c.data for c in pb.columns],
                 [c.validity for c in pb.columns], pb.row_mask, aux, kluts)
-            needed = int(r.needed)
+            needed, unsure = device_read("join.needed", r.needed, r.unsure)
+            if unsure is not None and int(unsure):
+                expand = True
+                continue
+            needed = int(needed)
             if needed <= out_cap:
                 break
             out_cap = bucket_capacity(needed)
@@ -1781,7 +1796,15 @@ class HashJoinExec(PhysicalPlan):
         cols = pipeline_columns(pschema.fields, host_outs, out_datas,
                                 out_valids)
         computed = ColumnarBatch(pschema, cols, mask, num_rows=None)
-        return computed, r
+        return computed, r, expand
+
+    @staticmethod
+    def _count_setop(jt: str, expand: bool, ctx) -> None:
+        """`join.semi_exists`/`join.anti_exists` for a semi/anti probe that
+        decided existence, `join.setop_expanded` for one that expanded."""
+        if jt in ("left_semi", "left_anti"):
+            ctx.metrics.add("join.setop_expanded" if expand else
+                            f"join.{jt.removeprefix('left_')}_exists")
 
     def _grace_join(self, lp: Partition, rp: Partition, lschema, rschema,
                     ctx, budget_rows: int, build_cap: int) -> Partition:
@@ -2015,6 +2038,13 @@ class HashJoinExec(PhysicalPlan):
         out_cap = build.capacity
         r = J.probe_join(pi, pkey_eqs, pkey_valids, bkey_eqs, bkey_valids,
                          build.row_mask, out_cap, "left_anti", kpath)
+        expand = r.unsure is not None \
+            and int(device_read("join.unsure", r.unsure)[0]) > 0
+        if expand:
+            r = J.probe_join(pi, pkey_eqs, pkey_valids, bkey_eqs,
+                             bkey_valids, build.row_mask, out_cap,
+                             "left_anti", kpath, expand=True)
+        self._count_setop("left_anti", expand, ctx)
         build_rows = gather_batch(build, r.probe_idx, r.out_mask)
         schema = attrs_schema(self.output)
         nl = len(self._left_attrs)

@@ -853,16 +853,18 @@ class _Collect(list):
     """Emit-time scalar collector. The list body carries per-join
     `needed` capacities (the capacity-retry contract); the side channels
     carry the dense-probe guard verdicts, the observed build-key spans
-    (warm-start manifest food), and per-exchange overflow counts (mesh
-    tier) that ride the SAME single dispatch — all checked once, on the
-    host, after the program returns."""
+    (warm-start manifest food), the rows a semi/anti join's hash index
+    left undecided, and per-exchange overflow counts (mesh tier) that ride
+    the SAME single dispatch — all checked once, on the host, after the
+    program returns."""
 
-    __slots__ = ("spans", "guards", "overflows")
+    __slots__ = ("spans", "guards", "unsure", "overflows")
 
     def __init__(self):
         super().__init__()
         self.spans: list = []      # (lo, hi, dup) per span-observed join
         self.guards: list = []     # violation scalar per dense join
+        self.unsure: list = []     # undecided rows per hash existence join
         self.overflows: list = []  # psum'd overflow per mesh exchange
 
 
@@ -877,7 +879,7 @@ class _ProgramBuilder:
     a single function; XLA fuses across what used to be stage boundaries."""
 
     def __init__(self, ctx, join_caps: list, spans_seed=None,
-                 dense_off=None):
+                 dense_off=None, expand_on=None):
         self.ctx = ctx
         self.args: list = []           # program inputs, in arg-index order
         self.key: list = []            # cache-key fragments
@@ -900,6 +902,13 @@ class _ProgramBuilder:
         # subtree lowers AND emits before build subtree before self)
         self.guard_jids: list[int] = []  # dense joins, = guards order
         self.dense_joins: list[int] = [] # joins on the dense fast path
+        # semi/anti joins: those an earlier attempt's verdict sent to the
+        # expansion (their hash index left rows undecided), the ones whose
+        # undecided count rides the dispatch (= needed.unsure order), and
+        # per semi/anti join (output slots, expanded)
+        self._expand_on = expand_on if expand_on is not None else set()
+        self.unsure_jids: list[int] = []
+        self.setops: list[tuple[int, bool]] = []
         # per join, lowering order: [node, build columns it hands on,
         # those its own consumer gathered (a set of build positions),
         # the rank note] — `finish` counts them and writes the notes
@@ -1609,12 +1618,19 @@ class _ProgramBuilder:
             self.ctx.metrics.add("cache.join_span_seeded")
         if eligible:
             self.span_jids.append(join_id)
+        semi_anti = jt in ("left_semi", "left_anti")
+        # a semi/anti join decides existence (`ops/joining._exists`) unless
+        # an earlier attempt's verdict sent it to the expansion
+        exists = semi_anti and dense is None \
+            and join_id not in self._expand_on
+        if semi_anti:
+            self.setops.append((out_cap, dense is None and not exists))
         self.key.append(("join", jt, lk, rk, out_cap, lk_bool, rk_bool,
                          tuple(x[1] for x in lk_luts),
                          tuple(x[1] for x in rk_luts),
                          ("dense",) + dense if dense is not None
-                         else None, eligible))
-        semi_anti = jt in ("left_semi", "left_anti")
+                         else None, eligible) + (("exists",) if exists
+                                                 else ()))
         if semi_anti:
             metas = list(probe.metas)
         else:
@@ -1628,8 +1644,9 @@ class _ProgramBuilder:
         rec = len(self.late_joins)
         self.late_joins.append([node, 0 if semi_anti else len(rattrs),
                                 set(), ""])
+        # a semi/anti join reads the build side's keys alone
         p_reads = self._side_reads(probe, lk, out_cap)
-        b_reads = self._side_reads(build, rk, out_cap)
+        b_reads = self._side_reads(build, rk, 0 if semi_anti else out_cap)
         late = tuple(None if i in p_reads or t is None
                      else t if dense is not None else t._replace(fresh=False)
                      for i, t in enumerate(probe.late or
@@ -1649,6 +1666,14 @@ class _ProgramBuilder:
                        [eq_key_dtype(probe.metas[i].dtype) for i in lk])
         self.late_joins[rec][3] = self._note_ranks(
             probe.cap, build.cap, out_cap, key)
+        if exists:
+            self.ctx.metrics.add(f"join.{jt.removeprefix('left_')}_exists")
+            self.late_joins[rec][3] += " exists"
+        elif semi_anti:
+            self.ctx.metrics.add("join.setop_expanded")
+            self.late_joins[rec][3] += " expand"
+        if exists and key == "hash":
+            self.unsure_jids.append(join_id)
 
         def eqs_of(d, v, idx, luts, bools, args):
             eqs, valids = [], []
@@ -1677,8 +1702,10 @@ class _ProgramBuilder:
             peqs, pvalids = eqs_of(pd, pv, lk, lk_luts, lk_bool, args)
             bi_ = J.build_index(beqs, bvalids, bm, key)
             r = J.probe_join(bi_, beqs, bvalids, peqs, pvalids, pm, _oc,
-                             jt, key)
+                             jt, key, expand=not exists)
             needed.append(r.needed)
+            if r.unsure is not None:
+                needed.unsure.append(r.unsure)
             if eligible:
                 # observe the build-key span + uniqueness so the NEXT
                 # same-fingerprint run (via the warm-start manifest)
@@ -1898,6 +1925,34 @@ def _seeded_caps(ctx, seed_rec: dict) -> list[int]:
     return join_caps
 
 
+def _apply_needed(b: _ProgramBuilder, needed, unsure, join_caps: list,
+                  expand_on: set) -> bool:
+    """The joins' verdict, read on the host: a join whose `needed` passed
+    its capacity gets the next bucket, and a semi/anti join whose hash
+    index left rows undecided goes to the expansion. True where the
+    program must be lowered again."""
+    again = False
+    for i, nd in enumerate(needed):
+        n_i = int(nd)
+        if n_i > join_caps[i]:
+            join_caps[i] = bucket_capacity(n_i)
+            again = True
+    for jid, u in zip(b.unsure_jids, unsure):
+        if int(u):
+            expand_on.add(jid)
+            again = True
+    return again
+
+
+def _setop_args(b: _ProgramBuilder) -> dict:
+    """A `whole_query.attempt` span's account of its semi/anti joins: how
+    many, the output slots they allocate, and how many took the
+    expansion."""
+    return {"setop_members": len(b.setops),
+            "setop_slots": sum(s for s, _ in b.setops),
+            "setop_expanded": sum(e for _, e in b.setops)}
+
+
 def _record_spans(ctx, b: _ProgramBuilder, spans, n_joins: int) -> None:
     """Stash the observed build-side key spans (host values, read with
     the verdict) on the context (aligned
@@ -2083,6 +2138,7 @@ class WholeQueryExec(PhysicalPlan):
         join_caps = _seeded_caps(ctx, seed_rec)
         spans_seed = seed_rec.get("join_spans") or None
         dense_off: set[int] = set()
+        expand_on: set[int] = set()
         with span:
             for attempt in range(_MAX_PROGRAM_RETRIES):
                 with sub("whole_query.attempt",
@@ -2091,7 +2147,8 @@ class WholeQueryExec(PhysicalPlan):
                     with sub("whole_query.lower"):
                         b = _ProgramBuilder(ctx, join_caps,
                                             spans_seed=spans_seed,
-                                            dense_off=dense_off)
+                                            dense_off=dense_off,
+                                            expand_on=expand_on)
                         root = b.finish(b.lower(self.plan), self.plan)
                         key = ("whole_query", tuple(b.key))
 
@@ -2101,7 +2158,8 @@ class WholeQueryExec(PhysicalPlan):
                             datas, valids, mask = _root.emit(args, needed)
                             return (datas, valids, mask, tuple(needed),
                                     tuple(needed.spans),
-                                    tuple(needed.guards))
+                                    tuple(needed.guards),
+                                    tuple(needed.unsure))
 
                         return named_jit("whole_query", _key, program,
                                          labels=_scopes)
@@ -2112,22 +2170,18 @@ class WholeQueryExec(PhysicalPlan):
                         launch.set_args({"program": module_name(kernel)})
                         note_program(kernel, (b.args,), b.members,
                                      b.scopes)
-                        datas, valids, mask, needed, spans, guards = \
-                            kernel(b.args)
+                        (datas, valids, mask, needed, spans, guards,
+                         unsure) = kernel(b.args)
                     # the program's ONE capacity verdict: the joins'
                     # `needed` scalars, the dense guards and the build
                     # spans come home in one read after the single
                     # dispatch (the query's last device interaction before
                     # collect), so this sync span is the host's view of
                     # the program's device time
-                    needed, guards, spans = device_read(
-                        "whole_query.verdict", needed, guards, spans)
-                    bumped = False
-                    for i, nd in enumerate(needed):
-                        n_i = int(nd)
-                        if n_i > join_caps[i]:
-                            join_caps[i] = bucket_capacity(n_i)
-                            bumped = True
+                    needed, guards, spans, unsure = device_read(
+                        "whole_query.verdict", needed, guards, spans, unsure)
+                    bumped = _apply_needed(b, needed, unsure, join_caps,
+                                           expand_on)
                     # dense-probe guards: the seeded span no longer
                     # covers the build rows (data drifted under the
                     # fingerprint) — drop the dense variant for that
@@ -2142,7 +2196,8 @@ class WholeQueryExec(PhysicalPlan):
                                   "discarded": bumped,
                                   "window_members": sum(
                                       (sc or "").endswith(".Window")
-                                      for sc in b.scopes)})
+                                      for sc in b.scopes),
+                                  **_setop_args(b)})
                 if bumped:
                     continue
                 if attempt:

@@ -32,10 +32,12 @@ def build_index_of_pr33(key_cols, key_valids, row_mask, key="hash"):
 
 def probe_join_of_pr33(build, build_key_cols, build_key_valids,
                        probe_key_cols, probe_key_valids, probe_mask,
-                       out_capacity, join_type="inner", key="hash"):
+                       out_capacity, join_type="inner", key="hash",
+                       expand=True):
     """`ops/joining.probe_join` as it was before `key_path` (PR 33), word
     for word (it ends in today's `_expand`, whose body that PR left as it
-    was); `key` is taken and not read."""
+    was); `key` and `expand` (a semi or anti join here always expands) are
+    taken and not read."""
     pcap = probe_mask.shape[0]
     oc = out_capacity
 

@@ -259,10 +259,13 @@ def _join_case(name):
 
 
 def _joined(case, join_type):
+    """The join on the expansion, which a semi or anti join takes where
+    its existence test leaves rows undecided (tests/test_existence_joins.py
+    holds that test to it)."""
     bk, bvalid, bmask, pk, pvalid, pmask, oc = case
     bi = build_index([bk], [bvalid], bmask)
     return probe_join(bi, [bk], [bvalid], [pk], [pvalid], pmask, oc,
-                      join_type)
+                      join_type, expand=True)
 
 
 def _same_arrays(a, b, what):
@@ -462,7 +465,7 @@ def test_probe_join_same_on_both_key_paths(case, join_type, src, monkeypatch):
     for key in ("exact", "hash"):
         bi = build_index([bk], [bvalid], bmask, key)
         got[key] = probe_join(bi, [bk], [bvalid], [pk], [pvalid], pmask, oc,
-                              join_type, key)
+                              join_type, key, expand=True)
         assert (got[key].runs is not None) == (src == "fill")
     exact, hashed = got["exact"], got["hash"]
     _same_arrays((exact.probe_idx, exact.matched, exact.out_mask,
@@ -516,7 +519,7 @@ def test_hash_keyed_join_lowers_to_the_text_of_pr33(join_type, keys):
         key = "hash" if keys == "int32_forced" else J.key_path(bk, pk)
         bi = index(bk, bv, bmask, key)
         return tuple(probe(bi, bk, bv, pk, pv, pmask, cap, join_type,
-                           key))[:5]
+                           key, expand=True))[:5]
 
     shapes = [jax.ShapeDtypeStruct((cap,), t)
               for t in dts + [bool] * n + dts + [bool] * n + [bool, bool]]
