@@ -544,50 +544,70 @@ class ColumnarBatch:
                     pa.array(codes, mask=mask),
                     pa.array(list(sd.values) or [""], type=pa.string())))
                 continue
-            vals = c.to_numpy(sel)
-            at = to_arrow_type(f.dataType)
-            if isinstance(f.dataType, NullType):
-                arrays.append(pa.nulls(len(sel)))
-            elif isinstance(f.dataType, DecimalType):
-                # vals are floats; rebuild exact decimals from scaled ints
-                raw = np.asarray(c.data)[sel]
-                valid = (np.asarray(c.validity)[sel]
-                         if c.validity is not None else None)
-                import decimal as _d
-
-                scale = f.dataType.scale
-                py = [None if (valid is not None and not valid[i])
-                      else _d.Decimal(int(raw[i])).scaleb(-scale)
-                      for i in range(len(raw))]
-                arrays.append(pa.array(py, type=at))
-            elif isinstance(f.dataType, MapType):
-                arrays.append(pa.array(
-                    [None if v is None else list(v.items())
-                     for v in vals], type=at))
-            elif isinstance(f.dataType, (StringType, ArrayType, StructType)):
-                arrays.append(pa.array(list(vals), type=at))
-            else:
-                mask = None
-                if c.validity is not None:
-                    mask = ~np.asarray(c.validity)[sel]
-                if vals.dtype == object and (
-                        str(at) == "date32[day]"
-                        or str(at).startswith("timestamp")):
-                    # host lists can carry None for masked slots (e.g. a
-                    # date column read from ORC) — zero-fill, the mask
-                    # already marks them null
-                    vals = np.asarray([0 if v is None else v
-                                       for v in vals])
-                if f.dataType.device_dtype == np.dtype(np.int32) and str(at) == "date32[day]":
-                    arrays.append(pa.array(np.asarray(vals, np.int32), type=at, mask=mask))
-                elif str(at).startswith("timestamp"):
-                    arrays.append(pa.array(np.asarray(vals, np.int64), type=at, mask=mask))
-                else:
-                    vals2 = np.asarray([v if v is not None else 0 for v in vals]) \
-                        if vals.dtype == object else vals
-                    arrays.append(pa.array(vals2, type=at, mask=mask))
+            arrays.append(_arrow_by_value(f.dataType, c, sel)
+                          if isinstance(f.dataType, BY_VALUE_TYPES)
+                          else _arrow_from_planes(f.dataType, c, sel))
         return pa.table(arrays, names=self.schema.names)
 
     def __repr__(self) -> str:  # pragma: no cover
         return (f"ColumnarBatch(cap={self.capacity}, rows={self._num_rows}, "
                 f"schema={self.schema.simple_string()})")
+
+
+# the types whose answer columns `to_arrow` builds a Python value at a
+# time; every other column is built from its host planes as they lie
+BY_VALUE_TYPES = (ArrayType, MapType, StructType)
+
+
+def _arrow_from_planes(dt: DataType, c: Column, sel: np.ndarray):
+    """One column of a flat type as an Arrow array built from its host
+    planes (data, validity) at `sel`, with no Python object a value."""
+    import pyarrow as pa
+
+    if isinstance(dt, NullType):
+        return pa.nulls(len(sel))
+    data = np.asarray(c.data)  # tpulint: ignore[host-sync]
+    if data.dtype.kind not in "biuf":
+        raise TypeError(f"a {dt.simple_string()} column holds "
+                        f"{data.dtype} data, not a numeric plane")
+    data = data[sel]
+    valid = None if c.validity is None \
+        else np.asarray(c.validity)[sel]  # tpulint: ignore[host-sync]
+    at = to_arrow_type(dt)
+    if isinstance(dt, StringType):
+        # the dictionary once, with the sentinel `Column.to_numpy` appends
+        # for codes past its end (all of them when it is empty)
+        values = c.dictionary.values
+        codes = np.clip(data, 0, len(values))
+        return pa.array(list(values) + [""], type=at).take(
+            pa.array(codes, mask=None if valid is None else ~valid))
+    if isinstance(dt, DecimalType):
+        # decimal128 is two little-endian 64-bit words a value: the
+        # unscaled integer and its sign
+        live = data if valid is None else data[valid]
+        bound = 10 ** dt.precision
+        if ((live >= bound) | (live <= -bound)).any():
+            raise pa.ArrowInvalid(
+                f"a value of {dt.simple_string()} does not fit into "
+                f"precision {dt.precision}")
+        words = np.empty((len(data), 2), dtype="<i8")
+        words[:, 0] = data
+        words[:, 1] = data >> 63
+        bitmap = None if valid is None or valid.all() \
+            else pa.py_buffer(np.packbits(valid, bitorder="little"))
+        return pa.Array.from_buffers(at, len(data),
+                                     [bitmap, pa.py_buffer(words)])
+    return pa.array(data, type=at, mask=None if valid is None else ~valid)
+
+
+def _arrow_by_value(dt: DataType, c: Column, sel: np.ndarray):
+    """One column of an array, map or struct type as an Arrow array built
+    a Python value at a time."""
+    import pyarrow as pa
+
+    vals = c.to_numpy(sel)
+    at = to_arrow_type(dt)
+    if isinstance(dt, MapType):
+        return pa.array([None if v is None else list(v.items())
+                         for v in vals], type=at)
+    return pa.array(list(vals), type=at)

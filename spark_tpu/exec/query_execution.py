@@ -608,8 +608,18 @@ class QueryExecution:
 
                     batches = [ColumnarBatch.empty(schema)]
                 host = _planes_to_host(batches)
-                with span_here("collect.arrow", cat="phase"):
+                with span_here("collect.arrow", cat="phase") as sp:
+                    from ..columnar.batch import BY_VALUE_TYPES
+
                     tables = [b.to_arrow() for b in batches]
+                    # the answer's columns built a Python value at a time
+                    by_value = sum(isinstance(f.dataType, BY_VALUE_TYPES)
+                                   for f in schema.fields)
+                    sp.set_args({"rows": sum(t.num_rows for t in tables),
+                                 "by_value": by_value})
+                    if by_value:
+                        self._last_ctx.metrics.add(
+                            "collect.columns_by_value", by_value)
                     try:
                         # identical schemas concat fine even with
                         # duplicate output names (legal, as in the
